@@ -1,47 +1,22 @@
-"""Version shims for jax APIs the SPMD modules use.
-
-``shard_map`` graduated from ``jax.experimental`` to the top level, and
-the explicit varying-manual-axes (vma) type system added ``lax.pcast``;
-older jax releases have neither. These shims let ``ring_attention`` /
-``pipeline`` run unchanged on both sides:
-
-* :func:`shard_map` — the top-level one when present, else the
-  experimental one with ``check_rep=False`` (the old replication checker
-  has no rules for the manual ppermute accumulation patterns these
-  modules build; the new vma system types them fine).
-* :func:`pcast` — marks a value device-varying over ``axes`` under the
-  vma type system; a no-op identity on jax without one (nothing tracks
-  variance there, so there is nothing to cast).
+"""The jax SPMD entry points ``ring_attention`` / ``pipeline`` /
+``comm_ops`` build on, under the positional call shapes those modules
+use: the top-level ``shard_map`` (varying-manual-axes typing, which
+types their manual ppermute accumulation patterns), ``lax.pcast`` (marks
+a value device-varying over ``axes``) and ``lax.axis_size``. Written for
+the one installation there is (JAX 0.9); no older-release branches.
 """
-import jax
 from jax import lax as _lax
+from jax import shard_map as _shard_map
 
-try:  # jax with top-level shard_map (vma typing)
-    from jax import shard_map as _shard_map
+__all__ = ["shard_map", "pcast", "axis_size"]
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-except ImportError:  # older jax: experimental, pre-vma
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def pcast(x, axes, to):
-    if hasattr(_lax, "pcast"):
-        return _lax.pcast(x, axes, to=to)
-    return x
+    return _lax.pcast(x, axes, to=to)
 
 
-def axis_size(name):
-    """``lax.axis_size`` where it exists; the psum-of-one identity (a
-    static constant — jax folds it) everywhere else."""
-    if hasattr(_lax, "axis_size"):
-        return _lax.axis_size(name)
-    return _lax.psum(1, name)
-
-
-__all__ = ["shard_map", "pcast", "axis_size"]
+axis_size = _lax.axis_size

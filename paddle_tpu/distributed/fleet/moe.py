@@ -104,7 +104,7 @@ def _moe_ep_kernel(x, gate_logits, w_in, w_out, *, mesh, ep_axis, capacity,
     per (source rank, expert); the per-expert total is ``ep * capacity``,
     matching the replicated kernel's global capacity."""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     E = gate_logits.shape[1]

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import os
 import threading
 
 import jax
@@ -38,38 +39,39 @@ __all__ = [
 from .fusion import fuse_elementwise_chains, fusion_stats  # noqa: E402
 
 
-def enable_compilation_cache(cache_dir, min_compile_time_s=0.0):
-    """Wire JAX's persistent compilation cache at ``cache_dir`` so
-    compiled programs (including the serving engine's AOT ``warmup()``
-    shapes) survive process restarts — a restarted server replays its
-    warmup from disk instead of re-invoking XLA per shape.
+# where the persistent compile cache lives when the environment does not
+# say: one fixed directory at the root of the checkout (git-ignored). The
+# path is part of the cache key, so it is never a temp name, pid or time.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    ``min_compile_time_s=0.0`` caches even sub-second programs (the
-    default JAX threshold would skip the small per-width prefill shapes).
-    Safe to call repeatedly; later calls just repoint the directory.
-    Returns the directory wired in."""
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    for opt, val in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             float(min_compile_time_s)),
-            ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            # knob absent in this jax build: the cache still works with
-            # its defaults
-            pass
-    try:
-        # jax latches cache initialization at the FIRST compile of the
-        # process: if anything compiled before this call (it always has —
-        # model init alone compiles), the new directory is silently
-        # ignored until the cache is reset
-        from jax._src import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:
-        pass
-    return str(cache_dir)
+def enable_compilation_cache():
+    """Switch on JAX's persistent compilation cache so compiled programs
+    (including the serving engine's AOT ``warmup()`` shapes) survive
+    process restarts — a restarted server replays its warmup from disk
+    instead of re-invoking XLA per shape.
+
+    The directory is placed from OUTSIDE the program: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set it is that directory, otherwise
+    ``<checkout>/.jax_cache``. This is the only place the program sets
+    ``jax_compilation_cache_dir``. Programs of any size and compile time
+    are cached (JAX's default thresholds would skip the small per-width
+    prefill shapes). Call it before the first compile that should be
+    cached; safe to call repeatedly. Returns the directory in use."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    directory = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or _CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # jax latches cache initialization at the FIRST compile of the
+    # process: if anything compiled before this call (model init alone
+    # compiles), the directory is ignored until the cache is reset
+    compilation_cache.reset_cache()
+    return directory
 
 
 # ------------------------------------------------------------ traced RNG
@@ -757,23 +759,36 @@ class TrainStep:
         model.load_raw_state(new_params, new_buffers)
         return Tensor._from_value(losses)
 
-    def __call__(self, *args, labels=None, **kwargs):
-        if self._compiled is None:
-            self._compiled = self._build()
+    def _step_operands(self, t, args, kwargs, labels):
+        """The one-step program's operand tuple at step count ``t``."""
         model, optimizer = self.model, self.optimizer
         params = {k: p._value for k, p in model.named_parameters()}
         buffers = {k: b._value for k, b in model.named_buffers()}
-        optimizer._step_count += 1
         lr = jnp.asarray(optimizer.get_lr(), jnp.float32)
-        t = jnp.asarray(optimizer._step_count, jnp.int32)
         rng_key = jax.random.key_data(_random.next_key())
+        return (params, buffers, self._accs, self._masters, lr,
+                jnp.asarray(t, jnp.int32), rng_key, _as_array_tree(args),
+                _as_array_tree(kwargs), _as_array_tree(labels))
 
-        loss, new_params, new_buffers, self._accs, self._masters = self._compiled(
-            params, buffers, self._accs, self._masters, lr, t, rng_key,
-            _as_array_tree(args), _as_array_tree(kwargs),
-            _as_array_tree(labels),
-        )
-        model.load_raw_state(new_params, new_buffers)
+    def lower(self, *args, labels=None, **kwargs):
+        """``jax.jit(...).lower`` of the one-step program for these
+        inputs — the ``jax.stages.Lowered`` whose ``as_text()`` /
+        ``compile()`` show what a ``__call__`` with the same inputs
+        runs (kernels present, memory). Touches no training state
+        beyond drawing one key from the host RNG stream."""
+        if self._compiled is None:
+            self._compiled = self._build()
+        return self._compiled.lower(*self._step_operands(
+            self.optimizer._step_count + 1, args, kwargs, labels))
+
+    def __call__(self, *args, labels=None, **kwargs):
+        if self._compiled is None:
+            self._compiled = self._build()
+        self.optimizer._step_count += 1
+        loss, new_params, new_buffers, self._accs, self._masters = \
+            self._compiled(*self._step_operands(
+                self.optimizer._step_count, args, kwargs, labels))
+        self.model.load_raw_state(new_params, new_buffers)
         return Tensor._from_value(loss)
 
     def state_dict(self):

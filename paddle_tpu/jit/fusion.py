@@ -20,7 +20,7 @@ this).
 The pass recurses into higher-order equations (``scan`` bodies,
 ``while`` cond/body, ``cond`` branches, ``pjit``/``closed_call``
 sub-jaxprs), which is where the serving segment program keeps its whole
-decode body.
+decode body — but never into a ``pallas_call``'s kernel body.
 
 ``count_eqns``/``fusion_stats`` expose the equation counts before and
 after — the op-bench ``decode_layer_launches`` reading.
@@ -30,8 +30,10 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax import core
+from jax import core as _jcore
 from jax import tree_util
+from jax.extend import core
+from jax.extend.core.primitives import closed_call_p
 
 __all__ = ["fuse_elementwise_chains", "rewrite_closed_jaxpr",
            "fusion_stats", "count_eqns", "ELEMENTWISE_PRIMS"]
@@ -58,7 +60,7 @@ _SUBJAXPR_PARAMS = ("jaxpr", "call_jaxpr", "cond_jaxpr", "body_jaxpr",
 
 
 def _outvars(eqn):
-    return [v for v in eqn.outvars if not isinstance(v, core.DropVar)]
+    return [v for v in eqn.outvars if not isinstance(v, _jcore.DropVar)]
 
 
 def _rewrite_sub(value, stats):
@@ -78,7 +80,11 @@ def _rewrite_jaxpr(jaxpr, stats):
     eqns = []
     for eqn in jaxpr.eqns:
         new_params = None
-        for k in _SUBJAXPR_PARAMS:
+        # a Pallas kernel body is Mosaic's to schedule, and the Pallas
+        # TPU lowering has no rule for closed_call: leave it untouched
+        sub_params = (() if eqn.primitive.name == "pallas_call"
+                      else _SUBJAXPR_PARAMS)
+        for k in sub_params:
             if k in eqn.params:
                 v = eqn.params[k]
                 rv = _rewrite_sub(v, stats)
@@ -135,11 +141,15 @@ def _rewrite_jaxpr(jaxpr, stats):
             out_eqns.extend(chain)
             i = j
             continue
-        inner = core.Jaxpr((), list(ext), list(outv), list(chain))
-        out_eqns.append(core.new_jaxpr_eqn(
-            list(ext), list(outv), core.closed_call_p,
+        inner = core.Jaxpr(
+            (), list(ext), list(outv), list(chain),
+            debug_info=_jcore.DebugInfo(
+                "elementwise_chain", jaxpr.debug_info.func_src_info,
+                (None,) * len(ext), (None,) * len(outv)))
+        out_eqns.append(_jcore.new_jaxpr_eqn(
+            list(ext), list(outv), closed_call_p,
             dict(call_jaxpr=core.ClosedJaxpr(inner, ())),
-            core.no_effects, chain[0].source_info))
+            _jcore.no_effects, chain[0].source_info))
         stats["chains"] += 1
         stats["collapsed_eqns"] += len(chain)
         i = j

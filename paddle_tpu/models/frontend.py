@@ -239,12 +239,11 @@ class ServingFrontend:
         self._segment = int(segment)
         engine.start(segment=segment)
 
-    def warmup(self, cache_dir=None):
+    def warmup(self):
         """AOT-compile every engine shape at THIS frontend's segment
         length (see ``ContinuousBatchingEngine.warmup``) so the first
         submitted request hits only precompiled programs."""
-        return self.engine.warmup(segment=self._segment,
-                                  cache_dir=cache_dir)
+        return self.engine.warmup(segment=self._segment)
 
     def fingerprint(self) -> tuple:
         """The engine identity a fleet router checks at registration:

@@ -16,6 +16,7 @@ Layout conventions: activations are (batch, seq, hidden); attention runs in
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -208,18 +209,20 @@ class PagedKVCache:
                 self.v_pages = self.v_pages.at[page_ids].set(
                     v_r.astype(self.v_pages.dtype))
             else:
-                # per-slot base positions, row-by-row (decode s=1, or a
-                # non-page-aligned chunk width)
-                for i in range(s):
-                    pos = self.length + i  # (B,)
-                    page_ids = jnp.take_along_axis(
-                        self.tables, (pos // self.page_size)[:, None],
-                        axis=1)[:, 0]
-                    off = pos % self.page_size
-                    self.k_pages = self.k_pages.at[page_ids, off].set(
-                        k_new[:, i].astype(self.k_pages.dtype))
-                    self.v_pages = self.v_pages.at[page_ids, off].set(
-                        v_new[:, i].astype(self.v_pages.dtype))
+                # per-slot base positions (decode s=1, or a
+                # non-page-aligned chunk width): ONE scatter over the
+                # (B, s) position grid. A scatter per token unrolled
+                # s x layers of them into the prefix-resume program,
+                # which then took minutes to compile at real depth.
+                pos = (self.length[:, None]
+                       + jnp.arange(s, dtype=jnp.int32)[None, :])
+                page_ids = jnp.take_along_axis(
+                    self.tables, pos // self.page_size, axis=1)
+                off = pos % self.page_size
+                self.k_pages = self.k_pages.at[page_ids, off].set(
+                    k_new.astype(self.k_pages.dtype))
+                self.v_pages = self.v_pages.at[page_ids, off].set(
+                    v_new.astype(self.v_pages.dtype))
             self.length = self.length + s
             return
         if (s > 1 and s % self.page_size == 0
@@ -367,10 +370,11 @@ class LlamaAttention(Layer):
             elif not isinstance(offset, int) or offset > 0:
                 position_ids = Tensor._from_value(
                     jnp.arange(s) + offset)
-            q, k = rotary_position_embedding(
-                q, k, self.rope_cos, self.rope_sin,
-                position_ids=position_ids)
-            out = self._cached_attention(q, k, v, cache, offset, s)
+            with self._kernel_scope():
+                q, k = rotary_position_embedding(
+                    q, k, self.rope_cos, self.rope_sin,
+                    position_ids=position_ids)
+                out = self._cached_attention(q, k, v, cache, offset, s)
             out = self.o_proj(reshape(out, [b, s, h * d]))
             return out, cache
         if cache is not None and cache[0].shape[1] > 0:
@@ -378,15 +382,17 @@ class LlamaAttention(Layer):
             offset = cache[0].shape[1]
             position_ids = Tensor._from_value(
                 jnp.arange(offset, offset + s))
-        q, k = rotary_position_embedding(q, k, self.rope_cos, self.rope_sin,
-                                         position_ids=position_ids)
-        if cache is not None:
-            k = concat([cache[0], k], axis=1)
-            v = concat([cache[1], v], axis=1)
-        new_cache = (k, v)
-        out = scaled_dot_product_attention(
-            q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
-        )
+        with self._kernel_scope():
+            q, k = rotary_position_embedding(
+                q, k, self.rope_cos, self.rope_sin,
+                position_ids=position_ids)
+            if cache is not None:
+                k = concat([cache[0], k], axis=1)
+                v = concat([cache[1], v], axis=1)
+            new_cache = (k, v)
+            out = scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
+            )
         out = self.o_proj(reshape(out, [b, s, h * d]))
         if cache is not None:
             return out, new_cache
@@ -394,6 +400,27 @@ class LlamaAttention(Layer):
 
     def _cached_attention(self, q, k, v, cache, offset, s):
         return cached_attention(q, k, v, cache, offset, s)
+
+    def _kernel_scope(self):
+        """Mesh scope for this layer's Mosaic kernels (rope, flash and
+        paged attention), read off the placement ``dist.shard_tensor``
+        stamped on q_proj's weight: heads split over the axis that
+        column-shards it, batch over the mesh's other axes. An unsharded
+        layer adds no scope (an enclosing one, e.g. the TP serving
+        engine's, stays in force)."""
+        hint = getattr(self.q_proj.weight, "_placements_hint", None)
+        if hint is None:
+            return contextlib.nullcontext()
+        from ..distributed.placement import Shard
+        from ..ops.pallas import kernel_mesh
+
+        mesh, placements = hint
+        head = [mesh.dim_names[i] for i, p in enumerate(placements)
+                if isinstance(p, Shard) and p.dim == 1]
+        return kernel_mesh(
+            mesh.jax_mesh(),
+            batch_axes=[n for n in mesh.dim_names if n not in head],
+            head_axis=head[0] if head else None)
 
 
 class LlamaMLP(Layer):

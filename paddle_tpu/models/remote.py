@@ -363,9 +363,9 @@ class ReplicaServer:
         with self._lock:
             return tuple(self.frontend.fingerprint())
 
-    def warmup(self, cache_dir=None):
+    def warmup(self):
         with self._lock:
-            return self.frontend.warmup(cache_dir=cache_dir)
+            return self.frontend.warmup()
 
     def stats(self) -> dict:
         return {"busy_s": self._busy_s, "live": len(self._live)}
@@ -595,9 +595,8 @@ class RemoteFrontend:
     def fingerprint(self):
         return tuple(self._rpc("fingerprint", timeout=self.health_timeout))
 
-    def warmup(self, cache_dir=None):
-        return self._rpc("warmup", cache_dir=cache_dir,
-                         timeout=self.warmup_timeout)
+    def warmup(self):
+        return self._rpc("warmup", timeout=self.warmup_timeout)
 
     def step(self):
         """No-op: the replica's own pump thread owns progress; the

@@ -1871,7 +1871,7 @@ class ServingRouter:
         self._autoscaler = scaler
         return scaler
 
-    def warmup(self, cache_dir=None):
+    def warmup(self):
         """AOT-warm every replica's compiled serving shapes. A replica
         whose warmup fails at the TRANSPORT is classified dead (like any
         other call) rather than aborting the remaining replicas'
@@ -1881,7 +1881,7 @@ class ServingRouter:
             if rep.state != "up":
                 continue
             try:
-                out[rep.id] = rep.frontend.warmup(cache_dir=cache_dir)
+                out[rep.id] = rep.frontend.warmup()
             except StaleLeaderError as e:
                 self._stand_down(str(e))
                 return out

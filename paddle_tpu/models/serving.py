@@ -31,9 +31,9 @@ selects the serial one-segment-at-a-time loop.
 
 ``warmup()`` AOT-compiles (``jit(...).lower().compile()``) every declared
 (bucket x group-width) prefill shape plus the chunked-prefill and
-decode-segment programs, and can wire JAX's persistent compilation cache,
-so first-request latency and ``stats()`` throughput stop absorbing
-compile time.
+decode-segment programs (``jit.enable_compilation_cache()`` keeps them
+across process restarts), so first-request latency and ``stats()``
+throughput stop absorbing compile time.
 
 KV memory is a DYNAMIC PAGE POOL (``models/kv_pool.py``), not a frozen
 slot->page map: a slot is granted pages for its prompt at admission and
@@ -54,6 +54,7 @@ allocator path.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 import uuid
@@ -524,6 +525,13 @@ class ContinuousBatchingEngine:
         greedy = not self.do_sample
         eos = self.eos_token_id
 
+        def run_model(params, tokens, caches):
+            # every program's model forward: traced inside the engine's
+            # kernel scope (the TP engine's mesh — see _kernel_scope)
+            with self._kernel_scope():
+                return functional(params, buffers, (tokens,),
+                                  {"caches": caches}, zero_key)
+
         def sample_batch(last, keys):
             # per-row key streams: row i is drawn with ITS OWN key, so a
             # row's tokens are independent of who it was batched with
@@ -545,8 +553,7 @@ class ContinuousBatchingEngine:
             # slot pages at ``base`` (0 = fresh slots, (N,) array =
             # chunked-prefill offsets); returns (logits, pools)
             caches = self._caches(ks, vs, table_rows, base)
-            (logits, caches2), _ = functional(
-                params, buffers, (prompts,), {"caches": caches}, zero_key)
+            (logits, caches2), _ = run_model(params, prompts, caches)
             return (logits, [c.k_pages for c in caches2],
                     [c.v_pages for c in caches2])
 
@@ -580,8 +587,7 @@ class ContinuousBatchingEngine:
             # CoW page copy ran first), sampling at the true last token
             caches = self._caches(ks, vs, table_rows, bases,
                                   aligned=False)
-            (logits, caches2), _ = functional(
-                params, buffers, (chunk,), {"caches": caches}, zero_key)
+            (logits, caches2), _ = run_model(params, chunk, caches)
             ks2 = [c.k_pages for c in caches2]
             vs2 = [c.v_pages for c in caches2]
             return sample_true_last(logits, true_lens, keys), ks2, vs2
@@ -619,9 +625,8 @@ class ContinuousBatchingEngine:
             def body(carry, key):
                 tok, ks, vs, lengths, active = carry
                 caches = self._caches(ks, vs, tables, lengths)
-                (logits, caches2), _ = functional(
-                    params, buffers, (tok[:, None],), {"caches": caches},
-                    zero_key)
+                (logits, caches2), _ = run_model(params, tok[:, None],
+                                                 caches)
                 nxt = sample_batch(logits[:, -1, :], key)
                 nxt = jnp.where(active, nxt, tok)  # frozen slots emit noise
                 new_lengths = jnp.where(active, lengths + 1, lengths)
@@ -719,7 +724,7 @@ class ContinuousBatchingEngine:
         out.append(self.max_slots)
         return tuple(out)
 
-    def warmup(self, segment=None, cache_dir=None):
+    def warmup(self, segment=None):
         """AOT-compile (``jit(...).lower().compile()``) every declared
         serving shape: one prefill program per (prompt bucket x admission
         group width), the chunked-prefill chunk/final programs per width
@@ -730,16 +735,12 @@ class ContinuousBatchingEngine:
         time.
 
         ``segment`` must match the segment length later sessions use
-        (defaults to the last ``start(segment=...)`` or 16).
-        ``cache_dir`` additionally wires JAX's persistent compilation
-        cache so the compiles survive process restarts. Returns
+        (defaults to the last ``start(segment=...)`` or 16). Call
+        ``paddle.jit.enable_compilation_cache()`` first to have the
+        compiles survive process restarts. Returns
         ``{"programs": newly compiled, "cached": already present,
         "seconds": wall}``.
         """
-        if cache_dir is not None:
-            from ..jit import enable_compilation_cache
-
-            enable_compilation_cache(cache_dir)
         t0 = time.monotonic()
         # compile watchdog: everything below is warmup-phase compilation;
         # once done, this engine's non-AOT dispatches become recompile
@@ -751,6 +752,19 @@ class ContinuousBatchingEngine:
         wd.arm()
         stats["seconds"] = time.monotonic() - t0
         return stats
+
+    def _kernel_scope(self):
+        """Context the programs' model forwards are traced in. The TP
+        engine overrides it with its mesh, so the Mosaic kernels are
+        partitioned by ``shard_map`` (GSPMD cannot partition them)."""
+        return contextlib.nullcontext()
+
+    def compiled_programs(self) -> dict:
+        """The executables ``warmup()`` compiled, keyed as the dispatch
+        path looks them up: ``("prefill", bucket, width)``, ``("chunk",
+        width)``, ``("segment", steps)``, ... (``as_text()`` /
+        ``memory_analysis()`` say what serves each shape)."""
+        return dict(self._aot)
 
     def _sds(self, x):
         """Warmup aval for an EXISTING engine array (params / KV pools).

@@ -251,6 +251,13 @@ class TPShardedEngine(ContinuousBatchingEngine):
             out[name] = sv
         return out
 
+    def _kernel_scope(self):
+        # heads (and kv heads) split over the TP axis, as the plan's
+        # column-parallel projections and the kv-head-sharded pools do
+        from ..ops.pallas import kernel_mesh
+
+        return kernel_mesh(self._jmesh, head_axis=self._tp_axis)
+
     # ---------------------------------------------------- aval overrides
 
     def _sds(self, x):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.dtype import convert_dtype, to_jax_dtype
+from ..core.flags import flag
 from ..core.tensor import Parameter, Tensor
 from . import initializer as I
 
@@ -97,9 +98,14 @@ class HookRemoveHelper:
 
 
 class Layer:
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
         self.training = True
-        self._dtype = convert_dtype(dtype).name if dtype is not None else "float32"
+        # parameters are created in ``paddle.get_default_dtype()`` unless
+        # the layer names one: ``set_default_dtype("bfloat16")`` builds a
+        # model in bf16 directly, with no float32 copy to cast afterwards
+        self._dtype = convert_dtype(
+            dtype if dtype is not None
+            else flag("FLAGS_default_dtype")).name
         self._parameters: OrderedDict[str, Parameter] = OrderedDict()
         self._sub_layers: OrderedDict[str, Layer] = OrderedDict()
         self._buffers: OrderedDict[str, Tensor] = OrderedDict()

@@ -30,11 +30,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import interpret as _interpret
+from . import over_mesh as _over_mesh
 
 __all__ = ["paged_attention", "masked_decode_attention",
            "paged_attention_supported"]
@@ -42,13 +41,7 @@ __all__ = ["paged_attention", "masked_decode_attention",
 NEG_INF = -1e30
 
 
-def _interpret():
-    return jax.default_backend() != "tpu"
-
-
 def paged_attention_supported(q, k_pages):
-    if pltpu is None:
-        return False
     if q.ndim != 3 or k_pages.ndim != 4:
         return False
     h, kvh = q.shape[1], k_pages.shape[2]
@@ -134,11 +127,19 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
     step, with the per-(b) online-softmax state carried in VMEM scratch
     across the page dimension.
     """
-    b, h, d = q.shape
-    npages, page_size, kvh, _ = k_pages.shape
     if (pages_per_seq is not None
             and pages_per_seq < block_tables.shape[1]):
         block_tables = block_tables[:, :pages_per_seq]
+    return _over_mesh(
+        _paged_attention,
+        (q, k_pages, v_pages, block_tables.astype(jnp.int32),
+         lengths.astype(jnp.int32)),
+        ("bh.", "..h.", "..h.", "b.", "b"), "bh.")
+
+
+def _paged_attention(q, k_pages, v_pages, block_tables, lengths):
+    b, h, d = q.shape
+    npages, page_size, kvh, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     scale = 1.0 / math.sqrt(d)
 
@@ -178,8 +179,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=_interpret(),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages)
+    )(block_tables, lengths, q, k_pages, v_pages)
 
 
 def masked_decode_attention(q, k_cache, v_cache, lengths, page_size=None):

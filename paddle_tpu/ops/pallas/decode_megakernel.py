@@ -50,11 +50,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import interpret as _interpret
 
 __all__ = [
     "fused_decode_layer", "reference_decode_layer",
@@ -65,6 +63,7 @@ __all__ = [
 ]
 
 NEG_INF = -1e30
+_LANES = 128  # TPU vreg lane width
 
 # whole projection weight blocks must fit VMEM (~16MB/core) next to the
 # page blocks and scratch; models past this budget decline to unfused
@@ -74,10 +73,6 @@ MEGAKERNEL_VMEM_BUDGET = 12 * 2 ** 20
 # segment program under megakernel_scope(False) and the fused one under
 # megakernel_scope(True), so one flag flip can never retrace the other
 _SCOPE = []
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def megakernel_mode():
@@ -114,9 +109,7 @@ def megakernel_kernel_active():
     right now (vs. the exact unfused composition)."""
     if not megakernel_enabled():
         return False
-    if pltpu is None:
-        return False
-    return jax.default_backend() == "tpu" or megakernel_mode() >= 2
+    return not _interpret() or megakernel_mode() >= 2
 
 
 def _weight_bytes(*arrays):
@@ -126,17 +119,21 @@ def _weight_bytes(*arrays):
 def megakernel_layer_supported(layer):
     """Structural probe over one decoder layer: standard LLaMA layout
     (bias-free projections, RMSNorm without bias, neox rope tables,
-    GQA-divisible heads) and projection weights within the VMEM budget.
-    Mirrors ``paged_attention_supported`` in spirit: callers branch, the
-    kernel itself assumes eligibility."""
-    if pltpu is None:
-        return False
+    GQA-divisible heads), a head_dim Mosaic can lay out, and projection
+    weights within the VMEM budget. Mirrors ``paged_attention_supported``
+    in spirit: callers branch, the kernel itself assumes eligibility."""
     try:
         attn = layer.self_attn
         cfg = attn.config
         h, kv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
         if h % kv or d % 2:
+            return False
+        if not _interpret() and d % _LANES:
+            # compiled for the chip, the per-head (1, d) rows and the
+            # (page, d) page planes must fill whole 128-lane vregs:
+            # Mosaic refuses head_dim 64 ("infer-vector-layout:
+            # unsupported shape cast"). The interpreter has no lanes.
             return False
         for lin in (attn.q_proj, attn.k_proj, attn.v_proj, attn.o_proj):
             if getattr(lin, "bias", None) is not None:
@@ -207,34 +204,42 @@ def _megakernel(tables_ref, lens_ref, x_ref, ln1_ref, ln2_ref,
         # input rms_norm — the exact jnp-fallback math of F.rms_norm
         # (traced programs always take that path), so fused == unfused
         xr = x_ref[0]                                    # (1, hidden)
+        dt = xr.dtype
         xf = xr.astype(jnp.float32)
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        xn = (xf * jax.lax.rsqrt(var + eps1)).astype(xr.dtype)
+        xn = (xf * jax.lax.rsqrt(var + eps1)).astype(dt)
         xn = xn * ln1_ref[...]
         xnf = xn.astype(jnp.float32)
-        c = cos_ref[...].astype(jnp.float32)             # (1, d) at length
-        s = sin_ref[...].astype(jnp.float32)
+        # this slot's rope row, gathered by the caller at the decode
+        # position and already in the model dtype
+        c = cos_ref[0].astype(jnp.float32)               # (1, d)
+        s = sin_ref[0].astype(jnp.float32)
         half = d // 2
 
-        def rope(row):                                   # neox layout
-            r1, r2 = row[:, :half], row[:, half:]
-            return row * c + jnp.concatenate([-r2, r1], axis=1) * s
+        def proj(w_ref, i):
+            # one head's (1, hidden) x (hidden, d) dot, rounded to the
+            # model dtype where the unfused Linear rounds its output
+            return jnp.dot(xnf, w_ref[:, i * d:(i + 1) * d]
+                           .astype(jnp.float32),
+                           preferred_element_type=jnp.float32
+                           ).astype(dt).astype(jnp.float32)
 
-        # per-head (1, hidden) x (hidden, d) dots, statically unrolled —
-        # same Mosaic constraint as _decode_kernel's per-kv-head matmuls
+        def rope(row, out_dt):                           # neox layout
+            r1, r2 = row[:, :half], row[:, half:]
+            out = row * c + jnp.concatenate([-r2, r1], axis=1) * s
+            return out.astype(out_dt).astype(jnp.float32)
+
+        # per-head dots, statically unrolled — same Mosaic constraint as
+        # _decode_kernel's per-kv-head matmuls. q/k/v scratch rows hold
+        # values already rounded the way the unfused composition rounds
+        # them (q to the model dtype, k/v to the page dtype), so the
+        # attention below reads what paged_attention would read.
         for i in range(h):
-            qi = jnp.dot(xnf, wq_ref[:, i * d:(i + 1) * d]
-                         .astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
-            q_s[i:i + 1, :] = rope(qi)
+            q_s[i:i + 1, :] = rope(proj(wq_ref, i), dt)
         for i in range(kvh):
-            ki = jnp.dot(xnf, wk_ref[:, i * d:(i + 1) * d]
-                         .astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
-            k_s[i:i + 1, :] = rope(ki)
-            v_s[i:i + 1, :] = jnp.dot(xnf, wv_ref[:, i * d:(i + 1) * d]
-                                      .astype(jnp.float32),
-                                      preferred_element_type=jnp.float32)
+            k_s[i:i + 1, :] = rope(proj(wk_ref, i), ko_ref.dtype)
+            v_s[i:i + 1, :] = proj(wv_ref, i).astype(
+                vo_ref.dtype).astype(jnp.float32)
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
@@ -341,9 +346,16 @@ def fused_decode_layer(x, *, ln1_weight, ln1_eps, wq, wk, wv, wo,
         tables = tables[:, :attn_pages]
     pages_per_seq = tables.shape[1]
     scale = 1.0 / math.sqrt(d)
+    # the decode position IS the pre-append depth (offset semantics of
+    # LlamaAttention.forward: positions = arange(1) + length). The rows
+    # are gathered here, not by a (1, d) block over the (rows, d) table:
+    # Mosaic wants a block's last two dims 8x128-aligned or whole, and a
+    # (B, 1, d) operand read in (1, 1, d) blocks is whole.
     cos2 = rope_cos.reshape(-1, rope_cos.shape[-1])
     sin2 = rope_sin.reshape(-1, rope_sin.shape[-1])
-    rope_rows = cos2.shape[0]
+    pos = jnp.clip(lengths.astype(jnp.int32), 0, cos2.shape[0] - 1)
+    cos_b = cos2[pos].astype(x.dtype)[:, None, :]        # (B, 1, d)
+    sin_b = sin2[pos].astype(x.dtype)[:, None, :]
     writeback = dump_page is None
     dump = 0 if writeback else int(dump_page)
     interp = _interpret() if interpret is None else interpret
@@ -353,11 +365,6 @@ def fused_decode_layer(x, *, ln1_weight, ln1_eps, wq, wk, wv, wo,
 
     def w_map(bi, pi, tables_p, lens_p):
         return (0, 0)
-
-    def rope_map(bi, pi, tables_p, lens_p):
-        # the decode position IS the pre-append depth (offset semantics
-        # of LlamaAttention.forward: positions = arange(1) + length)
-        return (jnp.clip(lens_p[bi], 0, rope_rows - 1), 0)
 
     if writeback:
         # invalid steps read AND write the append page: the in-kernel
@@ -395,8 +402,8 @@ def fused_decode_layer(x, *, ln1_weight, ln1_eps, wq, wk, wv, wo,
             pl.BlockSpec(wk.shape, w_map),
             pl.BlockSpec(wv.shape, w_map),
             pl.BlockSpec(wo.shape, w_map),
-            pl.BlockSpec((1, d), rope_map),
-            pl.BlockSpec((1, d), rope_map),
+            pl.BlockSpec((1, 1, d), x_map),              # rope cos row
+            pl.BlockSpec((1, 1, d), x_map),              # rope sin row
             pl.BlockSpec((1, page_size, kvh, d), kv_in_map),
             pl.BlockSpec((1, page_size, kvh, d), kv_in_map),
         ],
@@ -433,7 +440,7 @@ def fused_decode_layer(x, *, ln1_weight, ln1_eps, wq, wk, wv, wo,
         interpret=interp,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), x,
       ln1_weight.reshape(1, -1), ln2_weight.reshape(1, -1),
-      wq, wk, wv, wo, cos2, sin2, k_pages, v_pages)
+      wq, wk, wv, wo, cos_b, sin_b, k_pages, v_pages)
 
 
 def reference_decode_layer(x, *, ln1_weight, ln1_eps, wq, wk, wv, wo,

@@ -35,13 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu import works everywhere; kernels interpret off-TPU
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from . import interpret as _interpret
+from . import over_mesh as _over_mesh
 
 __all__ = ["flash_attention", "flash_attention_supported", "build_segments"]
 
@@ -58,10 +53,6 @@ def _block_for(seq: int) -> int:
 
     preferred = int(flag("FLAGS_flash_attention_block_size") or PREFERRED_BLOCK)
     return preferred if seq % preferred == 0 else BLOCK_Q
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention_supported(q, k, v, attn_mask=None, dropout_p=0.0):
@@ -454,5 +445,12 @@ def flash_attention(q, k, v, is_causal=False, seq_lens=None,
     qh = jnp.swapaxes(q, 1, 2)
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
-    out = _flash_bhsd(qh, kh, vh, q_seg, k_seg, bool(is_causal), scale)
+    segs = () if q_seg is None else (q_seg, k_seg)
+
+    def run(qh, kh, vh, *segs):
+        q_seg, k_seg = segs or (None, None)
+        return _flash_bhsd(qh, kh, vh, q_seg, k_seg, bool(is_causal), scale)
+
+    out = _over_mesh(run, (qh, kh, vh) + segs,
+                     ("bh..",) * 3 + ("b..",) * len(segs), "bh..")
     return jnp.swapaxes(out, 1, 2)

@@ -23,23 +23,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import interpret as _interpret
+from . import over_mesh as _over_mesh
 
 __all__ = ["fused_rope", "fused_rope_supported",
            "bias_dropout_residual_ln"]
 
 
-def _interpret():
-    return jax.default_backend() != "tpu"
-
-
 # ------------------------------------------------------------------ RoPE
 
 def fused_rope_supported(q, cos, position_ids=None, use_neox_rotary_style=True):
-    return (pltpu is not None and position_ids is None
+    return (position_ids is None
             and use_neox_rotary_style and q is not None and q.ndim == 4
             and q.shape[-1] % 2 == 0)
 
@@ -102,9 +96,11 @@ def fused_rope(q, k, cos, sin):
     s = q.shape[1]
     cos = cos.reshape(-1, cos.shape[-1])[:s]
     sin = sin.reshape(-1, sin.shape[-1])[:s]
-    out_q = _rope_one(q, cos, sin)
-    out_k = _rope_one(k, cos, sin) if k is not None else None
-    return out_q, out_k
+    def rope(x):
+        return _over_mesh(_rope_one, (x, cos, sin), ("b.h.", "..", ".."),
+                          "b.h.")
+
+    return rope(q), rope(k) if k is not None else None
 
 
 # ------------------------------------------- bias + dropout + residual + LN

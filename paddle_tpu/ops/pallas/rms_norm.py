@@ -20,13 +20,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret as _interpret
+
 __all__ = ["rms_norm", "rms_norm_supported"]
 
 BLOCK_ROWS = 256
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def rms_norm_supported(x, weight):
@@ -42,10 +40,10 @@ def rms_norm_supported(x, weight):
     return d % 128 == 0 and d <= 16384 and n % 8 == 0
 
 
-def _rows_block(n, d):
-    # cap the block so x/g/dx row-blocks stay well inside VMEM
-    # (~4MB of f32 per buffer)
-    cap = max(8, (1 << 20) // max(d, 1))
+def _rows_block(n, d, elems=1 << 20):
+    # cap the block so the row-blocks stay well inside VMEM (``elems``
+    # elements, ~4MB of f32, per buffer)
+    cap = max(8, elems // max(d, 1))
     b = BLOCK_ROWS
     while b > cap:
         b //= 2
@@ -128,7 +126,10 @@ def _bwd_kernel(x_ref, w_ref, r_ref, g_ref, dx_ref, dw_ref, db_ref):
 
 def _bwd_call(x2, w, r, g2):
     n, d = x2.shape
-    br = _rows_block(n, d)
+    # half the forward's block: x, g and dx blocks plus their f32
+    # temporaries are live together, and at d=4096 the forward's 256 rows
+    # needed 20 MB of the chip's 16 MB scoped VMEM
+    br = _rows_block(n, d, elems=1 << 19)
     dx, dw, db = pl.pallas_call(
         _bwd_kernel,
         grid=(n // br,),

@@ -76,12 +76,16 @@ class OpDef:
         # Kernel-routing context is part of the key: kernels may lower
         # differently inside a fused program vs a standalone executable
         # (e.g. rms_norm keeps the jnp composition under to_static so XLA
-        # fuses it, but takes the Pallas kernel as a per-op launch) and per
-        # the Pallas flag — a cached jaxpr from one context must not leak
-        # into the other.
+        # fuses it, but takes the Pallas kernel as a per-op launch), per
+        # the Pallas flag, and per kernel-mesh scope (Mosaic kernels trace
+        # to a shard_map over THAT mesh) — a cached jaxpr from one context
+        # must not leak into the other.
+        from .pallas import active_kernel_mesh
+
         key = (_freeze(attrs), tuple(_struct_key(v) for v in in_vals),
                _random_mod.in_whole_graph_trace(),
-               bool(flag("FLAGS_use_pallas_kernels")))
+               bool(flag("FLAGS_use_pallas_kernels")),
+               active_kernel_mesh())
         fn = self._jit_cache.get(key)
         if fn is None:
             kernel = self.kernel

@@ -20,7 +20,6 @@ C ABI for v1 (elementwise, float32):
 from __future__ import annotations
 
 import ctypes
-import hashlib
 import os
 
 import numpy as np
@@ -51,20 +50,17 @@ def load(name, sources, functions=None, extra_cxx_cflags=None, verbose=False,
     import jax
     import jax.numpy as jnp
 
-    from ..native import build_library, _here
+    from ..native import build_library
     from ..ops.registry import OPS, apply_op, register_op
 
-    # copy sources beside the native dir so the cache key is stable
     src_paths = []
     for s in sources:
         if os.path.exists(s):
             src_paths.append(os.path.abspath(s))
         else:
             raise FileNotFoundError(s)
-    digest = hashlib.sha256(
-        b"".join(open(p, "rb").read() for p in src_paths)).hexdigest()[:12]
-    libname = f"ext_{name}_{digest}"
-    out = build_library(libname, sources=src_paths,
+    # build_library keys the binary on a hash of sources + flags
+    out = build_library(f"ext_{name}", sources=src_paths,
                         extra_flags=list(extra_cxx_cflags or []))
     if out is None:
         raise RuntimeError(f"compilation of extension {name!r} failed")

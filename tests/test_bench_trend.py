@@ -43,12 +43,12 @@ def test_every_checked_in_round_parses(bt):
     data = bt.collect(str(_ROOT))
     assert data["baseline"] is not None
     rounds = {r["name"]: r for r in data["rounds"]}
-    assert len(rounds) >= 6
+    assert len(rounds) >= 4  # r01, r04, r05, r06
     errors = {n: r["error"] for n, r in rounds.items() if r["error"]}
     assert not errors, f"unparseable bench rounds: {errors}"
     # r01 recorded nothing (empty tail) — data-free, not broken
     assert rounds["BENCH_r01"]["metrics"] is None
-    # r02-r04: the parsed dict; r05/r06: recovered from the tail
+    # r04: the parsed dict; r05/r06: recovered from the tail
     assert rounds["BENCH_r04"]["source"] == "parsed"
     assert rounds["BENCH_r05"]["source"] == "tail-braced"
     assert rounds["BENCH_r06"]["source"] == "tail"

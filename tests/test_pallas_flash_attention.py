@@ -282,3 +282,49 @@ def test_build_segments_rejects_shared_ids_cross_attention():
         2, 16, 32, segment_ids=(np.zeros((2, 16), np.int32),
                                 np.zeros((2, 32), np.int32)))
     assert q_seg.shape == (2, 16) and k_seg.shape == (2, 32)
+
+
+def test_attention_op_traces_over_the_active_kernel_mesh():
+    """Inside ``kernel_mesh`` the attention op — reached through the op
+    surface and its per-op jit cache — traces to a ``shard_map`` over THAT
+    scope's mesh, and to none outside a scope, whatever was traced before
+    at the same shapes. (The cache keys on the scope; without that key a
+    cross-mesh pipeline's later stages got the first stage's devices —
+    tests/test_cross_mesh_pipeline.py fails.)"""
+    import contextlib
+
+    import jax
+    from jax.sharding import Mesh
+
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.ops.pallas import kernel_mesh
+
+    q = np.random.rand(2, 128, 4, 32).astype(np.float32)
+
+    def shard_map_meshes(jaxpr):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "shard_map":
+                found.append(eqn.params["mesh"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += shard_map_meshes(sub)
+        return found
+
+    def traced(scope):
+        def f(x):
+            with scope:
+                t = Tensor._from_value(x)
+                return paddle.scaled_dot_product_attention(
+                    t, t, t, is_causal=True)._value
+        return shard_map_meshes(jax.jit(f).trace(q).jaxpr.jaxpr)
+
+    devs = np.array(jax.devices())
+    mesh_a, mesh_b = Mesh(devs[:2], ("mp",)), Mesh(devs[2:4], ("mp",))
+    in_a = traced(kernel_mesh(mesh_a, head_axis="mp"))
+    plain = traced(contextlib.nullcontext())
+    in_b = traced(kernel_mesh(mesh_b, head_axis="mp"))
+    assert plain == []
+    assert in_a and all(m.devices.tolist() == mesh_a.devices.tolist()
+                        for m in in_a)
+    assert in_b and all(m.devices.tolist() == mesh_b.devices.tolist()
+                        for m in in_b)

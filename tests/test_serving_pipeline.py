@@ -330,31 +330,59 @@ def test_warmup_precompiles_every_shape_zero_compiles_after():
     assert compiles == [], f"post-warmup run compiled {len(compiles)} programs"
 
 
-def test_warmup_cache_dir_wires_persistent_cache(tmp_path):
+@pytest.mark.parametrize("from_env", [True, False],
+                         ids=["env_dir", "checkout_dir"])
+def test_compilation_cache_is_placed_from_outside(tmp_path, monkeypatch,
+                                                  from_env):
+    """``jit.enable_compilation_cache()`` takes no directory: with
+    ``JAX_COMPILATION_CACHE_DIR`` set the cache is THAT directory and the
+    program sets no other; unset, it is the one fixed path at the root
+    of the checkout."""
     import os
 
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    m = _model()
-    eng = _engine(m, max_slots=2, max_len=32, prompt_buckets=(8,))
-    before = jax.config.jax_compilation_cache_dir
-    cache = str(tmp_path / "jaxcache")
+    from paddle_tpu import jit as pjit
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if from_env:
+        want = str(tmp_path / "jaxcache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        want = os.path.join(repo, ".jax_cache")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    dirs_set = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            dirs_set.append(value)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
     try:
-        info = eng.warmup(segment=2, cache_dir=cache)
-        assert jax.config.jax_compilation_cache_dir == cache
-        assert info["programs"] >= 3  # 2 widths x 1 bucket + segment
-        # the warmup compiles really landed on disk (jax latches cache
-        # initialization at first compile; enable_compilation_cache must
-        # reset it or the directory is silently ignored)
-        assert os.path.isdir(cache) and len(os.listdir(cache)) > 0
+        assert pjit.enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        if from_env:
+            # the warmup compiles really land on disk there (jax latches
+            # cache initialization at first compile; the helper resets
+            # it or the directory would be ignored)
+            eng = _engine(_model(), max_slots=2, max_len=32,
+                          prompt_buckets=(8,))
+            info = eng.warmup(segment=2)
+            assert info["programs"] >= 3  # 2 widths x 1 bucket + segment
+            assert os.path.isdir(want) and len(os.listdir(want)) > 0
+        assert dirs_set == [want]  # set once, to no other directory
     finally:
-        jax.config.update("jax_compilation_cache_dir", before)
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
+        monkeypatch.undo()
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 def test_warmed_engine_matches_unwarmed_tokens():

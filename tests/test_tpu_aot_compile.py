@@ -1,0 +1,211 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached (``v5e:2x2``). These cases hand it the
+main path's Pallas kernels at the widths ``chip_smoke.py`` runs — what
+interpret mode cannot show: block shapes Mosaic refuses, vector layouts it
+cannot infer, kernels GSPMD cannot partition. Nothing runs, so nothing here
+says anything about results or times; a compile that passes is not a chip
+run. Code that asks ``jax.default_backend()`` would take its CPU branch, so
+the fixture steers that HERE (no option of the program does). JAX's
+persistent compile cache is off around the module: it can write such an
+executable but not read it back without a chip.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu as paddle
+from paddle_tpu.jit.fusion import fuse_elementwise_chains
+from paddle_tpu.models import LlamaConfig
+from paddle_tpu.models.llama import LlamaDecoderLayer
+from paddle_tpu.ops import pallas
+from paddle_tpu.ops.pallas.decode_attention import paged_attention
+from paddle_tpu.ops.pallas.decode_megakernel import (
+    fused_decode_layer,
+    megakernel_layer_supported,
+)
+from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.fused_ops import fused_rope
+from paddle_tpu.ops.pallas.rms_norm import rms_norm
+
+BF16 = jnp.bfloat16
+MOSAIC_CALL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _as_if_on_the_chip(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, topo, *shapes):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in shapes]
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(32, 32), (32, 8)],
+                         ids=["mha32x128", "gqa32_8x128"])
+def test_paged_attention_compiles(topo, heads, kv_heads):
+    b, pages, page, per_seq, d = 8, 512, 128, 32, 128
+    _compile(paged_attention, topo,
+             ((b, heads, d), BF16), ((pages, page, kv_heads, d), BF16),
+             ((pages, page, kv_heads, d), BF16), ((b, per_seq), jnp.int32),
+             ((b,), jnp.int32))
+
+
+def test_flash_attention_fwd_bwd_compiles(topo):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, is_causal=True).astype(
+            jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), topo,
+             *[((2, 2048, 32, 128), BF16)] * 3)
+
+
+def _decode_layer_shapes(hidden, heads, kv_heads, d, b=8, pages=65,
+                         page=128, per_seq=8):
+    return (((b, 1, hidden), BF16), ((hidden,), BF16), ((hidden,), BF16),
+            ((hidden, heads * d), BF16), ((hidden, kv_heads * d), BF16),
+            ((hidden, kv_heads * d), BF16), ((heads * d, hidden), BF16),
+            ((2048, d), jnp.float32), ((2048, d), jnp.float32),
+            ((pages, page, kv_heads, d), BF16),
+            ((pages, page, kv_heads, d), BF16),
+            ((b, per_seq), jnp.int32), ((b,), jnp.int32))
+
+
+def _decode_layer(heads, dump_page):
+    def fn(x, ln1, ln2, wq, wk, wv, wo, cos, sin, kp, vp, tables, lens):
+        return fused_decode_layer(
+            x, ln1_weight=ln1, ln1_eps=1e-6, wq=wq, wk=wk, wv=wv, wo=wo,
+            rope_cos=cos, rope_sin=sin, ln2_weight=ln2, ln2_eps=1e-6,
+            k_pages=kp, v_pages=vp, tables=tables, lengths=lens,
+            heads=heads, dump_page=dump_page)
+    return fn
+
+
+def test_fused_decode_layer_compiles_at_widest_admitted_width(topo):
+    """hidden 1152 = 9 heads x 128 is the widest MHA width whose attention
+    projections fit the probe's VMEM budget (``chip_smoke.MEGAKERNEL``).
+    The fused engine's segment program also runs the elementwise-chain
+    fusion pass over the kernel: it must leave the kernel body alone (the
+    Pallas TPU lowering has no rule for ``closed_call``)."""
+    _compile(fuse_elementwise_chains(_decode_layer(9, dump_page=64)), topo,
+             *_decode_layer_shapes(1152, 9, 9, 128))
+
+
+def test_fused_decode_layer_compiles_in_write_back_mode(topo):
+    _compile(_decode_layer(2, dump_page=None), topo,
+             *_decode_layer_shapes(256, 2, 2, 128))
+
+
+def test_rms_norm_and_fused_rope_compile_at_hidden_4096(topo):
+    def norm_loss(x, w):
+        return rms_norm(x, w, jnp.zeros_like(w), 1e-6, False).astype(
+            jnp.float32).sum()
+
+    _compile(jax.grad(norm_loss, argnums=(0, 1)), topo,
+             ((2, 1024, 4096), BF16), ((4096,), BF16))
+
+    def rope_loss(q, k, cos, sin):
+        oq, ok = fused_rope(q, k, cos, sin)
+        return (oq.astype(jnp.float32).sum()
+                + ok.astype(jnp.float32).sum())
+
+    _compile(jax.grad(rope_loss, argnums=(0, 1)), topo,
+             ((2, 1024, 32, 128), BF16), ((2, 1024, 32, 128), BF16),
+             ((1024, 128), jnp.float32), ((1024, 128), jnp.float32))
+
+
+def test_kernels_partition_over_a_four_chip_mesh(topo):
+    """GSPMD cannot partition a Mosaic kernel; under ``kernel_mesh`` the
+    attention family is partitioned by ``shard_map`` instead — heads over
+    the TP axis for serving, batch x heads for the dp x mp train step."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
+
+    def aval(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    def decode(q, kp, vp, tables, lens):
+        with pallas.kernel_mesh(mesh, head_axis="mp"):
+            return paged_attention(q, kp, vp, tables, lens)
+
+    pool = aval((64, 128, 32, 128), BF16, None, None, "mp", None)
+    text = jax.jit(decode).lower(
+        aval((4, 32, 128), BF16, None, "mp", None), pool, pool,
+        aval((4, 8), jnp.int32), aval((4,), jnp.int32)).compile().as_text()
+    assert MOSAIC_CALL in text
+
+    def train(q, k, v):
+        with pallas.kernel_mesh(mesh, batch_axes=("dp",), head_axis="mp"):
+            return flash_attention(q, k, v, is_causal=True).astype(
+                jnp.float32).sum()
+
+    qkv = aval((4, 1024, 32, 128), BF16, "dp", None, "mp", None)
+    text = jax.jit(jax.grad(train, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    assert MOSAIC_CALL in text
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(lambda q, k, v: flash_attention(q, k, v, is_causal=True)
+                ).lower(qkv, qkv, qkv)
+
+
+def _layer(hidden, heads):
+    cfg = LlamaConfig(vocab_size=64, hidden_size=hidden,
+                      intermediate_size=128, num_hidden_layers=1,
+                      num_attention_heads=heads, max_position_embeddings=8)
+    paddle.seed(0)
+    before = paddle.get_default_dtype()
+    paddle.set_default_dtype("bfloat16")
+    try:
+        return LlamaDecoderLayer(cfg)
+    finally:
+        paddle.set_default_dtype(before)
+
+
+@pytest.mark.parametrize("hidden,heads,admitted", [
+    (1152, 9, True),      # the widest MHA width within the VMEM budget
+    (1024, 16, False),    # head_dim 64: Mosaic cannot lay the rows out
+    (4096, 32, False),    # LLaMA-7B projections: 128 MiB, far past VMEM
+], ids=["h1152_9x128", "head_dim_64", "h4096_32x128"])
+def test_megakernel_probe_declines_what_the_compiler_refuses(
+        hidden, heads, admitted):
+    assert megakernel_layer_supported(_layer(hidden, heads)) is admitted
+
+
+def test_megakernel_probe_admits_head_dim_64_only_interpreted(monkeypatch):
+    """The lane-width rule binds only where Mosaic compiles the kernel."""
+    layer = _layer(256, 4)
+    assert not megakernel_layer_supported(layer)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert megakernel_layer_supported(layer)
