@@ -277,8 +277,8 @@ def cached_attention(q, k, v, cache, offset, s):
     if paged:
         # attention reads at most ``attn_pages`` table columns (the
         # serving engine's dynamic tables carry trailing write-scratch
-        # columns past max_len — reads must not pay grid steps or
-        # gather width for them)
+        # columns past max_len: the static ceiling of the kernel's work
+        # list and of the fallback's gather width stops before them)
         ap = getattr(cache, "attn_pages", None)
         if s == 1 and use_kernel:
             out = paged_attention(

@@ -126,6 +126,14 @@ _M_REQS = telemetry.counter(
 _M_MEGA_SEG = telemetry.counter(
     "serving.megakernel_segments", "decode segments dispatched through "
     "the fused megakernel program (FLAGS_decode_megakernel)")
+_M_ATTN_LIVE = telemetry.counter(
+    "serving.attn_pages_live_total", "pages that hold tokens at a decode "
+    "segment's dispatch, sum of ceil(length / page) over every slot (idle "
+    "ones park at length 1): what paged attention walks")
+_M_ATTN_TABLE = telemetry.counter(
+    "serving.attn_pages_table_total", "max_slots x attention-visible "
+    "table columns per decode segment dispatched; live over table is the "
+    "share of the page table that held tokens")
 # KV-occupancy accounting (perfwatch): the measurement side of the
 # paged-KV roadmap item — logical occupancy of the preallocated page
 # pool, not PJRT allocator bytes (the pool is allocated up front; the
@@ -1629,8 +1637,12 @@ class ContinuousBatchingEngine:
                         self._tables_device(),
                         lengths, toks, active, self._limits_device(), keys)
             self._seg_runs += 1
-            if self._megakernel and telemetry.enabled():
-                _M_MEGA_SEG.inc()
+            if telemetry.enabled():
+                _M_ATTN_LIVE.inc(int(
+                    (-(-self._lengths // self.page_size)).sum()))
+                _M_ATTN_TABLE.inc(self.max_slots * self._cols)
+                if self._megakernel:
+                    _M_MEGA_SEG.inc()
         return {"emitted": emitted, "was_active": was_active, "tok": tok,
                 "lengths": new_lengths, "active": still_active,
                 "mask": np.asarray(mask), "disp": d}

@@ -6,10 +6,13 @@ TPU-native re-emission of the reference's decode kernel pair:
   (/root/reference/paddle/phi/kernels/fusion/gpu/
   block_multi_head_attention_kernel.cu): the KV cache lives in fixed-size
   pages shared by all sequences; a per-sequence block table maps logical
-  cache positions to physical pages. The page indices ride as
-  scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``) so each grid
-  step's page DMA is issued from the block table before the body runs —
-  the TPU shape of the CUDA kernel's gather-from-block-table.
+  cache positions to physical pages. The kernel walks a WORK LIST of the
+  pages that hold tokens, built from ``lengths`` and the block table with
+  a few XLA ops and handed over as scalar-prefetch operands
+  (``pltpu.PrefetchScalarGridSpec``), so each grid step's page DMA is
+  issued before the body runs — the TPU shape of the CUDA kernel's
+  gather-from-block-table — and a call costs what its live pages cost,
+  not what the table could hold.
 * ``masked_decode_attention`` — the analog of masked decode MHA
   (masked_multihead_attention_kernel.cu): single-token queries attending
   over a fixed-size contiguous cache with a per-sequence valid length.
@@ -18,9 +21,12 @@ TPU-native re-emission of the reference's decode kernel pair:
 
 Layouts: q (B, H, D) one decode token per sequence; pages
 (num_pages, page_size, KV_HEADS, D); block_tables (B, pages_per_seq) int32;
-lengths (B,) int32. GQA folds query-head groups onto kv heads in the index
-map. Online softmax in f32; each (b, h) accumulates across its pages via
-VMEM scratch carried over the innermost grid dim.
+lengths (B,) int32. The grid is one-dimensional over the work list, its
+bound the number of live pages (a dynamic grid dimension under the static
+ceiling B * pages_per_seq); a row's pages are consecutive items in table
+order, so each (b) accumulates across its pages via VMEM scratch (online
+softmax in f32), initialised at the row's first page and written out at
+its last. GQA folds query-head groups onto kv heads inside the body.
 """
 from __future__ import annotations
 
@@ -48,11 +54,13 @@ def paged_attention_supported(q, k_pages):
     return h % kvh == 0 and q.shape[2] == k_pages.shape[3]
 
 
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale, page_size, pages_per_seq,
+def _decode_kernel(rows_ref, pages_ref, phys_ref, lens_ref, q_ref, k_ref,
+                   v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, page_size,
                    kvh):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+    item = pl.program_id(0)
+    b = rows_ref[item]
+    p = pages_ref[item]
+    length = lens_ref[b]
 
     @pl.when(p == 0)
     def _init():
@@ -60,10 +68,8 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = lens_ref[b]
-    valid = p * page_size < length
-
-    @pl.when(valid)
+    # false only on the one item a row of length 0 holds
+    @pl.when(p * page_size < length)
     def _accumulate():
         h, d = q_ref.shape[1], q_ref.shape[2]
         group = h // kvh
@@ -98,7 +104,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[:, :] = alpha * acc_ref[:, :] + jnp.concatenate(
             pv_parts, axis=0)
 
-    @pl.when(p == pages_per_seq - 1)
+    @pl.when((p + 1) * page_size >= length)
     def _finalize():
         o_ref[0, :, :] = (
             acc_ref[:, :] / jnp.maximum(l_ref[:, :], 1e-30)
@@ -114,18 +120,19 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
     lengths: (B,) int32 valid context length per sequence.
     Returns (B, H, D).
 
-    ``pages_per_seq`` bounds how many table columns the grid walks per
-    sequence (static slice). Dynamic serving tables are RAGGED: rows
-    hold however many pages their slot was granted, padded with
-    scratch-alias columns the kernel must not pay grid steps for — the
-    per-page ``valid`` mask already skips DMA'd pages past ``lengths``,
-    but the grid itself is static, so the caller caps it here.
+    ``pages_per_seq`` is the static ceiling of the table's width (a
+    static slice): dynamic serving tables carry trailing write-scratch
+    columns that attention never reads. Inside it the kernel does work
+    in proportion to ``sum(ceil(lengths / page_size))``: the grid walks
+    the pages that hold tokens and no others, a row of length 0 yields
+    zeros, and a table entry past ``lengths[b]`` is never attended to
+    nor used as an address outside the pool.
 
     Block shapes keep the last two dims equal to full array dims
     ((H, D) for q/out, (KVH, D) for pages) — the Mosaic lowering
     requirement — so all query heads of one token are processed per grid
     step, with the per-(b) online-softmax state carried in VMEM scratch
-    across the page dimension.
+    across the row's pages.
     """
     if (pages_per_seq is not None
             and pages_per_seq < block_tables.shape[1]):
@@ -137,28 +144,46 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
         ("bh.", "..h.", "..h.", "b.", "b"), "bh.")
 
 
+def _work_list(block_tables, lengths, page_size, npages):
+    """The (row, page) pairs that hold tokens, row-major, as three int32
+    vectors of the static ceiling ``b * pages_per_seq`` — each item's row,
+    its page's column in the row's table and that page's id in the pool —
+    and how many of them are real. A row of length 0 still gets one item:
+    it attends to nothing, but its output block has to be written. Table
+    entries are clamped into the pool, so a garbage entry (only such a
+    row's first can be reached) never addresses memory outside it."""
+    b, pages_per_seq = block_tables.shape
+    counts = jnp.maximum(pl.cdiv(lengths, page_size), 1)
+    ends = jnp.cumsum(counts)
+    item = jnp.arange(b * pages_per_seq, dtype=jnp.int32)
+    done = item[:, None] >= ends[None, :]   # the rows wholly before an item
+    rows = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), b - 1)
+    pages = jnp.minimum(
+        item - jnp.sum(jnp.where(done, counts[None, :], 0), axis=1),
+        pages_per_seq - 1)
+    phys = jnp.clip(block_tables[rows, pages], 0, npages - 1)
+    return rows, pages, phys, ends[-1]
+
+
 def _paged_attention(q, k_pages, v_pages, block_tables, lengths):
     b, h, d = q.shape
     npages, page_size, kvh, _ = k_pages.shape
-    pages_per_seq = block_tables.shape[1]
     scale = 1.0 / math.sqrt(d)
+    # the table's width is the static ceiling of a row's pages
+    lengths = jnp.minimum(lengths, block_tables.shape[1] * page_size)
+    # built here, inside over_mesh's shard_map, from the shard's own rows
+    rows, pages, phys, total = _work_list(block_tables, lengths, page_size,
+                                          npages)
 
-    grid = (b, pages_per_seq)
+    def q_map(i, rows, pages, phys, lens):
+        return (rows[i], 0, 0)
 
-    def q_map(bi, pi, tables, lens):
-        return (bi, 0, 0)
-
-    def kv_map(bi, pi, tables, lens):
-        # Table tails past lengths[b] may be uninitialized in real paged
-        # serving: redirect the (masked-anyway) DMA to the row's first page
-        # and clamp into the pool, so garbage entries never address memory.
-        pid = jnp.where(pi * page_size < lens[bi], tables[bi, pi],
-                        tables[bi, 0])
-        return (jnp.clip(pid, 0, npages - 1), 0, 0, 0)
+    def kv_map(i, rows, pages, phys, lens):
+        return (phys[i], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
+        num_scalar_prefetch=4,
+        grid=(total,),
         in_specs=[
             pl.BlockSpec((1, h, d), q_map),
             pl.BlockSpec((1, page_size, kvh, d), kv_map),
@@ -172,15 +197,14 @@ def _paged_attention(q, k_pages, v_pages, block_tables, lengths):
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, scale=scale, page_size=page_size,
-        pages_per_seq=pages_per_seq, kvh=kvh)
+        _decode_kernel, scale=scale, page_size=page_size, kvh=kvh)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         name="paged_attention",
         interpret=_interpret(),
-    )(block_tables, lengths, q, k_pages, v_pages)
+    )(rows, pages, phys, lengths, q, k_pages, v_pages)
 
 
 def masked_decode_attention(q, k_cache, v_cache, lengths, page_size=None):

@@ -72,10 +72,14 @@ def _compile(fn, topo, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(32, 32), (32, 8)],
-                         ids=["mha32x128", "gqa32_8x128"])
-def test_paged_attention_compiles(topo, heads, kv_heads):
-    b, pages, page, per_seq, d = 8, 512, 128, 32, 128
+@pytest.mark.parametrize("b,heads,kv_heads,pages", [
+    (8, 32, 32, 512),
+    (8, 32, 8, 512),
+    # the mistral7b-chat-open cell: 32 slots, 512 pool pages + 1 scratch
+    (32, 32, 8, 513),
+], ids=["mha32x128", "gqa32_8x128", "chat_cell_b32"])
+def test_paged_attention_compiles(topo, b, heads, kv_heads, pages):
+    page, per_seq, d = 128, 32, 128
     _compile(paged_attention, topo,
              ((b, heads, d), BF16), ((pages, page, kv_heads, d), BF16),
              ((pages, page, kv_heads, d), BF16), ((b, per_seq), jnp.int32),
@@ -151,19 +155,27 @@ def test_kernels_partition_over_a_four_chip_mesh(topo):
     the TP axis for serving, batch x heads for the dp x mp train step."""
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
 
-    def aval(shape, dtype, *spec):
+    def aval(shape, dtype, *spec, mesh=mesh):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, P(*spec)))
 
-    def decode(q, kp, vp, tables, lens):
-        with pallas.kernel_mesh(mesh, head_axis="mp"):
-            return paged_attention(q, kp, vp, tables, lens)
+    # serving splits heads over the TP axis; every row is on every chip.
+    # Second case: the chat cell's shape as TPShardedEngine lays it over
+    # four chips, 8 query / 2 KV heads a chip
+    for over, b, kv_heads, pages, cols in (
+            (mesh, 4, 32, 64, 8),
+            (Mesh(np.array(topo.devices), ("mp",)), 32, 8, 513, 32)):
+        def decode(q, kp, vp, tables, lens):
+            with pallas.kernel_mesh(over, head_axis="mp"):
+                return paged_attention(q, kp, vp, tables, lens)
 
-    pool = aval((64, 128, 32, 128), BF16, None, None, "mp", None)
-    text = jax.jit(decode).lower(
-        aval((4, 32, 128), BF16, None, "mp", None), pool, pool,
-        aval((4, 8), jnp.int32), aval((4,), jnp.int32)).compile().as_text()
-    assert MOSAIC_CALL in text
+        pool = aval((pages, 128, kv_heads, 128), BF16, None, None, "mp",
+                    None, mesh=over)
+        text = jax.jit(decode).lower(
+            aval((b, 32, 128), BF16, None, "mp", None, mesh=over), pool,
+            pool, aval((b, cols), jnp.int32, mesh=over),
+            aval((b,), jnp.int32, mesh=over)).compile().as_text()
+        assert MOSAIC_CALL in text
 
     def train(q, k, v):
         with pallas.kernel_mesh(mesh, batch_axes=("dp",), head_axis="mp"):
