@@ -27,6 +27,16 @@ __all__ = [
     "llama_shard_fn", "llama_tiny_config",
 ]
 
+from .moe_mla import (  # noqa: F401
+    MoEMLAConfig,
+    MoEMLAForCausalLM,
+    MoEMLAModel,
+    moe_mla_tiny_config,
+)
+
+__all__ += ["MoEMLAConfig", "MoEMLAForCausalLM", "MoEMLAModel",
+            "moe_mla_tiny_config"]
+
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

@@ -69,9 +69,23 @@ def _make_static_cache(k, v, length):
     return c
 
 
+def kv_page_shapes(model):
+    """The trailing shapes of a layer's two cache buffers, after (pages,
+    page) or (batch, max_len): the model's own where it has a say
+    (``kv_page_shapes()``: a latent cache keeps no per-head keys), else
+    (kv heads, head size) twice, from its config."""
+    own = getattr(model, "kv_page_shapes", None)
+    if own is not None:
+        k_shape, v_shape = own()
+        return tuple(k_shape), tuple(v_shape)
+    cfg = model.config
+    kv = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+    return (kv, cfg.head_dim), (kv, cfg.head_dim)
+
+
 def _make_paged_cache(kp, vp, tables, page_size, length,
                       aligned_bases=False, attn_pages=None,
-                      dump_page=None):
+                      dump_page=None, live=None):
     from .llama import PagedKVCache
 
     c = PagedKVCache.__new__(PagedKVCache)
@@ -85,6 +99,8 @@ def _make_paged_cache(kp, vp, tables, page_size, length,
     # sacrificial page absorbing the decode megakernel's non-append
     # page flushes (the engine's dump page)
     c.dump_page = dump_page
+    c.live = live      # (B,) rows that hold a sequence, or None: all
+    c.stats = None     # what a layer counted this step, if it counts
     return c
 
 
@@ -214,7 +230,7 @@ def build_serve_fn(model, max_new_tokens, do_sample=False, temperature=1.0,
     from .llama import PagedKVCache, StaticCache
 
     cfg = model.config
-    kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+    shapes = kv_page_shapes(model)
     n_layers = cfg.num_hidden_layers
     functional = _FunctionalModel(model)
     buffers = {k: bu._value for k, bu in model.named_buffers()}
@@ -231,8 +247,8 @@ def build_serve_fn(model, max_new_tokens, do_sample=False, temperature=1.0,
         if paged:
             page = 128
             padded = ((max_len + page - 1) // page) * page
-            empty = [PagedKVCache(b, padded, kv_heads, cfg.head_dim,
-                                  page_size=page, dtype=cache_dtype)
+            empty = [PagedKVCache(b, padded, page_size=page,
+                                  dtype=cache_dtype, shapes=shapes)
                      for _ in range(n_layers)]
             tables = empty[0].tables
             page_size = empty[0].page_size
@@ -244,8 +260,8 @@ def build_serve_fn(model, max_new_tokens, do_sample=False, temperature=1.0,
             ks0 = [c.k_pages for c in empty]
             vs0 = [c.v_pages for c in empty]
         else:
-            empty = [StaticCache(b, max_len, kv_heads, cfg.head_dim,
-                                 dtype=cache_dtype) for _ in range(n_layers)]
+            empty = [StaticCache(b, max_len, dtype=cache_dtype,
+                                 shapes=shapes) for _ in range(n_layers)]
 
             def rebuild(ks, vs, length):
                 return [_make_static_cache(ks[i], vs[i], length)
@@ -320,7 +336,7 @@ def generate(model, input_ids, max_new_tokens=20, do_sample=False,
         return Tensor._from_value(ids)
     b, s = ids.shape
     cfg = model.config
-    kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+    shapes = kv_page_shapes(model)
     max_len = s + max_new_tokens
     maxp = getattr(cfg, "max_position_embeddings", None)
     # the FINAL sampled token is appended but never fed back, so with
@@ -345,12 +361,11 @@ def generate(model, input_ids, max_new_tokens=20, do_sample=False,
     if cache == "paged":
         page = 128
         padded = ((max_len + page - 1) // page) * page
-        empty = [PagedKVCache(b, padded, kv_heads, cfg.head_dim,
-                              page_size=page, dtype=cache_dtype)
+        empty = [PagedKVCache(b, padded, page_size=page, dtype=cache_dtype,
+                              shapes=shapes)
                  for _ in range(cfg.num_hidden_layers)]
     else:
-        empty = [StaticCache(b, max_len, kv_heads, cfg.head_dim,
-                             dtype=cache_dtype)
+        empty = [StaticCache(b, max_len, dtype=cache_dtype, shapes=shapes)
                  for _ in range(cfg.num_hidden_layers)]
 
     if use_jit:

@@ -112,9 +112,14 @@ class StaticCache:
 
     __slots__ = ("k", "v", "length")
 
-    def __init__(self, batch, max_len, kv_heads, head_dim, dtype=jnp.float32):
-        self.k = jnp.zeros((batch, max_len, kv_heads, head_dim), dtype)
-        self.v = jnp.zeros((batch, max_len, kv_heads, head_dim), dtype)
+    def __init__(self, batch, max_len, kv_heads=None, head_dim=None,
+                 dtype=jnp.float32, shapes=None):
+        # ``shapes``: what a token keeps, as the two buffers' trailing
+        # shapes, where the model says so (``kv_page_shapes``: a latent
+        # cache has no kv heads); else (kv_heads, head_dim) twice
+        k_shape, v_shape = shapes or ((kv_heads, head_dim),) * 2
+        self.k = jnp.zeros((batch, max_len) + tuple(k_shape), dtype)
+        self.v = jnp.zeros((batch, max_len) + tuple(v_shape), dtype)
         self.length = 0  # concrete python int: static under per-step jit
 
     def update(self, k_new, v_new):
@@ -146,19 +151,22 @@ class PagedKVCache:
     over this layout runs the Pallas ``paged_attention`` kernel."""
 
     __slots__ = ("k_pages", "v_pages", "tables", "page_size", "length",
-                 "aligned_bases", "attn_pages", "dump_page")
+                 "aligned_bases", "attn_pages", "dump_page", "live", "stats")
 
-    def __init__(self, batch, max_len, kv_heads, head_dim, page_size=128,
-                 dtype=jnp.float32):
+    def __init__(self, batch, max_len, kv_heads=None, head_dim=None,
+                 page_size=128, dtype=jnp.float32, shapes=None):
         page_size = min(page_size, max_len)
         if max_len % page_size:
             raise ValueError(
                 f"max_len {max_len} not divisible by page_size {page_size}")
         per_seq = max_len // page_size
         num_pages = batch * per_seq
-        self.k_pages = jnp.zeros((num_pages, page_size, kv_heads, head_dim),
+        # ``shapes`` as in StaticCache: the pools' trailing shapes
+        k_shape, v_shape = shapes or ((kv_heads, head_dim),) * 2
+        self.k_pages = jnp.zeros((num_pages, page_size) + tuple(k_shape),
                                  dtype)
-        self.v_pages = jnp.zeros_like(self.k_pages)
+        self.v_pages = jnp.zeros((num_pages, page_size) + tuple(v_shape),
+                                 dtype)
         self.tables = (jnp.arange(per_seq, dtype=jnp.int32)[None, :] * batch
                        + jnp.arange(batch, dtype=jnp.int32)[:, None])
         self.page_size = page_size
@@ -176,6 +184,12 @@ class PagedKVCache:
         # non-append page flushes (None = no spare page: the kernel
         # writes visited pages back in place instead)
         self.dump_page = None
+        # (B,) rows that hold a sequence, where the caller knows (the
+        # serving engine's decode segment): a layer whose cost follows its
+        # rows (sparse experts) leaves the others out. And what such a
+        # layer counted this step, for the caller to carry out.
+        self.live = None
+        self.stats = None
 
     def update(self, k_new, v_new):
         """Write (B, S, KVH, D) new keys/values at positions

@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import math
 import time
 import uuid
 import zlib
@@ -76,7 +77,7 @@ from ..core.resilience import (
 )
 from ..core.tensor import Tensor
 from ..profiler import annotate, record_span
-from .generation import _make_paged_cache, _sample_rows
+from .generation import _make_paged_cache, _sample_rows, kv_page_shapes
 from .kv_pool import PagePool, PrefixCache
 
 __all__ = ["ContinuousBatchingEngine", "Request", "TERMINAL_STATES"]
@@ -358,7 +359,9 @@ class ContinuousBatchingEngine:
         self.eos_token_id = eos_token_id
         self.prompt_buckets = tuple(sorted(prompt_buckets))
         self.pipeline_opt = pipeline
-        kv = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+        # what a token keeps a layer: the model's own page shapes where
+        # it has a say (a latent cache), else (kv heads, head size) twice
+        k_shape, v_shape = kv_page_shapes(model)
         try:
             dtype = next(iter(model.parameters()))._value.dtype
         except StopIteration:
@@ -393,9 +396,10 @@ class ContinuousBatchingEngine:
         self._extra_cols = -(-chunk_w // self.page_size)
         total_cols = per_seq + self._extra_cols
         self._nl = cfg.num_hidden_layers
-        self._ks = [jnp.zeros((n_pages, self.page_size, kv, cfg.head_dim),
-                              dtype) for _ in range(self._nl)]
-        self._vs = [jnp.zeros_like(k) for k in self._ks]
+        self._ks = [jnp.zeros((n_pages, self.page_size) + k_shape, dtype)
+                    for _ in range(self._nl)]
+        self._vs = [jnp.zeros((n_pages, self.page_size) + v_shape, dtype)
+                    for _ in range(self._nl)]
         # any table cell not backed by a granted page aliases the DUMP
         # page (the last scratch page): writes there are garbage by
         # construction and reads never reach it (attention masks by
@@ -446,7 +450,17 @@ class ContinuousBatchingEngine:
         # KV accounting invariants (perfwatch): bytes one token's K+V
         # rows cost across all layers, at the cache dtype
         self._kv_bytes_per_token = int(
-            self._nl * 2 * kv * cfg.head_dim * np.dtype(dtype).itemsize)
+            self._nl * (math.prod(k_shape) + math.prod(v_shape))
+            * np.dtype(dtype).itemsize)
+        # a model that counts (sparse experts: expert load) names what
+        # its layers' per-step statistics are; the decode segment carries
+        # their sum out and ``_consume`` feeds ``serving.<name>_total``
+        self._stat_names = tuple(getattr(model, "step_stat_names", ()))
+        self._stat_counters = [
+            telemetry.counter(f"serving.{name}_total",
+                              f"{name}, summed over the decode steps and "
+                              "the counting layers of every segment")
+            for name in self._stat_names]
         self._warmed = False
         self._prefill_p = None
         self._segment_p = None
@@ -531,7 +545,7 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------------------ programs
 
-    def _caches(self, ks, vs, tables, length, aligned=None):
+    def _caches(self, ks, vs, tables, length, aligned=None, live=None):
         # chunked-prefill bases are chunk_w multiples: page-aligned (the
         # bulk-write opt-in) exactly when chunk_w is a page multiple;
         # the prefix-RESUME path passes aligned=False — its bases start
@@ -541,7 +555,7 @@ class ContinuousBatchingEngine:
         return [_make_paged_cache(ks[i], vs[i], tables, self.page_size,
                                   length, aligned_bases=aligned,
                                   attn_pages=self._cols,
-                                  dump_page=self._dump_page)
+                                  dump_page=self._dump_page, live=live)
                 for i in range(self._nl)]
 
     def _build_programs(self):
@@ -652,9 +666,13 @@ class ContinuousBatchingEngine:
                     keys):
             def body(carry, key):
                 tok, ks, vs, lengths, active = carry
-                caches = self._caches(ks, vs, tables, lengths)
+                caches = self._caches(ks, vs, tables, lengths, live=active)
                 (logits, caches2), _ = run_model(params, tok[:, None],
                                                  caches)
+                # a counting model's layers leave their step statistics
+                # on their caches; a dense model leaves none, and its
+                # program has no such output
+                counted = [c.stats for c in caches2 if c.stats is not None]
                 nxt = sample_batch(logits[:, -1, :], key)
                 nxt = jnp.where(active, nxt, tok)  # frozen slots emit noise
                 new_lengths = jnp.where(active, lengths + 1, lengths)
@@ -668,11 +686,14 @@ class ContinuousBatchingEngine:
                 ks2 = [c.k_pages for c in caches2]
                 vs2 = [c.v_pages for c in caches2]
                 return ((nxt, ks2, vs2, new_lengths, new_active),
-                        (nxt, active))
+                        (nxt, active, sum(counted) if counted else None))
 
-            (tok, ks, vs, lengths, active), (emitted, was_active) = \
+            (tok, ks, vs, lengths, active), (emitted, was_active, counts) = \
                 jax.lax.scan(body, (toks, ks, vs, lengths, active), keys)
-            return emitted, was_active, tok, lengths, active, ks, vs
+            if counts is None:
+                return emitted, was_active, tok, lengths, active, ks, vs
+            return (emitted, was_active, tok, lengths, active, ks, vs,
+                    jnp.sum(counts, axis=0))
 
         self._prefill_p = jax.jit(prefill, donate_argnums=(1, 2))
         self._chunk_p = jax.jit(chunk_step, donate_argnums=(1, 2))
@@ -891,12 +912,12 @@ class ContinuousBatchingEngine:
         # width export/import chunk programs, warmed so page payloads
         # move between replicas without a single post-warmup trace
         xfer_idx_s = self._op_aval((_XFER_WIDTH,), i32)
-        pay_s = self._op_aval(
-            (len(self._ks), _XFER_WIDTH) + tuple(self._ks[0].shape[1:]),
-            self._ks[0].dtype)
+        payk_s, payv_s = (self._op_aval(
+            (len(pool), _XFER_WIDTH) + tuple(pool[0].shape[1:]),
+            pool[0].dtype) for pool in (self._ks, self._vs))
         compile_(("export", _XFER_WIDTH), self._export_p, xfer_idx_s)
         compile_(("import", _XFER_WIDTH), self._import_p, xfer_idx_s,
-                 pay_s, pay_s)
+                 payk_s, payv_s)
         seg = int(segment if segment is not None
                   else getattr(self, "_segment_len", 16))
         m = self.max_slots
@@ -1631,7 +1652,7 @@ class ContinuousBatchingEngine:
             sp.set(**self._mask_trace_args(mask))
             with annotate("serving.segment_call"):
                 emitted, was_active, tok, new_lengths, still_active, \
-                    self._ks, self._vs = self._call(
+                    self._ks, self._vs, *stats = self._call(
                         ("segment", self._segment_len), self._segment_p,
                         self._params, self._ks, self._vs,
                         self._tables_device(),
@@ -1645,7 +1666,7 @@ class ContinuousBatchingEngine:
                     _M_MEGA_SEG.inc()
         return {"emitted": emitted, "was_active": was_active, "tok": tok,
                 "lengths": new_lengths, "active": still_active,
-                "mask": np.asarray(mask), "disp": d}
+                "stats": stats, "mask": np.asarray(mask), "disp": d}
 
     def _note_host_gap(self, now, sync):
         """The host gap that ends at this dispatch (``serving.host_gap``,
@@ -1672,10 +1693,15 @@ class ContinuousBatchingEngine:
         append emissions, retire finished slots."""
         # the blocking fetch: device compute the pipeline did not hide
         # (plus transfer) — the device share of a decode step
-        with annotate("serving.device_wait", phase="device_wait"):
-            emitted, was_active, cur_tok, lengths, still_active = \
+        with annotate("serving.device_wait", phase="device_wait") as sp:
+            emitted, was_active, cur_tok, lengths, still_active, stats = \
                 jax.device_get((h["emitted"], h["was_active"], h["tok"],
-                                h["lengths"], h["active"]))
+                                h["lengths"], h["active"], h["stats"]))
+            if stats:
+                # the segment's own counts ride on the span of its fetch,
+                # so a reader can sum them over any interval
+                stats = [int(n) for n in stats[0]]
+                sp.set(**dict(zip(self._stat_names, stats)))
         with annotate("serving.host_bookkeeping", phase="host_bookkeeping"):
             # the blocking fetch proves this segment (and every program
             # dispatched before it) executed: quarantined page frees up to
@@ -1716,6 +1742,9 @@ class ContinuousBatchingEngine:
         if telemetry.enabled() and self._useful > useful0:
             # one bump per consumed segment, not per token
             _M_TOKENS.inc(self._useful - useful0)
+        if stats and telemetry.enabled():
+            for counter, n in zip(self._stat_counters, stats):
+                counter.inc(n)
 
     def _drain_pipeline(self, finished, cause="dirty"):
         """Consume the in-flight segment (if any) so the host view of

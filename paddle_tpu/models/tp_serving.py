@@ -175,6 +175,15 @@ class TPShardedEngine(ContinuousBatchingEngine):
 
     def __init__(self, model, max_slots, max_len, mesh=None, tp_axis="mp",
                  plan=None, **kwargs):
+        if hasattr(model, "kv_page_shapes"):
+            # this engine shards the page pools over kv heads and plans
+            # placements for a dense block's projections; a latent cache
+            # has no kv heads and sparse experts no plan (ROADMAP M3)
+            raise NotImplementedError(
+                f"TPShardedEngine cannot serve {type(model).__name__}: its "
+                "latent attention cache has no kv-head axis to shard and "
+                "its sparse experts have no tensor-parallel plan; serve it "
+                "with ContinuousBatchingEngine on one chip")
         if mesh is None:
             from ..distributed.process_mesh import get_mesh
 

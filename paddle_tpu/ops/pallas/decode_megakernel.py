@@ -160,6 +160,11 @@ def megakernel_model_supported(model):
     """True when the model carries at least one decoder layer and EVERY
     decoder layer passes ``megakernel_layer_supported`` (the engine-level
     capability probe behind FLAGS_decode_megakernel)."""
+    if hasattr(model, "kv_page_shapes"):
+        # the kernel fuses a dense block's q/k/v projections over (k, v)
+        # pages; a model that keeps another cache (latent attention)
+        # stays on the unfused segment program
+        return False
     layers = [l for l in model.sublayers()
               if hasattr(l, "self_attn") and hasattr(l, "mlp")
               and hasattr(l, "input_layernorm")

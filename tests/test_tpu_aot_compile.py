@@ -86,6 +86,29 @@ def test_paged_attention_compiles(topo, b, heads, kv_heads, pages):
              ((b,), jnp.int32))
 
 
+def test_paged_mla_attention_compiles_at_the_reason_cell_shapes(topo):
+    """joyai-flash-reason-open: 32 slots, 32 heads against one latent of
+    512 + 64, 1,024 pool pages + 1 scratch, 32 table columns."""
+    from paddle_tpu.ops.pallas.mla_attention import paged_mla_attention
+
+    _compile(lambda *a: paged_mla_attention(*a, 192 ** -0.5,
+                                            pages_per_seq=32), topo,
+             ((32, 32, 512), BF16), ((32, 32, 64), BF16),
+             ((1025, 128, 512), BF16), ((1025, 128, 64), BF16),
+             ((32, 33), jnp.int32), ((32,), jnp.int32))
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (256, 2048, 1536), (256, 768, 2048),        # a decode step: 32 x top-8
+    (32768, 2048, 1536), (32768, 768, 2048),    # a 32 x 128 prefill
+], ids=["decode_gate_up", "decode_down", "prefill_gate_up", "prefill_down"])
+def test_moe_gmm_compiles_at_the_published_expert_shapes(topo, m, k, n):
+    from paddle_tpu.ops.pallas.moe_gmm import moe_gmm
+
+    _compile(moe_gmm, topo, ((m, k), BF16), ((256, k, n), BF16),
+             ((256,), jnp.int32))
+
+
 def test_flash_attention_fwd_bwd_compiles(topo):
     def loss(q, k, v):
         return flash_attention(q, k, v, is_causal=True).astype(

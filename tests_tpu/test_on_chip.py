@@ -420,3 +420,53 @@ def test_continuous_batching_on_chip():
             generate(m, paddle.to_tensor(p[None, :]), max_new_tokens=8,
                      cache="paged")._value)[0, p.size:]
         np.testing.assert_array_equal(outs[i], want, err_msg=f"req {i}")
+
+
+def test_paged_mla_attention_on_chip():
+    """The latent decode kernel at the JoyAI widths (32 heads, latent 512,
+    rope 64, page 128), bf16 pools, scattered tables and ragged lengths,
+    against its jnp oracle computed in float32."""
+    from paddle_tpu.ops.pallas.mla_attention import (
+        mla_attention_reference, paged_mla_attention)
+
+    B, H, C, R, PAGE, NPAGES = 4, 32, 512, 64, 128, 24
+    bf = jnp.bfloat16
+    ql = jnp.asarray(rng.randn(B, H, C).astype(np.float32) * 0.1, bf)
+    qr = jnp.asarray(rng.randn(B, H, R).astype(np.float32) * 0.1, bf)
+    cp = jnp.asarray(rng.randn(NPAGES, PAGE, C).astype(np.float32), bf)
+    rp = jnp.asarray(rng.randn(NPAGES, PAGE, R).astype(np.float32), bf)
+    tables = jnp.asarray(rng.permutation(NPAGES).reshape(B, 6), jnp.int32)
+    lens = jnp.asarray([1, 700, 129, 0], jnp.int32)
+    scale = 1.0 / math.sqrt(192)
+    out = paged_mla_attention(ql, qr, cp, rp, tables, lens, scale)
+    f32 = jnp.float32
+    with jax.default_matmul_precision("highest"):
+        want = mla_attention_reference(ql.astype(f32), qr.astype(f32),
+                                       cp.astype(f32), rp.astype(f32),
+                                       tables, lens, scale)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), rtol=BF16_RTOL,
+                               atol=BF16_ATOL)
+
+
+@pytest.mark.parametrize("m,total", [(256, 152), (4096, 4000)])
+def test_moe_gmm_on_chip(m, total):
+    """The grouped product at the JoyAI expert shapes (K 2048, N 1536,
+    bf16) over 64 experts, most of them empty at the decode size, against
+    its oracle in float32."""
+    from paddle_tpu.ops.pallas.moe_gmm import gmm_reference, moe_gmm
+
+    E, K, N = 64, 2048, 1536
+    sizes = np.zeros(E, np.int32)
+    hit = rng.choice(E, 29, replace=False)
+    sizes[hit] = rng.multinomial(total - 29, [1 / 29] * 29) + 1
+    lhs = jnp.asarray(rng.randn(m, K).astype(np.float32), jnp.bfloat16)
+    rhs = jnp.asarray(rng.randn(E, K, N).astype(np.float32) * 0.02,
+                      jnp.bfloat16)
+    out = moe_gmm(lhs, rhs, jnp.asarray(sizes))
+    with jax.default_matmul_precision("highest"):
+        want = gmm_reference(lhs.astype(jnp.float32),
+                             rhs.astype(jnp.float32), jnp.asarray(sizes))
+    np.testing.assert_allclose(np.asarray(out, np.float32)[:total],
+                               np.asarray(want)[:total], rtol=BF16_RTOL,
+                               atol=BF16_ATOL)
