@@ -12,6 +12,28 @@ MXU/VPU aligned (q/k blocks of 128 rows); accumulation is f32; the backward
 is the standard two-kernel FA2 split (dkdv over k-blocks, dq over q-blocks)
 with the usual ``delta = rowsum(dO * O)`` trick.
 
+What each grid keeps resident. The forward and dq grids are
+``(b, h, q block)``: a head's whole-sequence K and V blocks keep their block
+index across its q blocks and are fetched once a head. The dk/dv grid is
+``(b, query head, k block)`` with the k block fastest, for the same reason
+from the other side: the head's whole-sequence q, do, lse and delta blocks
+(1 + 1 + 2 + 2 MiB at 4096 x 128, the two f32 columns padded to 128 lanes;
+12 MiB double-buffered) stay put for its 16 k blocks, and lse / delta are
+turned from columns into rows once a head. Its body works in the
+orientation its outputs have (``s^T = k q^T``, ``dp^T = v do^T``), so
+``dv += p^T do`` and ``dk += ds^T q`` contract no tile over its first axis.
+Under GQA every query head writes an f32 partial ``(b, h, sk, d)`` and one
+XLA reduction sums a kv head's group (never through bf16). The k blocks
+are walked last to first, so that under the causal mask a head's last step
+is its longest and hides the next head's fetch. Measured on v5e at the
+``internlm2-d12-pretrain-1chip`` shape (2, 4096, 16 / 8 heads of 128,
+causal, bf16; PERF.md section 6, PR 28), kernel alone, ms a call: 4.92 with
+the group fastest and the score tile transposed twice a product step; 5.19
+with the grid reordered alone (the re-fetch of 6 MiB a step had been hidden
+behind the transposes); 3.25 without the transposes; 3.02 with the k blocks
+last to first: 0.76 ms a 256x256x128 product against dq's 0.73, dk and dv
+bit-identical throughout.
+
 Masking (round 4, the flash_attn varlen/padding analog): per-sequence
 valid lengths and/or segment ids are folded into per-token int32 segment
 arrays (padding becomes segment ``-1``); the kernels mask score entries
@@ -34,6 +56,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
 from . import over_mesh as _over_mesh
@@ -42,8 +65,10 @@ __all__ = ["flash_attention", "flash_attention_supported", "build_segments"]
 
 BLOCK_Q = 128  # minimum/gating granularity
 BLOCK_K = 128
-# Measured on v5e at (4, 1536, 12, 128): 256x256 blocks run the fwd+bwd in
-# 5.2ms vs 11.8ms at 128x128 (VMEM reuse sweet spot); 512x512 regresses.
+# Measured on v5e at (4, 1536, 12, 128), before PR 28's dk/dv kernel: 256x256
+# blocks run the fwd+bwd in 5.2ms vs 11.8ms at 128x128 (VMEM reuse sweet
+# spot); 512x512 regresses. All three kernels take this tile; the dk/dv
+# kernel's cost per product at it is in the module docstring.
 PREFERRED_BLOCK = 256
 NEG_INF = -1e30
 
@@ -171,67 +196,88 @@ def _fwd(q, k, v, causal, scale, q_seg=None, k_seg=None):
 # ------------------------------------------------------------------ backward
 
 def _bwd_dkdv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, masked):
+    """One (query head, k block) of dk / dv. Everything is computed in the
+    orientation the outputs need: ``s^T = k q^T`` and ``dp^T = v do^T`` are
+    (bk, bq), so ``dv += p^T do`` and ``dk += ds^T q`` are plain products and
+    no score tile is transposed. ``lse`` / ``delta`` arrive as columns
+    (sq, 1) and are wanted as rows (1, bq): they are turned once a head,
+    on its first grid step, into the two row scratches."""
     if masked:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dk_ref, dv_ref) = refs
+         kseg_ref, dk_ref, dv_ref, lse_row, dlt_row) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref) = refs
+         dv_ref, lse_row, dlt_row) = refs
         qseg_ref = kseg_ref = None
-    ki = pl.program_id(2)
-    g = pl.program_id(3)  # position within the GQA group (0 for MHA)
-    k = k_ref[0, 0, :, :].astype(jnp.float32)  # (bk, d)
-    v = v_ref[0, 0, :, :].astype(jnp.float32)
+    step = pl.program_id(2)
+    # s and dp take the operands as they came: a bf16 x bf16 product summed
+    # in f32 is the product of their f32 casts (mixed dtypes meet in f32)
+    op_dtype = jnp.result_type(q_ref.dtype, k_ref.dtype, v_ref.dtype,
+                               do_ref.dtype)
+    k = k_ref[0, 0, :, :].astype(op_dtype)  # (bk, d)
+    v = v_ref[0, 0, :, :].astype(op_dtype)
     bk, d = k.shape
-    kseg = (kseg_ref[0, 0, pl.ds(ki * bk, bk)] if masked
-            else None)  # (bk,)
+    num_q = seq_q // block_q
+    ki = seq_k // bk - 1 - step  # k blocks are walked last to first (_bwd)
 
-    # the dk/dv block is revisited across the (fastest) group dim: zero it
-    # on the first group member, accumulate in place for the rest
-    @pl.when(g == 0)
-    def _init():
-        dk_ref[0, 0, :, :] = jnp.zeros((bk, d), dk_ref.dtype)
-        dv_ref[0, 0, :, :] = jnp.zeros((bk, d), dv_ref.dtype)
+    def q_rows(qi):
+        return pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
+    # the k block is the fastest grid dimension: the head's q-side blocks
+    # (and these rows) stay put while it runs
+    @pl.when(step == 0)
+    def _rows():
+        def turn(qi, _):
+            for col_ref, row_ref in ((lse_ref, lse_row), (delta_ref, dlt_row)):
+                col = col_ref[0, 0, q_rows(qi), :]  # (bq, 1)
+                # over the 128 lanes, so that the transpose is tile-aligned
+                row_ref[:, q_rows(qi)] = jnp.transpose(
+                    jnp.broadcast_to(col, (block_q, 128)))[:8]
+            return 0
+
+        jax.lax.fori_loop(0, num_q, turn, 0)
+
+    kseg = (kseg_ref[0, 0, pl.ds(ki * bk, bk)][:, None] if masked
+            else None)  # (bk, 1)
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
-    num_q = seq_q // block_q
     off = seq_k - seq_q
     # causal: q rows with r + off < ki*bk see nothing of this k block
     q_start = jnp.maximum(ki * bk - off, 0) // block_q if causal else 0
 
     def body(qi, carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q), :]
-        dlt = delta_ref[0, 0, pl.ds(qi * block_q, block_q), :]
+        q = q_ref[0, 0, q_rows(qi), :].astype(op_dtype)  # (bq, d)
+        do = do_ref[0, 0, q_rows(qi), :].astype(op_dtype)
+        lse = lse_row[0:1, q_rows(qi)]  # (1, bq)
+        dlt = dlt_row[0:1, q_rows(qi)]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bk, bq)
         if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + qi * block_q
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + ki * bk
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + ki * bk
+            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + qi * block_q
             s = jnp.where(rows + off >= cols, s, NEG_INF)
         if masked:
-            qseg = qseg_ref[0, 0, pl.ds(qi * block_q, block_q)]
-            s = jnp.where(qseg[:, None] == kseg[None, :], s, NEG_INF)
-        p = jnp.exp(s - lse)  # (bq, bk)
+            qseg = qseg_ref[0, 0, q_rows(qi)]
+            s = jnp.where(kseg == qseg[None, :], s, NEG_INF)
+        # p and ds are f32 values and stay f32 operands
+        p = jnp.exp(s - lse)  # p^T
         dv_new = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # p^T @ do
+            p, do.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (bq, bk)
-        ds = p * (dp - dlt) * scale
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (bk, bq)
+        ds = p * (dp - dlt) * scale  # ds^T
         dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # ds^T @ q
+            ds, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
     dk, dv = jax.lax.fori_loop(q_start, num_q, body, (dk0, dv0))
-    dk_ref[0, 0, :, :] += dk.astype(dk_ref.dtype)
-    dv_ref[0, 0, :, :] += dv.astype(dv_ref.dtype)
+    dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
+    dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(*refs, scale, causal, block_k, seq_k, seq_q, masked):
@@ -296,53 +342,63 @@ def _bwd(causal, scale, res, g):
 
     BQ = _block_for(sq)
     BK = _block_for(sk)
-    # grid: group is the FASTEST dim so the (b, kvh, i) dk/dv block is
-    # revisited on consecutive steps (init at g==0, accumulate in VMEM)
+    # grid: the k block is the FASTEST dim under a fixed query head, so the
+    # head's whole-sequence q / do / lse / delta blocks are fetched once a
+    # head, not once a step; a kv head's group then cannot share an output
+    # block (its revisits would not be consecutive): each query head writes
+    # its own f32 partial and the group is summed below. k blocks are walked
+    # last to first: under the causal mask the first one sees every q block,
+    # and as a head's LAST step it hides the next head's 6 MiB fetch, which
+    # the last one (one q block) cannot
+    num_k = sk // BK
+
+    def q_side(b_, h_, i):
+        return (b_, h_, 0, 0)
+
+    def k_side(b_, h_, i):
+        return (b_, h_ // group, num_k - 1 - i, 0)
+
+    def out_block(b_, h_, i):
+        return (b_, h_, num_k - 1 - i, 0)
+
     dkdv_in_specs = [
-        pl.BlockSpec((1, 1, sq, d),
-                     lambda b_, j_, i, g_: (b_, j_ * group + g_, 0, 0)),
-        pl.BlockSpec((1, 1, BK, d), lambda b_, j_, i, g_: (b_, j_, i, 0)),
-        pl.BlockSpec((1, 1, BK, d), lambda b_, j_, i, g_: (b_, j_, i, 0)),
-        pl.BlockSpec((1, 1, sq, d),
-                     lambda b_, j_, i, g_: (b_, j_ * group + g_, 0, 0)),
-        pl.BlockSpec((1, 1, sq, 1),
-                     lambda b_, j_, i, g_: (b_, j_ * group + g_, 0, 0)),
-        pl.BlockSpec((1, 1, sq, 1),
-                     lambda b_, j_, i, g_: (b_, j_ * group + g_, 0, 0)),
+        pl.BlockSpec((1, 1, sq, d), q_side),
+        pl.BlockSpec((1, 1, BK, d), k_side),
+        pl.BlockSpec((1, 1, BK, d), k_side),
+        pl.BlockSpec((1, 1, sq, d), q_side),
+        pl.BlockSpec((1, 1, sq, 1), q_side),
+        pl.BlockSpec((1, 1, sq, 1), q_side),
     ]
     dkdv_operands = [q, k, v, do, lse, delta]
     if masked:
         dkdv_in_specs += [
-            pl.BlockSpec((1, 1, sq), lambda b_, j_, i, g_: (b_, 0, 0)),
-            pl.BlockSpec((1, 1, sk), lambda b_, j_, i, g_: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, sq), lambda b_, h_, i: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, sk), lambda b_, h_, i: (b_, 0, 0)),
         ]
         dkdv_operands += [q_seg, k_seg]
-    dkdv = pl.pallas_call(
+    dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=BQ, seq_q=sq, seq_k=sk, masked=masked),
-        grid=(b, kvh, sk // BK, group),
+        grid=(b, h, num_k),
         in_specs=dkdv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, BK, d), lambda b_, j_, i, g_: (b_, j_, i, 0)),
-            pl.BlockSpec((1, 1, BK, d), lambda b_, j_, i, g_: (b_, j_, i, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, BK, d), out_block)] * 2,
         out_shape=[
-            # GQA (group>1): f32 accumulators so the cross-group revisit
-            # adds never round through bf16; MHA keeps the input dtype
-            # (no revisits, no extra HBM footprint or cast kernels)
-            jax.ShapeDtypeStruct((b, kvh, sk, d),
+            # GQA (group>1): f32 partials, one a query head, so the sum
+            # over the group never rounds through bf16; MHA keeps the
+            # input dtype (no partials, no extra HBM footprint)
+            jax.ShapeDtypeStruct((b, h, sk, d),
                                  jnp.float32 if group > 1 else k.dtype),
-            jax.ShapeDtypeStruct((b, kvh, sk, d),
+            jax.ShapeDtypeStruct((b, h, sk, d),
                                  jnp.float32 if group > 1 else v.dtype),
         ],
+        # lse and delta as rows: 8 sublanes is the least an f32 tile holds
+        scratch_shapes=[pltpu.VMEM((8, sq), jnp.float32)] * 2,
         name="flash_bwd_dkdv",
         interpret=_interpret(),
     )(*dkdv_operands)
-    dk, dv = dkdv
-    if dk.dtype != k.dtype:
-        dk = dk.astype(k.dtype)
-    if dv.dtype != v.dtype:
-        dv = dv.astype(v.dtype)
+    if group > 1:
+        dk = dk.reshape(b, kvh, group, sk, d).sum(axis=2).astype(k.dtype)
+        dv = dv.reshape(b, kvh, group, sk, d).sum(axis=2).astype(v.dtype)
 
     dq_in_specs = [
         pl.BlockSpec((1, 1, BQ, d), lambda b_, h_, i: (b_, h_, i, 0)),
@@ -427,8 +483,8 @@ def flash_attention(q, k, v, is_causal=False, seq_lens=None,
     """(B, S, H, D) flash attention. GQA-native: kv heads are NOT
     materialized to the query head count — the kernel index maps fold each
     query head onto its kv head (``h // group``), and the dk/dv pass
-    accumulates over the group in VMEM, so KV memory/bandwidth stays at
-    the grouped size.
+    writes one f32 partial a query head that is summed over the group, so
+    KV memory/bandwidth stays at the grouped size.
 
     ``seq_lens`` (B,) int32 masks keys/queries past each row's valid length
     (the flash_attn padding/varlen analog,

@@ -14,11 +14,15 @@ from paddle_tpu.ops.pallas import flash_attention as fa
 
 
 def _naive(q, k, v, causal):
-    b, s, h, d = q.shape
+    """Plain attention; kv heads repeated over their group (GQA) and, for
+    sq != sk, the causal diagonal shifted by the cache offset."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
     qh, kh, vh = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    kh, vh = (jnp.repeat(x, h // x.shape[1], axis=1) for x in (kh, vh))
     logits = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) / np.sqrt(d)
     if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
+        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         logits = jnp.where(mask, logits, -jnp.inf)
     p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vh), 1, 2)
@@ -36,12 +40,23 @@ def test_forward_matches_naive(causal):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_backward_matches_naive(causal):
+@pytest.mark.parametrize("causal,group,sq,sk", [
+    (False, 1, 128, 128), (True, 1, 128, 128),
+    (False, 2, 128, 128), (True, 2, 128, 128),
+    (False, 4, 256, 256), (True, 4, 256, 256),
+    # sq != sk: the cache offset, with the 128 fallback on the q side
+    # (384), on the k side (384) and 256 blocks on both
+    (True, 2, 384, 512), (False, 2, 512, 384), (True, 4, 256, 512),
+    (True, 1, 128, 384),
+])
+def test_backward_matches_naive(causal, group, sq, sk):
+    """dq, dk, dv of the two backward kernels against plain attention's:
+    MHA, grouped kv heads (a query head's dk / dv partial summed over the
+    group), several k blocks a head and q blocks a k block."""
     rng = np.random.RandomState(1)
-    q = jnp.asarray(rng.randn(1, 128, 2, 32), jnp.float32)
-    k = jnp.asarray(rng.randn(1, 128, 2, 32), jnp.float32)
-    v = jnp.asarray(rng.randn(1, 128, 2, 32), jnp.float32)
+    q = jnp.asarray(rng.randn(1, sq, 4, 32), jnp.float32)
+    k = jnp.asarray(rng.randn(1, sk, 4 // group, 32), jnp.float32)
+    v = jnp.asarray(rng.randn(1, sk, 4 // group, 32), jnp.float32)
 
     def loss_fa(q, k, v):
         return (fa.flash_attention(q, k, v, is_causal=causal) ** 2).sum()
@@ -51,9 +66,34 @@ def test_backward_matches_naive(causal):
 
     g_fa = jax.grad(loss_fa, argnums=(0, 1, 2))(q, k, v)
     g_nv = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_fa, g_nv):
+    for a, b, n in zip(g_fa, g_nv, "qkv"):
+        assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-3, rtol=1e-3)
+                                   atol=1e-3, rtol=1e-3, err_msg=n)
+
+
+def test_backward_mixed_operand_dtypes():
+    """float32 queries against a bfloat16 k / v (a cache's dtype): the dk/dv
+    kernel feeds its score products the operands as they came only when
+    they agree, and meets in float32 otherwise, as its siblings always do."""
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(1, 256, 4, 32), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 256, 2, 32), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(1, 256, 2, 32), jnp.bfloat16)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    g_fa = jax.grad(loss(lambda *a: fa.flash_attention(*a, is_causal=True)),
+                    argnums=(0, 1, 2))(q, k, v)
+    g_nv = jax.grad(loss(lambda q, k, v: _naive(
+        q, k.astype(jnp.float32), v.astype(jnp.float32), True)),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b, n in zip(g_fa, g_nv, "qkv"):
+        assert a.dtype == b.dtype, n
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=2e-2, rtol=2e-2, err_msg=n)
 
 
 def test_gqa_repeat():
@@ -167,14 +207,17 @@ def test_forward_segment_ids_packed():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_backward_masked():
+@pytest.mark.parametrize("group", [1, 2])
+def test_backward_masked(group):
     """Grads through the masked kernel match the oracle on valid positions,
-    and padded-key dk/dv are exactly zero (loss reads valid rows only)."""
+    and padded-key dk/dv are exactly zero (loss reads valid rows only);
+    under GQA too, where every query head of the group masks its own
+    partial."""
     rng = np.random.RandomState(6)
     B, S, H, D = 2, 128, 2, 32
     q = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
-    k = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
+    k = jnp.asarray(rng.randn(B, S, H // group, D), jnp.float32)
+    v = jnp.asarray(rng.randn(B, S, H // group, D), jnp.float32)
     lens = jnp.asarray([128, 70], jnp.int32)
     valid = (jnp.arange(S)[None, :] < lens[:, None]).astype(jnp.float32)
     w = valid[:, :, None, None]
@@ -184,7 +227,8 @@ def test_backward_masked():
         return ((o * w) ** 2).sum()
 
     def loss_ref(q, k, v):
-        o = _naive_masked(q, k, v, True, seq_lens=lens)
+        o = _naive_masked(q, jnp.repeat(k, group, axis=2),
+                          jnp.repeat(v, group, axis=2), True, seq_lens=lens)
         return ((o * w) ** 2).sum()
 
     g_fa = jax.grad(loss_fa, argnums=(0, 1, 2))(q, k, v)
@@ -264,6 +308,39 @@ def test_flash_attention_gqa_native():
                                    atol=2e-4, err_msg=n)
     # dk/dv keep the GROUPED shape: the memory win is structural
     assert g1[1].shape == (B, S, KVH, D)
+
+
+def test_backward_custom_calls_keep_the_signature_the_benchmark_reads(
+        monkeypatch):
+    """``flash_bwd_roofline.train`` finds the two backward kernels by their
+    operand list: four bf16 arrays, then ``lse`` and ``delta`` as f32 arrays
+    whose last dimension is 1. Lowered for the TPU (nothing runs), at a GQA
+    shape, both calls must still read so, and be the only two that do."""
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, S, H, KVH, D = 2, 512, 4, 2, 128
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, is_causal=True).astype(
+            jnp.float32).sum()
+
+    avals = [jax.ShapeDtypeStruct((B, S, n, D), jnp.bfloat16)
+             for n in (H, KVH, KVH)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = {}
+    for line in text.splitlines():
+        m = re.search(r'@tpu_custom_call\(.*kernel_name = "(\w+)".* : '
+                      r'\(([^)]*)\) ->', line)
+        if m:
+            calls[m.group(1)] = [t.strip() for t in m.group(2).split(", ")]
+    assert set(calls) == {"flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"}
+    q_t, kv_t = f"tensor<{B}x{H}x{S}x{D}xbf16>", f"tensor<{B}x{KVH}x{S}x{D}xbf16>"
+    col_t = f"tensor<{B}x{H}x{S}x1xf32>"
+    for name in ("flash_bwd_dkdv", "flash_bwd_dq"):
+        assert calls[name] == [q_t, kv_t, kv_t, q_t, col_t, col_t], name
+    assert calls["flash_fwd"] == [q_t, kv_t, kv_t]
 
 
 def test_build_segments_rejects_shared_ids_cross_attention():

@@ -109,13 +109,19 @@ def test_moe_gmm_compiles_at_the_published_expert_shapes(topo, m, k, n):
              ((256,), jnp.int32))
 
 
-def test_flash_attention_fwd_bwd_compiles(topo):
+@pytest.mark.parametrize("seq,heads,kv_heads", [
+    (2048, 32, 32),
+    # the internlm2-d12-pretrain-1chip cell: the dk/dv kernel holds a query
+    # head's whole-sequence q / do / lse / delta blocks (12 MiB of VMEM)
+    (4096, 16, 8),
+], ids=["mha32x128_s2048", "train_cell_gqa16_8_s4096"])
+def test_flash_attention_fwd_bwd_compiles(topo, seq, heads, kv_heads):
     def loss(q, k, v):
         return flash_attention(q, k, v, is_causal=True).astype(
             jnp.float32).sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), topo,
-             *[((2, 2048, 32, 128), BF16)] * 3)
+             *[((2, seq, n, 128), BF16) for n in (heads, kv_heads, kv_heads)])
 
 
 def _decode_layer_shapes(hidden, heads, kv_heads, d, b=8, pages=65,

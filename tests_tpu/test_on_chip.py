@@ -256,6 +256,51 @@ def test_pallas_flash_attention_gqa_on_chip():
     assert rel < 1e-2, rel
 
 
+def test_pallas_flash_attention_gqa_backward_at_the_train_cell_shape():
+    """The backward at the shape ``internlm2-d12-pretrain-1chip`` runs twelve
+    times a step: batch 2, 4096 tokens, 16 query / 8 KV heads of 128,
+    causal, bfloat16. dq, dk, dv against plain attention in float32 at
+    ``highest``, a batch row at a time (its scores are 1 GiB), by the norm
+    of the difference: the kernels' products round their operands to
+    bfloat16 (2^-9), the oracle does not."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, S, H, KVH, D = 2, 4096, 16, 8, 128
+    keys = jax.random.split(jax.random.PRNGKey(28), 4)
+    q, k, v, w = (jax.random.normal(key, (B, S, n, D), jnp.bfloat16)
+                  for key, n in zip(keys, (H, KVH, KVH, H)))
+    hi = jax.lax.Precision.HIGHEST
+
+    def ref(q_, k_, v_):
+        qh, kh, vh = (jnp.swapaxes(x.astype(jnp.float32), 1, 2)
+                      for x in (q_, k_, v_))
+        kh, vh = (jnp.repeat(x, H // KVH, axis=1) for x in (kh, vh))
+        s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
+                       precision=hi) / math.sqrt(D)
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+        return jnp.swapaxes(
+            jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vh,
+                       precision=hi), 1, 2)
+
+    def loss(fn):
+        return lambda q_, k_, v_, w_: (
+            fn(q_, k_, v_).astype(jnp.float32) * w_.astype(jnp.float32)).sum()
+
+    got = jax.jit(jax.grad(
+        loss(lambda *a: flash_attention(*a, is_causal=True)),
+        argnums=(0, 1, 2)))(q, k, v, w)
+    ref_grad = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))
+    for row in range(B):
+        one = slice(row, row + 1)
+        want = ref_grad(q[one], k[one], v[one], w[one])
+        for a, b, n in zip(got, want, ("dq", "dk", "dv")):
+            assert a.shape[1:] == b.shape[1:] and a.dtype == jnp.bfloat16, n
+            a = np.asarray(a[one], np.float32)
+            b = np.asarray(b, np.float32)
+            rel = np.linalg.norm(a - b) / np.linalg.norm(b)
+            assert rel < 1e-2, (n, row, rel)
+
+
 def test_pallas_flash_attention_masked_on_chip():
     """seq_lens padding + segment-id masking must lower through Mosaic
     ((1, S) int32 seg blocks in all three kernels) and match the masked
