@@ -529,7 +529,6 @@ log(f"model decode: {gen_dt*1e3:.0f}ms for {GNEW} tokens x batch {GB} -> "
 # beyond the reference's in-tree serving (VERDICT r4 item 9).
 cb_metrics = {}
 try:
-    from paddle_tpu.core.flags import set_flags
     from paddle_tpu.models.serving import ContinuousBatchingEngine
 
     if SMOKE:
@@ -543,9 +542,9 @@ try:
     # two buckets: each (bucket x group-width) costs one fixed-shape
     # prefill compile —
     # 32/128 still covers the 8..119 mixed-length draw below
-    eng = ContinuousBatchingEngine(model, max_slots=CB_SLOTS,
-                                   max_len=CB_LEN, page_size=128,
-                                   prompt_buckets=(32, 128))
+    cb_kw = dict(max_slots=CB_SLOTS, max_len=CB_LEN, page_size=128,
+                 prompt_buckets=(32, 128))
+    eng = ContinuousBatchingEngine(model, **cb_kw)
     log("continuous batching: AOT warmup (every bucket x width prefill + "
         "segment program)...")
     winfo = eng.warmup(segment=CB_SEG)
@@ -563,10 +562,12 @@ try:
     mk_reqs = lambda: [
         rng_cb.randint(0, cfg.vocab_size, (int(n),)).astype(np.int32)
         for n in lens]
-    set_flags({"FLAGS_serving_pipeline": 0})
-    s_outs, s_stats = eng.run(mk_reqs(), max_new_tokens=CB_NEW,
-                              segment=CB_SEG)
-    set_flags({"FLAGS_serving_pipeline": 1})
+    # the serial scheduler as the A side, on a warmed engine of its own
+    s_eng = ContinuousBatchingEngine(model, pipeline=False, **cb_kw)
+    s_eng.warmup(segment=CB_SEG)
+    s_outs, s_stats = s_eng.run(mk_reqs(), max_new_tokens=CB_NEW,
+                                segment=CB_SEG)
+    del s_eng
     outs, stats = eng.run(mk_reqs(), max_new_tokens=CB_NEW, segment=CB_SEG)
     assert all(o is not None and len(o) == CB_NEW for o in outs)
     assert all(o is not None and len(o) == CB_NEW for o in s_outs)
@@ -1525,78 +1526,6 @@ except Exception as e:
         f"{type(e).__name__}: {e}")
     xfer_metrics = {"xfer_error": f"{type(e).__name__}: {e}"[:200]}
 
-# ------------------------------------------- (e11) decode megakernel
-# Fused per-layer Pallas decode step + elementwise-chain fusion (ISSUE
-# 20): the SAME workload through a fused (FLAGS_decode_megakernel=1)
-# and an unfused (=0) engine. Token streams must be IDENTICAL (the
-# megakernel contract); the speedup and the device_wait p50 movement
-# are the numbers that re-win the decode floor (PR 10's
-# serving.phase_s{phase=device_wait} budget — decode_tok_s_vs_floor
-# stood at 0.81x).
-mk_metrics = {}
-try:
-    from paddle_tpu.core.flags import set_flags as _mk_setf
-    from paddle_tpu.models.serving import (
-        ContinuousBatchingEngine as _MkCBE,
-    )
-
-    if SMOKE:
-        MK_SLOTS, MK_LEN, MK_REQ, MK_NEW, MK_SEG = 2, 128, 4, 8, 4
-    else:
-        MK_SLOTS, MK_LEN, MK_REQ, MK_NEW, MK_SEG = 8, 512, 16, 64, 32
-    log(f"decode megakernel: A/B {MK_REQ} requests x {MK_NEW} tokens, "
-        "FLAGS_decode_megakernel 1 vs 0...")
-    _mk_setf({"FLAGS_telemetry": 1})
-    rng_mk = np.random.RandomState(41)
-    mk_lens = rng_mk.randint(8, 28, MK_REQ)
-
-    def _mk_run(flag_val):
-        _mk_setf({"FLAGS_decode_megakernel": flag_val})
-        e = _MkCBE(model, max_slots=MK_SLOTS, max_len=MK_LEN,
-                   page_size=128, prompt_buckets=(32, 128), seed=3)
-        e.warmup(segment=MK_SEG)
-        warm = [rng_mk.randint(0, cfg.vocab_size, (12,)).astype(np.int32)
-                for _ in range(2)]
-        e.run(warm, max_new_tokens=2, segment=MK_SEG)
-        # identical measured prompts per arm: dedicated stream
-        rng_tok = np.random.RandomState(43)
-        reqs = [rng_tok.randint(0, cfg.vocab_size,
-                                (int(n),)).astype(np.int32)
-                for n in mk_lens]
-        outs, st = e.run(reqs, max_new_tokens=MK_NEW, segment=MK_SEG)
-        wait = e.stats()["phases"].get("device_wait", {}).get("p50", 0.0)
-        return e, outs, st, wait
-
-    eng_f, f_outs, f_st, f_wait = _mk_run(1)
-    assert eng_f._megakernel, "model failed the megakernel probe"
-    eng_u, u_outs, u_st, u_wait = _mk_run(0)
-    for i, (a, b) in enumerate(zip(f_outs, u_outs)):
-        assert np.array_equal(a, b), f"fused stream diverged at req {i}"
-    _mk_setf({"FLAGS_decode_megakernel": 1})
-    mk_metrics = {
-        "decode_megakernel_speedup": round(
-            f_st["tokens_per_sec"] / u_st["tokens_per_sec"], 3)
-            if u_st["tokens_per_sec"] else None,
-        "megakernel_tokens_per_sec": round(f_st["tokens_per_sec"], 1),
-        "megakernel_unfused_tokens_per_sec": round(
-            u_st["tokens_per_sec"], 1),
-        "megakernel_device_wait_us_p50": round(1e6 * f_wait, 1),
-        "megakernel_unfused_device_wait_us_p50": round(1e6 * u_wait, 1),
-        "megakernel_device_wait_ratio": round(f_wait / u_wait, 3)
-            if u_wait else None,
-    }
-    log(f"decode megakernel: {f_st['tokens_per_sec']:,.0f} tok/s fused "
-        f"vs {u_st['tokens_per_sec']:,.0f} unfused "
-        f"({mk_metrics['decode_megakernel_speedup']}x, gate > 1 on "
-        f"chip); device_wait p50 "
-        f"{mk_metrics['megakernel_device_wait_us_p50']}us fused vs "
-        f"{mk_metrics['megakernel_unfused_device_wait_us_p50']}us "
-        f"unfused (ratio {mk_metrics['megakernel_device_wait_ratio']}, "
-        "gate: no worse); token streams identical")
-except Exception as e:
-    log(f"decode megakernel section FAILED: {type(e).__name__}: {e}")
-    mk_metrics = {"megakernel_error": f"{type(e).__name__}: {e}"[:200]}
-
 # ------------------------------------------------------- (f) op microbench
 # Per-op regression gate (reference: tools/ci_op_benchmark.sh relative
 # check): ~20 hot ops + eager dispatch overhead, compared against the
@@ -1693,7 +1622,6 @@ result = {
     **tp_metrics,
     **kv_metrics,
     **xfer_metrics,
-    **mk_metrics,
     "op_bench_us": op_results,
     "op_bench_vs_baseline": op_vs_baseline,
     "op_bench_regressions": op_regressions,

@@ -153,73 +153,6 @@ def _bench_one(fn, args, iters, reps, rtt, sync_fetch):
     return net / iters, iters
 
 
-def _decode_layer_bench(smoke, iters, reps, rtt, sync_fetch, log):
-    """Fused megakernel decode step vs the unfused composition, same
-    weights/pool, one (B, 1, hidden) token batch. Off-TPU the kernel
-    runs in interpret mode — the _us reading is then only a smoke check;
-    the launch counts are backend-independent."""
-    from paddle_tpu.ops.pallas import decode_megakernel as mk
-
-    key = jax.random.PRNGKey(7)
-    heads, kvh, d = (4, 2, 32) if smoke else (8, 4, 64)
-    b, page_size, pps = 4, 32, 4
-    hidden = heads * d
-    npages = b * pps + 2
-    ks = jax.random.split(key, 12)
-    rnd = lambda i, *s: jax.random.normal(ks[i], s, jnp.float32) * 0.1
-    pos = jnp.arange(page_size * pps + 1, dtype=jnp.float32)[:, None]
-    inv = 1.0 / (10000.0 ** (jnp.arange(0, d, 2) / d))
-    ang = jnp.concatenate([pos * inv, pos * inv], axis=-1)
-    fixed = dict(
-        ln1_weight=rnd(0, hidden) + 1.0, ln1_eps=1e-6,
-        wq=rnd(1, hidden, heads * d), wk=rnd(2, hidden, kvh * d),
-        wv=rnd(3, hidden, kvh * d), wo=rnd(4, heads * d, hidden),
-        rope_cos=jnp.cos(ang), rope_sin=jnp.sin(ang),
-        ln2_weight=rnd(5, hidden) + 1.0, ln2_eps=1e-6,
-        tables=jnp.arange(b * pps, dtype=jnp.int32).reshape(b, pps),
-        lengths=jnp.asarray([37, 5, 90, 61], jnp.int32),
-        heads=heads,
-    )
-    x = rnd(6, b, 1, hidden)
-    kp = rnd(7, npages, page_size, kvh, d)
-    vp = rnd(8, npages, page_size, kvh, d)
-    dump = npages - 1
-
-    def fused(x, kp, vp):
-        h, y2, kp2, vp2 = mk.fused_decode_layer(
-            x, k_pages=kp, v_pages=vp, dump_page=dump, **fixed)
-        return h.sum() + y2.sum() + kp2.sum() * 1e-6 + vp2.sum() * 1e-6
-
-    def unfused(x, kp, vp):
-        h, y2, kp2, vp2 = mk.reference_decode_layer(
-            x, k_pages=kp, v_pages=vp, **fixed)
-        return h.sum() + y2.sum() + kp2.sum() * 1e-6 + vp2.sum() * 1e-6
-
-    out = {}
-    for name, fn in (("decode_layer_fused_us", fused),
-                     ("decode_layer_unfused_us", unfused)):
-        us_per, used_iters = _bench_one(fn, (x, kp, vp), iters, reps, rtt,
-                                        sync_fetch)
-        out[name] = None if us_per is None else round(us_per * 1e6, 2)
-        log(f"  op {name}: "
-            + ("n/a" if us_per is None else f"{us_per*1e6:,.1f} us"))
-    # launch-site proxy: top-level traced equations per decode layer
-    # step (the megakernel's point — ONE pallas_call where the unfused
-    # composition dispatches a zoo); counted on the BARE layer step,
-    # without the benchmark's reduction wrapper
-    out["decode_layer_launches"] = len(jax.make_jaxpr(
-        lambda x, kp, vp: mk.fused_decode_layer(
-            x, k_pages=kp, v_pages=vp, dump_page=dump, **fixed)
-    )(x, kp, vp).jaxpr.eqns)
-    out["decode_layer_launches_unfused"] = len(jax.make_jaxpr(
-        lambda x, kp, vp: mk.reference_decode_layer(
-            x, k_pages=kp, v_pages=vp, **fixed)
-    )(x, kp, vp).jaxpr.eqns)
-    log(f"  decode_layer launches: fused {out['decode_layer_launches']} "
-        f"vs unfused {out['decode_layer_launches_unfused']}")
-    return out
-
-
 def run_op_bench(smoke, rtt, sync_fetch, log, rerecord=False):
     iters = 4 if smoke else 50
     reps = 2 if smoke else 3
@@ -242,22 +175,6 @@ def run_op_bench(smoke, rtt, sync_fetch, log, rerecord=False):
             log(f"  op {name}: FAILED {type(e).__name__}: {e}")
             results[name] = None
             invalid.append(name)
-
-    # decode-layer A/B (ISSUE 20): the fused Pallas megakernel step vs
-    # the exact unfused composition it replaces, plus the launch-site
-    # reading (top-level traced equations — the megakernel collapses the
-    # attention half of a layer into ONE)
-    try:
-        for k, v in _decode_layer_bench(smoke, iters, reps, rtt,
-                                        sync_fetch, log).items():
-            results[k] = v
-            if v is None and k.endswith("_us"):
-                invalid.append(k)
-    except Exception as e:
-        log(f"  decode_layer A/B: FAILED {type(e).__name__}: {e}")
-        results["decode_layer_fused_us"] = None
-        results["decode_layer_unfused_us"] = None
-        invalid.append("decode_layer_fused_us")
 
     # host-side eager dispatch overhead (cached-executable path)
     import paddle_tpu as paddle

@@ -11,9 +11,6 @@ only depth is cut, to what 16 GB holds, and printed):
   admissions), all retiring ``ok`` with zero compiles after warm-up, pipelined
   and serial greedy streams identical, and the paged-cache path agreeing with
   the model's plain cache-less forward;
-* **megakernel** — the fused decode program (``FLAGS_decode_megakernel`` at
-  its default) against the same engine with the flag at 0, at the widest
-  width the capability probe admits, plus the kernel against its jnp oracle;
 * **train** — ``jit.TrainStep`` + ``AdamW(multi_precision=True)``: a few
   steps on one seeded batch, loss finite and falling, flash attention in the
   compiled step.
@@ -35,7 +32,6 @@ only ``main()`` holds the device gate and the real sizes.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import gc
 import json
 import sys
@@ -44,14 +40,12 @@ import traceback
 import warnings
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 from paddle_tpu import native
 from paddle_tpu.core import telemetry
-from paddle_tpu.core.flags import flag, set_flags
 from paddle_tpu.jit import count_backend_compiles
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, llama_shard_fn
 from paddle_tpu.models.frontend import ServingFrontend
@@ -59,12 +53,6 @@ from paddle_tpu.models.llama import PagedKVCache
 from paddle_tpu.models.serving import ContinuousBatchingEngine
 from paddle_tpu.models.tp_serving import TPShardedEngine, serving_mesh
 from paddle_tpu.ops import nn_kernels, pallas
-from paddle_tpu.ops.pallas.decode_megakernel import (
-    MEGAKERNEL_VMEM_BUDGET,
-    fused_decode_layer,
-    megakernel_model_supported,
-    reference_decode_layer,
-)
 
 # What marks a Mosaic (compiled Pallas) kernel in a program's text. In
 # interpret mode the kernel body is inlined as ordinary HLO and this is absent.
@@ -78,10 +66,6 @@ MOSAIC_CALL = "tpu_custom_call"
 # order of their spread. 2**-4 of the largest |logit| separates the two with
 # room on both sides (float32 runs land orders of magnitude below it).
 LOGIT_TOL_REL = 2.0 ** -4
-# One decoder layer, fused kernel vs its oracle: the kernel rounds where the
-# unfused composition rounds, so they differ by a step or two of bf16 at the
-# value scale (2**-6 of the largest magnitude is two steps of its binade).
-KERNEL_TOL_REL = 2.0 ** -6
 # Loss of a dp x mp step vs the single-chip step on the same weights and
 # batch: row-parallel projections split contractions, so gradients and the
 # steps taken differ at bf16 rounding level; a sharding fault changes what
@@ -102,16 +86,6 @@ def log(msg):
     print(f"[smoke] {msg}", flush=True)
 
 
-@contextlib.contextmanager
-def flags_set(**values):
-    old = {k: flag(k) for k in values}
-    set_flags(values)
-    try:
-        yield
-    finally:
-        set_flags(old)
-
-
 def build_model(cfg, seed):
     """Seeded random weights, created in bf16 directly (no float32 copy)."""
     paddle.seed(seed)
@@ -128,13 +102,6 @@ def build_model(cfg, seed):
 def param_bytes(model):
     return sum(p._value.size * p._value.dtype.itemsize
                for p in model.parameters())
-
-
-def attn_projection_bytes(model):
-    """What the megakernel would hold in VMEM for one layer: q/k/v/o."""
-    attn = model.model.layers[0].self_attn
-    return sum(p.weight._value.size * p.weight._value.dtype.itemsize
-               for p in (attn.q_proj, attn.k_proj, attn.v_proj, attn.o_proj))
 
 
 def device_bytes_in_use():
@@ -265,14 +232,14 @@ def check_streams_against_plain_forward(plain, plain_len, prompts, streams,
 
 def check_streams_agree(a, b, what, plain, prompts):
     """Greedy streams of two engines that evaluate the same bf16 model in
-    different orders (fused vs unfused kernel, four chips vs one): equal,
+    different orders (four chips vs one): equal,
     except where bf16 leaves the choice open. A request whose streams
     differ must differ first at a near-tie — both candidates within the
     logit tolerance of the plain forward's best at that position, the
     context being common up to there — and from there on each stream is
     held to the plain forward on its own context. (Measured on the chip:
-    the unfused path's rounding points are XLA's to choose, so the two
-    are NOT bit-identical in bf16 the way they are in float32.)"""
+    the rounding points are XLA's to choose per program, so the two are
+    NOT bit-identical in bf16 the way they are in float32.)"""
     longest = max(len(p) + len(a[rid]) for rid, p in enumerate(prompts))
     plain_len = -(-longest // 128) * 128   # one shape the flash kernel takes
     diverged = first_differences(a, b)
@@ -297,24 +264,21 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
     t0 = time.monotonic()
     rng = np.random.RandomState(seed)
     model = build_model(cfg, seed)
-    engine = ContinuousBatchingEngine(
-        model, max_slots=max_slots, max_len=max_len, page_size=page_size,
-        prompt_buckets=prompt_buckets, pool_pages=pool_pages)
+    engine_kw = dict(max_slots=max_slots, max_len=max_len,
+                     page_size=page_size, prompt_buckets=prompt_buckets,
+                     pool_pages=pool_pages)
+    engine = ContinuousBatchingEngine(model, **engine_kw)
     pool_bytes = sum(k.size * k.dtype.itemsize
                      for k in engine._ks + engine._vs)
     report = {
         "layers": cfg.num_hidden_layers,
         "weight_bytes": param_bytes(model),
         "kv_pool_bytes": pool_bytes, "kv_pool_pages": pool_pages,
-        "megakernel_probe": megakernel_model_supported(model),
         "build_wall_s": round(time.monotonic() - t0, 2),
     }
     log(f"serve: depth {cfg.num_hidden_layers} layers, weights "
         f"{report['weight_bytes'] / 2**30:.2f} GiB, KV pool {pool_pages} "
-        f"pages = {pool_bytes / 2**30:.2f} GiB; megakernel probe says "
-        f"{report['megakernel_probe']} (one layer's attention projections "
-        f"{attn_projection_bytes(model) / 2**20:.0f} MiB vs the kernel's "
-        f"{MEGAKERNEL_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget)")
+        f"pages = {pool_bytes / 2**30:.2f} GiB")
 
     info = ServingFrontend(engine, segment=segment).warmup()
     report["warmup_programs"] = info["programs"]
@@ -338,16 +302,23 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
           "prompt_lens must mix bucketed and chunked admissions")
     serving_compiles = telemetry.counter("xla.compiles_total")
     before = serving_compiles.value(phase="serving")
-    mega_before = telemetry.counter("serving.megakernel_segments").value()
     t1 = time.monotonic()
     with count_backend_compiles() as compiles:
         piped = serve_session(engine, prompts, max_new, segment)
         check(engine.stats()["pipelined"], "first session was not pipelined")
         kv = engine.kv_stats()
-        with flags_set(FLAGS_serving_pipeline=False):
-            serial = serve_session(engine, prompts, max_new, segment)
-            check(not engine.stats()["pipelined"],
-                  "second session was not serial")
+    # the serial scheduler is the reference, on an engine of its own. The
+    # two pools do not fit the chip together, so the first goes first; the
+    # second compiles what it runs (from the persistent cache where the
+    # first one's programs are in it).
+    del engine
+    gc.collect()
+    serial_engine = ContinuousBatchingEngine(model, pipeline=False,
+                                             **engine_kw)
+    serial = serve_session(serial_engine, prompts, max_new, segment)
+    check(not serial_engine.stats()["pipelined"],
+          "second session was not serial")
+    del serial_engine
     report["requests_wall_s"] = round(time.monotonic() - t1, 2)
     report["requests"] = len(prompts)
     report["tokens_per_session"] = int(sum(max_new))
@@ -355,9 +326,6 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
     report["post_warmup_compiles_serving"] = (
         serving_compiles.value(phase="serving") - before)
     report["post_warmup_compiles_any"] = len(compiles)
-    report["megakernel_segments"] = (
-        telemetry.counter("serving.megakernel_segments").value()
-        - mega_before)
     check(report["post_warmup_compiles_serving"] == 0 and not compiles,
           f"compiled after warm-up: {report['post_warmup_compiles_serving']} "
           f"by the engine's count, {len(compiles)} backend compiles in all")
@@ -366,9 +334,9 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
               "the prefix-sharing request did not hit the prefix cache")
     check_streams_equal(piped, serial, "pipelined vs serial")
     log(f"serve: {len(prompts)} requests x 2 sessions (pipelined, serial) "
-        f"all ok, streams identical, 0 compiles after warm-up, "
-        f"{kv['prefix_tokens_saved']} prompt tokens served from the prefix "
-        f"cache, megakernel segments {report['megakernel_segments']}")
+        f"all ok, streams identical, 0 compiles after warm-up in the "
+        f"pipelined one, {kv['prefix_tokens_saved']} prompt tokens served "
+        f"from the prefix cache")
 
     plain = paddle.jit.to_static(model)
     report["cache_vs_plain"] = check_cache_against_plain_forward(
@@ -385,106 +353,6 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
     log(f"serve: paged cache vs plain forward {report['cache_vs_plain']}; "
         f"engine streams vs plain forward {report['streams_vs_plain']}")
     report["wall_s"] = round(time.monotonic() - t0, 2)
-    return report
-
-
-def megakernel_phase(cfg, *, seed, max_slots, max_len, page_size,
-                     prompt_buckets, prompt_lens, max_new, segment):
-    """Fused vs unfused engines on one model, and the kernel vs its oracle."""
-    t0 = time.monotonic()
-    rng = np.random.RandomState(seed)
-    model = build_model(cfg, seed)
-    check(megakernel_model_supported(model),
-          "the capability probe declines the megakernel phase's model")
-    prompts = make_prompts(rng, cfg.vocab_size, prompt_lens, 0)
-    kw = dict(max_slots=max_slots, max_len=max_len, page_size=page_size,
-              prompt_buckets=prompt_buckets)
-    counter = telemetry.counter("serving.megakernel_segments")
-
-    fused_engine = ContinuousBatchingEngine(model, **kw)
-    check(fused_engine._megakernel,
-          "the engine did not build the fused segment program")
-    fe = ServingFrontend(fused_engine, segment=segment)
-    info = fe.warmup()
-    seg_calls = mosaic_calls(
-        fused_engine.compiled_programs()[("segment", segment)])
-    n0 = counter.value()
-    fused = serve_session(fused_engine, prompts, max_new, segment)
-    fused_segments = counter.value() - n0
-    check(fused_segments > 0, "serving.megakernel_segments did not move")
-    del fused_engine, fe
-
-    with flags_set(FLAGS_decode_megakernel=0):
-        plain_engine = ContinuousBatchingEngine(model, **kw)
-        check(not plain_engine._megakernel, "flag 0 still built a fused "
-                                            "segment program")
-        unfused = serve_session(plain_engine, prompts, max_new, segment)
-    del plain_engine
-    agree = check_streams_agree(
-        fused, unfused, "fused vs unfused", paddle.jit.to_static(model),
-        prompts)
-
-    # the kernel against its jnp oracle on this model's first layer
-    layer = model.model.layers[0]
-    attn = layer.self_attn
-    b, per_seq = max_slots, max_len // page_size
-    n_pages = b * per_seq + 1
-    dtype = attn.q_proj.weight._value.dtype
-    kvh, d = cfg.num_key_value_heads, cfg.head_dim
-    arrays = dict(
-        x=jnp.asarray(rng.standard_normal((b, 1, cfg.hidden_size)), dtype),
-        ln1_weight=layer.input_layernorm.weight._value,
-        wq=attn.q_proj.weight._value, wk=attn.k_proj.weight._value,
-        wv=attn.v_proj.weight._value, wo=attn.o_proj.weight._value,
-        rope_cos=attn.rope_cos._value, rope_sin=attn.rope_sin._value,
-        ln2_weight=layer.post_attention_layernorm.weight._value,
-        k_pages=jnp.asarray(
-            rng.standard_normal((n_pages, page_size, kvh, d)), dtype),
-        v_pages=jnp.asarray(
-            rng.standard_normal((n_pages, page_size, kvh, d)), dtype),
-        tables=jnp.asarray(rng.permutation(n_pages - 1)
-                           .reshape(b, per_seq).astype(np.int32)),
-        # fresh, mid-page, page-boundary and near-full depths
-        lengths=jnp.asarray(([0, page_size - 1, page_size,
-                              max_len - 2] * b)[:b], jnp.int32))
-    static = dict(ln1_eps=layer.input_layernorm.epsilon,
-                  ln2_eps=layer.post_attention_layernorm.epsilon,
-                  heads=cfg.num_attention_heads, dump_page=n_pages - 1)
-    got = jax.jit(lambda a: fused_decode_layer(**a, **static))(arrays)
-    want = jax.jit(lambda a: reference_decode_layer(**a, **static))(arrays)
-    errs = {}
-    keep = np.arange(n_pages) != n_pages - 1   # the dump page holds garbage
-    for name, g, w in zip(("h_mid", "mlp_in", "k_pages", "v_pages"),
-                          got, want):
-        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-        if g.shape[0] == n_pages:
-            g, w = g[keep], w[keep]
-        check(np.all(np.isfinite(g)), f"fused kernel {name} is not finite")
-        errs[name] = float(np.max(np.abs(g - w)))
-        tol = logits_tolerance(w, KERNEL_TOL_REL)
-        check(errs[name] <= tol,
-              f"fused kernel {name} differs from the oracle by "
-              f"{errs[name]:.4g} (tolerance {tol:.4g})")
-    report = {
-        "hidden": cfg.hidden_size, "heads": cfg.num_attention_heads,
-        "head_dim": d, "layers": cfg.num_hidden_layers,
-        "weight_bytes": param_bytes(model),
-        "warmup_programs": info["programs"],
-        "mosaic_in_segment": seg_calls > 0,
-        "mosaic_calls_in_segment": seg_calls,
-        "megakernel_segments": fused_segments,
-        "fused_vs_unfused": agree, "kernel_vs_oracle_max_abs": errs,
-        "wall_s": round(time.monotonic() - t0, 2),
-    }
-    log(f"megakernel: hidden {cfg.hidden_size} = {cfg.num_attention_heads} "
-        f"heads x {d}, {cfg.num_hidden_layers} layers; one layer's "
-        f"attention projections "
-        f"{attn_projection_bytes(model) / 2**20:.1f} MiB of the "
-        f"probe's {MEGAKERNEL_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget; "
-        f"fused vs unfused streams {agree}, "
-        f"{fused_segments} fused segments, "
-        f"{seg_calls} Mosaic calls in the segment program; kernel vs "
-        f"oracle max abs diff {errs}")
     return report
 
 
@@ -701,15 +569,6 @@ SERVE = dict(
     pool_pages=192,
     prompt_lens=(9, 300, 77, 128, 40, 450, 100, 260), shared_prefix=200,
     max_new=(32, 48, 64, 40, 56, 48, 64, 32), segment=16, plain_len=512)
-# the widest MHA width the capability probe admits: 4 * h^2 * 2 B of
-# attention projections within MEGAKERNEL_VMEM_BUDGET -> h = 9 * 128. It is
-# the kernel's limit, not a published model.
-MEGAKERNEL = dict(
-    config=dict(hidden_size=1152, num_attention_heads=9,
-                intermediate_size=3072, num_hidden_layers=4,
-                max_position_embeddings=2048),
-    max_slots=4, max_len=1024, page_size=128, prompt_buckets=(128,),
-    prompt_lens=(9, 120, 33, 128, 64, 77), max_new=(32,) * 6, segment=16)
 TRAIN = dict(config=dict(num_hidden_layers=5, use_recompute=True),
              batch=2, seq=1024, steps=5, lr=3e-4)
 MULTICHIP = dict(
@@ -771,8 +630,6 @@ def run(chips, device):
               ("serve_mosaic_in_segment", "train_mosaic_in_step"))
     else:
         phase("serve", serve_phase, SERVE, ("mosaic_in_segment",))
-        phase("megakernel", megakernel_phase, MEGAKERNEL,
-              ("mosaic_in_segment",))
         phase("train", train_phase, TRAIN, ("mosaic_in_step",))
     summary["compile_cache"] = cache
     log(f"persistent compile cache: {cache['hits']} hits, "

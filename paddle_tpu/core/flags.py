@@ -70,11 +70,6 @@ def flag(name: str):
 define_flag("FLAGS_check_nan_inf", False, "Check outputs of every op for NaN/Inf")
 define_flag("FLAGS_eager_op_jit", True, "Compile+cache per-op executables for eager mode")
 define_flag("FLAGS_use_pallas_kernels", True, "Use Pallas kernels for fused ops when available")
-define_flag("FLAGS_decode_megakernel", 1,
-            "Fused per-layer Pallas decode step in serving (0 = off, "
-            "1 = auto: Pallas megakernel on TPU / exact unfused "
-            "composition on CPU, 2 = force the kernel in interpret "
-            "mode off-TPU — tests and benches)")
 define_flag("FLAGS_flash_attention_block_size", 256,
             "Preferred q/k block for the Pallas flash-attention kernel "
             "(256 measured fastest on v5e; falls back to 128 when the "

@@ -19,9 +19,7 @@ in production before they can be attacked:
     already tracks, now with a full distribution.
 
   :func:`phase_summaries` renders p50/p95/p99 + mean per phase from the
-  live registry or any (fleet-merged) snapshot — the measurement side of
-  the decode-megakernel item (a fused kernel must beat the attributed
-  ``segment_dispatch``+``device_wait`` budget, not a guess).
+  live registry or any (fleet-merged) snapshot.
 
 * **Memory watchdog** — :class:`MemoryWatchdog` polls
   ``paddle_tpu.device.memory_stats()`` (PJRT) into

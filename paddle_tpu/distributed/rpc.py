@@ -429,12 +429,6 @@ def _serve(state: _RpcState):
     worker name, so slots dispatch exactly once per incarnation."""
     store = state.serve_store
     inbox = _inbox(state.name)
-    try:
-        _recover_inbox(state)
-    except Exception as e:  # noqa: BLE001 — recovery is best-effort
-        bump_counter("rpc.dispatch_error")
-        logger.warning("rpc inbox recovery failed for %r: %s",
-                       state.name, e)
     hot_until = 0.0  # monotonic: poll hot while traffic is flowing
     while not state.stop.is_set():
         try:
@@ -503,6 +497,16 @@ def init_rpc(name, rank=None, world_size=None, master_endpoint=None,
         _state.pool = ThreadPoolExecutor(
             max_workers=_state.num_workers,
             thread_name_prefix=f"rpc-{name}")
+        # the predecessor's inbox is recovered (or purged) BEFORE the
+        # name is published: the name in the store tells a caller that
+        # this worker is addressable, and a request enqueued between the
+        # publication and a purge would go with the dead epoch's traffic
+        # (its caller then waits out a whole timeout for no reply)
+        try:
+            _recover_inbox(_state)
+        except Exception as e:  # noqa: BLE001 — recovery is best-effort
+            bump_counter("rpc.dispatch_error")
+            logger.warning("rpc inbox recovery failed for %r: %s", name, e)
         _state.store.set(f"rpc/worker/{name}", str(rank or 0))
         _state.thread = threading.Thread(target=_serve, args=(_state,),
                                          daemon=True,

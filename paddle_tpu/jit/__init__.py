@@ -34,10 +34,7 @@ __all__ = [
     "to_static", "TrainStep", "cond", "while_loop", "scan",
     "ignore_module", "not_to_static", "StaticFunction",
     "enable_compilation_cache",
-    "fuse_elementwise_chains", "fusion_stats",
 ]
-
-from .fusion import fuse_elementwise_chains, fusion_stats  # noqa: E402
 
 
 # where the persistent compile cache lives when the environment does not

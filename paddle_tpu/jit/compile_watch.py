@@ -99,9 +99,7 @@ class CompileWatchdog:
         with self._lock:
             if not self._registered:
                 return
-            with contextlib.suppress(Exception):
-                _monitoring()._unregister_event_duration_listener_by_callback(
-                    self._on_event)
+            _monitoring().unregister_event_duration_listener(self._on_event)
             self._registered = False
 
     def reset(self):
@@ -214,5 +212,4 @@ def count_backend_compiles():
     try:
         yield events
     finally:
-        with contextlib.suppress(Exception):
-            mon._unregister_event_duration_listener_by_callback(listener)
+        mon.unregister_event_duration_listener(listener)

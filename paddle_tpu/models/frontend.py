@@ -26,8 +26,8 @@ scheduler and how the system degrades when it is saturated or broken:
 The frontend is a synchronous pump: callers ``submit()`` whenever
 requests arrive and drive progress with ``step()`` (one admit → decode →
 retire turn) or ``results(wait=True)`` (pump until everything pending has
-resolved). With the engine's overlapped scheduler
-(``FLAGS_serving_pipeline``, default on) each pumped turn dispatches the
+resolved). With the engine's overlapped scheduler (its default;
+``pipeline=False`` builds the serial one) each pumped turn dispatches the
 NEXT decode segment before consuming the previous one, so results arrive
 one segment behind the device — admission control, poison bisection,
 deadlines, and the circuit breaker are unchanged because the engine

@@ -84,8 +84,7 @@ def kv_page_shapes(model):
 
 
 def _make_paged_cache(kp, vp, tables, page_size, length,
-                      aligned_bases=False, attn_pages=None,
-                      dump_page=None, live=None):
+                      aligned_bases=False, attn_pages=None, live=None):
     from .llama import PagedKVCache
 
     c = PagedKVCache.__new__(PagedKVCache)
@@ -96,9 +95,6 @@ def _make_paged_cache(kp, vp, tables, page_size, length,
     # attn_pages caps how many table columns attention READS (the
     # ragged paged-attention kernel's pages-per-sequence bound)
     c.attn_pages = attn_pages
-    # sacrificial page absorbing the decode megakernel's non-append
-    # page flushes (the engine's dump page)
-    c.dump_page = dump_page
     c.live = live      # (B,) rows that hold a sequence, or None: all
     c.stats = None     # what a layer counted this step, if it counts
     return c

@@ -151,7 +151,7 @@ class PagedKVCache:
     over this layout runs the Pallas ``paged_attention`` kernel."""
 
     __slots__ = ("k_pages", "v_pages", "tables", "page_size", "length",
-                 "aligned_bases", "attn_pages", "dump_page", "live", "stats")
+                 "aligned_bases", "attn_pages", "live", "stats")
 
     def __init__(self, batch, max_len, kv_heads=None, head_dim=None,
                  page_size=128, dtype=jnp.float32, shapes=None):
@@ -180,10 +180,6 @@ class PagedKVCache:
         # engine's dynamic tables append write-scratch columns past
         # max_len that reads must never pay grid steps for
         self.attn_pages = None
-        # sacrificial page id absorbing the decode megakernel's
-        # non-append page flushes (None = no spare page: the kernel
-        # writes visited pages back in place instead)
-        self.dump_page = None
         # (B,) rows that hold a sequence, where the caller knows (the
         # serving engine's decode segment): a layer whose cost follows its
         # rows (sparse experts) leaves the others out. And what such a
@@ -464,9 +460,6 @@ class LlamaDecoderLayer(Layer):
                                                 epsilon=config.rms_norm_eps)
 
     def forward(self, hidden_states, attn_mask=None, cache=None):
-        if cache is not None and self._megakernel_step(hidden_states,
-                                                       cache):
-            return self._fused_decode_forward(hidden_states, cache)
         # named scopes: a profile's op metadata says which section of the
         # layer an HLO op came from (attn / mlp; lm_head and sample below)
         residual = hidden_states
@@ -483,57 +476,6 @@ class LlamaDecoderLayer(Layer):
         if cache is not None:
             return hidden_states, new_cache
         return hidden_states
-
-    def _megakernel_step(self, hidden_states, cache):
-        """True when this call is a decode step the fused Pallas
-        megakernel should take: s=1 over a paged cache with per-slot
-        depths, kernel dispatch active (flag/scope + backend), and the
-        layer structurally supported (ops/pallas/decode_megakernel)."""
-        if not isinstance(cache, PagedKVCache):
-            return False
-        if hidden_states.shape[1] != 1 or not _per_seq_lengths(cache.length):
-            return False
-        from ..ops.pallas.decode_megakernel import (
-            megakernel_kernel_active, megakernel_supported)
-
-        return megakernel_kernel_active() and megakernel_supported(
-            self, cache)
-
-    def _fused_decode_forward(self, hidden_states, cache):
-        """One fused decode step: the attention half of the layer (ln1 ->
-        qkv -> rope -> paged append -> paged attention -> o_proj ->
-        residual -> ln2) runs as ONE pallas_call; the MLP half stays in
-        XLA. Cache post-state replicates ``cache.update`` exactly."""
-        from ..ops.pallas.decode_megakernel import fused_decode_layer
-
-        attn = self.self_attn
-        cfg = attn.config
-        offset = cache.length  # (B,) PRE-append depths
-        dump = getattr(cache, "dump_page", None)
-        with jax.named_scope("attn"):
-            h_mid, y2, kp, vp = fused_decode_layer(
-                hidden_states._value,
-                ln1_weight=self.input_layernorm.weight._value,
-                ln1_eps=self.input_layernorm.epsilon,
-                wq=attn.q_proj.weight._value,
-                wk=attn.k_proj.weight._value,
-                wv=attn.v_proj.weight._value,
-                wo=attn.o_proj.weight._value,
-                rope_cos=attn.rope_cos._value,
-                rope_sin=attn.rope_sin._value,
-                ln2_weight=self.post_attention_layernorm.weight._value,
-                ln2_eps=self.post_attention_layernorm.epsilon,
-                k_pages=cache.k_pages, v_pages=cache.v_pages,
-                tables=cache.tables, lengths=offset.astype(jnp.int32),
-                heads=cfg.num_attention_heads,
-                attn_pages=getattr(cache, "attn_pages", None),
-                dump_page=dump if isinstance(dump, int) else None)
-        cache.k_pages, cache.v_pages = kp, vp
-        cache.length = cache.length + 1
-        with jax.named_scope("mlp"):
-            out = Tensor._from_value(h_mid) + self.mlp(
-                Tensor._from_value(y2))
-        return out, cache
 
 
 class LlamaModel(Layer):

@@ -26,8 +26,8 @@ Whenever the host changes the slot mask in a way the device cannot see
 dispatch is a synchronous turn from host state. Sampling uses PER-REQUEST
 key streams — a pure function of (engine seed, rid, token index) — so a
 speculatively dispatched segment, a bisection replay, and the serial
-schedule all emit bit-identical tokens. ``FLAGS_serving_pipeline=0``
-selects the serial one-segment-at-a-time loop.
+schedule all emit bit-identical tokens. ``pipeline=False`` selects the
+serial one-segment-at-a-time loop.
 
 ``warmup()`` AOT-compiles (``jit(...).lower().compile()``) every declared
 (bucket x group-width) prefill shape plus the chunked-prefill and
@@ -67,7 +67,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import perfwatch, telemetry
-from ..core.flags import define_flag, flag
 from ..core.resilience import (
     Deadline,
     InjectedFault,
@@ -90,11 +89,6 @@ logger = logging.getLogger("paddle_tpu.serving")
 # (tests/test_no_bare_except.py): a new terminal state added here without
 # a router handler fails the guard, not production traffic.
 TERMINAL_STATES = frozenset({"ok", "timed_out", "failed", "cancelled"})
-
-define_flag("FLAGS_serving_pipeline", True,
-            "Overlap host bookkeeping with the next compiled decode "
-            "segment in ContinuousBatchingEngine (0 = serial fallback: "
-            "dispatch, wait, consume, one segment at a time)")
 
 # serving-path metrics (module-level handles: registry reset zeroes them
 # in place, so caching here is safe and keeps the hot-path cost at one
@@ -124,9 +118,6 @@ _M_TOKENS = telemetry.counter(
     "serving.tokens_total", "tokens emitted by the engine scheduler")
 _M_REQS = telemetry.counter(
     "serving.requests_total", "terminal request verdicts, by status")
-_M_MEGA_SEG = telemetry.counter(
-    "serving.megakernel_segments", "decode segments dispatched through "
-    "the fused megakernel program (FLAGS_decode_megakernel)")
 _M_ATTN_LIVE = telemetry.counter(
     "serving.attn_pages_live_total", "pages that hold tokens at a decode "
     "segment's dispatch, sum of ceil(length / page) over every slot (idle "
@@ -315,20 +306,15 @@ class ContinuousBatchingEngine:
         eng.warmup(segment=16)   # optional: AOT-compile every shape
         outs, stats = eng.run(prompts, max_new_tokens=64, segment=16)
 
-    ``pipeline=None`` (default) follows ``FLAGS_serving_pipeline``;
-    ``pipeline=False`` forces the serial scheduler for this engine.
+    ``pipeline=False`` selects the serial scheduler (dispatch, wait,
+    consume, one segment at a time): the reference the overlapped one is
+    held token-identical to.
     """
-
-    # The fused decode megakernel keeps residual + post-attention norm
-    # INSIDE the per-layer kernel, right after o_proj. Subclasses whose
-    # o_proj output is a PARTIAL sum (TP row-parallel needs a psum
-    # before the residual) must opt out.
-    _megakernel_ok = True
 
     def __init__(self, model, max_slots, max_len, page_size=128,
                  do_sample=False, temperature=1.0, top_k=None, top_p=None,
                  eos_token_id=None, prompt_buckets=(16, 32, 64, 128),
-                 seed=0, pipeline=None, pool_pages=None, prefix_cache=True):
+                 seed=0, pipeline=True, pool_pages=None, prefix_cache=True):
         from ..jit import _FunctionalModel, _swap_lock
 
         model.eval()
@@ -358,7 +344,7 @@ class ContinuousBatchingEngine:
         self.top_p = top_p
         self.eos_token_id = eos_token_id
         self.prompt_buckets = tuple(sorted(prompt_buckets))
-        self.pipeline_opt = pipeline
+        self._pipeline = bool(pipeline)
         # what a token keeps a layer: the model's own page shapes where
         # it has a say (a latent cache), else (kv heads, head size) twice
         k_shape, v_shape = kv_page_shapes(model)
@@ -464,15 +450,6 @@ class ContinuousBatchingEngine:
         self._warmed = False
         self._prefill_p = None
         self._segment_p = None
-        # fused decode path (FLAGS_decode_megakernel): decided ONCE per
-        # engine — the fused segment program is built and AOT-warmed
-        # only when the model passes the capability probe, so the
-        # zero-post-warmup-compile invariant covers both paths
-        from ..ops.pallas.decode_megakernel import megakernel_model_supported
-
-        self._megakernel = (int(flag("FLAGS_decode_megakernel")) > 0
-                            and type(self)._megakernel_ok
-                            and megakernel_model_supported(model))
         self._build_programs()
 
     # -------------------------------------------- page recycling safety
@@ -554,8 +531,7 @@ class ContinuousBatchingEngine:
             aligned = self.prompt_buckets[-1] % self.page_size == 0
         return [_make_paged_cache(ks[i], vs[i], tables, self.page_size,
                                   length, aligned_bases=aligned,
-                                  attn_pages=self._cols,
-                                  dump_page=self._dump_page, live=live)
+                                  attn_pages=self._cols, live=live)
                 for i in range(self._nl)]
 
     def _build_programs(self):
@@ -702,32 +678,7 @@ class ContinuousBatchingEngine:
         self._cow_p = jax.jit(cow_copy, donate_argnums=(1, 2))
         self._export_p = jax.jit(export_pages, donate_argnums=(1, 2))
         self._import_p = jax.jit(import_pages, donate_argnums=(1, 2))
-        from ..ops.pallas.decode_megakernel import megakernel_scope
-
-        def segment_unfused(*args):
-            # scope(False): the per-layer megakernel hook must not fire
-            # in a declined engine's program even under forced-kernel
-            # flag modes — this program IS the unfused reference
-            with megakernel_scope(False):
-                return segment(*args)
-
-        def segment_fused(*args):
-            with megakernel_scope(True):
-                return segment(*args)
-
-        # ONE segment program, shape decided by the construction-time
-        # probe (self._megakernel): every caller — dispatch, bisection
-        # replay, fault-injecting tests that monkeypatch _segment_p —
-        # sees the same program either way.
-        if self._megakernel:
-            from ..jit.fusion import fuse_elementwise_chains
-
-            self._segment_p = jax.jit(
-                fuse_elementwise_chains(segment_fused),
-                donate_argnums=(1, 2))
-        else:
-            self._segment_p = jax.jit(segment_unfused,
-                                      donate_argnums=(1, 2))
+        self._segment_p = jax.jit(segment, donate_argnums=(1, 2))
 
     # --------------------------------------------------- program dispatch
 
@@ -1095,9 +1046,6 @@ class ContinuousBatchingEngine:
         # pipeline state: at most ONE dispatched-but-unconsumed segment;
         # ``_dirty`` marks host mask changes the device cannot see
         # (abort / deadline retirement), forcing a drain + sync turn
-        self._pipeline = (bool(flag("FLAGS_serving_pipeline"))
-                          if self.pipeline_opt is None
-                          else bool(self.pipeline_opt))
         self._inflight = None
         self._dirty = False
         # host-gap accounting: time from finishing one segment's host
@@ -1662,8 +1610,6 @@ class ContinuousBatchingEngine:
                 _M_ATTN_LIVE.inc(int(
                     (-(-self._lengths // self.page_size)).sum()))
                 _M_ATTN_TABLE.inc(self.max_slots * self._cols)
-                if self._megakernel:
-                    _M_MEGA_SEG.inc()
         return {"emitted": emitted, "was_active": was_active, "tok": tok,
                 "lengths": new_lengths, "active": still_active,
                 "stats": stats, "mask": np.asarray(mask), "disp": d}
@@ -2478,7 +2424,7 @@ class ContinuousBatchingEngine:
             "host_gap_ms": (1e3 * self._gap_sum / self._gap_n
                             if self._gap_n else 0.0),
             "host_gap_total_s": self._gap_sum,
-            "pipelined": bool(getattr(self, "_pipeline", False)),
+            "pipelined": self._pipeline,
             "timed_out": self._counts.get("timed_out", 0),
             "failed": self._counts.get("failed", 0),
             "cancelled": self._counts.get("cancelled", 0),

@@ -166,13 +166,6 @@ class TPShardedEngine(ContinuousBatchingEngine):
     (``put_s``) — bench e8 gates it as ``tp_dispatch_overhead_pct``.
     """
 
-    # fused decode megakernel: DECLINED under TP. The kernel folds
-    # residual + post-attention norm in right after o_proj, but the
-    # row-parallel o_proj shard produces a PARTIAL sum that needs a
-    # psum across the mesh first — an in-kernel collective this kernel
-    # does not carry. TP decode stays on the unfused segment program.
-    _megakernel_ok = False
-
     def __init__(self, model, max_slots, max_len, mesh=None, tp_axis="mp",
                  plan=None, **kwargs):
         if hasattr(model, "kv_page_shapes"):

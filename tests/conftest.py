@@ -12,6 +12,15 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = flags + " --xla_force_host_platform_device_count=8"
 
+# The CPU client runs the virtual devices' executions on ONE thread pool of
+# max(PJRT_NPROC or cores, devices) threads. With eight devices on eight
+# cores, executions of the NEXT eagerly dispatched program can hold threads
+# that a collective's missing participants need: XLA's rendezvous then
+# aborts the process after 40 s ("Termination timeout"), which took an
+# xdist worker down in tests/test_semi_auto_llama.py in 7 of 48 runs under
+# the suite's load (0 of 18 with room for four programs in flight).
+os.environ.setdefault("PJRT_NPROC", "32")
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -1,5 +1,5 @@
-"""``chip_smoke.py`` rehearsed on the CPU: its serve, megakernel, train and
-four-chip phase functions at a tiny config over the virtual CPU devices of
+"""``chip_smoke.py`` rehearsed on the CPU: its serve, train and four-chip
+phase functions at a tiny config over the virtual CPU devices of
 ``conftest.py`` (Pallas kernels interpreted), and ``main()`` refusing to
 report success without a TPU. The real widths, the device gate and the
 Mosaic-kernel requirements live in ``main()``/``run()`` and only ever pass
@@ -11,16 +11,12 @@ import pytest
 
 import chip_smoke
 import paddle_tpu.distributed as dist
-from paddle_tpu.core.flags import flag, set_flags
 from paddle_tpu.models import LlamaConfig
 
 
 @pytest.fixture(autouse=True)
 def _restore():
-    before = {k: flag(k) for k in ("FLAGS_decode_megakernel",
-                                   "FLAGS_serving_pipeline")}
     yield
-    set_flags(before)
     dist.set_mesh(None)
 
 
@@ -48,21 +44,6 @@ def test_serve_phase_tiny():
     # interpreted kernels leave no Mosaic call: run() would refuse this
     assert rep["mosaic_in_segment"] is False
     assert rep["kv_pool_bytes"] > 0 and rep["weight_bytes"] > 0
-
-
-def test_megakernel_phase_tiny():
-    # flag 2 puts the real kernel (interpreted) into the fused program;
-    # at the default the CPU's fused program is the unfused composition
-    set_flags({"FLAGS_decode_megakernel": 2})
-    rep = chip_smoke.megakernel_phase(
-        _cfg(num_hidden_layers=1), seed=0, max_slots=1, max_len=64,
-        page_size=16, prompt_buckets=(16,), prompt_lens=(5, 16),
-        max_new=(6, 6), segment=3)
-    assert rep["megakernel_segments"] > 0
-    assert rep["mosaic_in_segment"] is False
-    assert set(rep["kernel_vs_oracle_max_abs"]) == {
-        "h_mid", "mlp_in", "k_pages", "v_pages"}
-    assert flag("FLAGS_decode_megakernel") == 2  # restored by the phase
 
 
 def test_train_phase_tiny():

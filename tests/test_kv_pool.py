@@ -31,6 +31,8 @@ from paddle_tpu.models.frontend import ServingFrontend
 from paddle_tpu.models.kv_pool import PagePool, PrefixCache
 from paddle_tpu.models.serving import ContinuousBatchingEngine
 
+from _tiny_models import MODEL_KINDS, sparse_latent_model
+
 
 @pytest.fixture(autouse=True)
 def _clean(tmp_path):
@@ -54,6 +56,19 @@ _CFG = LlamaConfig(vocab_size=151, hidden_size=32, intermediate_size=64,
 def model():
     paddle.seed(0)
     return LlamaForCausalLM(_CFG)
+
+
+@pytest.fixture(scope="module", params=MODEL_KINDS)
+def served(request, model):
+    """What the allocator's engine-level drills serve: the dense block,
+    or sparse experts over a latent cache (one dense layer, one sparse),
+    whose two page pools have different shapes on the one table the
+    allocator grants from."""
+    if request.param == "dense":
+        return model
+    return sparse_latent_model(vocab_size=_CFG.vocab_size,
+                               num_hidden_layers=2,
+                               max_position_embeddings=512)
 
 
 def _engine(model, **kw):
@@ -142,7 +157,7 @@ def test_prefix_cache_match_insert_evict_verifies_tokens():
 
 @pytest.mark.parametrize("pipeline", [False, True])
 @pytest.mark.parametrize("do_sample", [False, True])
-def test_shared_prefix_streams_bit_identical(model, pipeline, do_sample):
+def test_shared_prefix_streams_bit_identical(served, pipeline, do_sample):
     """Prefix-shared streams == unshared streams, greedy + per-request
     key-stream sampling, serial + pipelined — including a full-page hit,
     a mid-page CoW divergence, and an identical-prompt replay."""
@@ -153,13 +168,13 @@ def test_shared_prefix_streams_bit_identical(model, pipeline, do_sample):
             (3, pre[:40].copy(), 10),        # inside req 1, ends MID-PAGE
             (4, np.concatenate([pre, _toks(rng, 20)]), 10)]
     kw = dict(pipeline=pipeline, do_sample=do_sample, top_k=8)
-    got, _ = _serve(_engine(model, prefix_cache=True, **kw), subs)
-    want, _ = _serve(_engine(model, prefix_cache=False, **kw), subs)
+    got, _ = _serve(_engine(served, prefix_cache=True, **kw), subs)
+    want, _ = _serve(_engine(served, prefix_cache=False, **kw), subs)
     for i, (g, w) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(g, w, err_msg=f"request {i}")
 
 
-def test_cow_divergence_leaves_the_owner_intact(model):
+def test_cow_divergence_leaves_the_owner_intact(served):
     """A mid-page CoW admission while the prefix OWNER is still decoding:
     both streams match their unshared references (the copy really is a
     copy — the writer never touches the shared page)."""
@@ -168,7 +183,7 @@ def test_cow_divergence_leaves_the_owner_intact(model):
     p_owner = np.concatenate([pre, _toks(rng, 8)])
     p_cow = pre[:50].copy()                  # diverges mid page 1
     for pc in (True, False):
-        eng = _engine(model, prefix_cache=pc)
+        eng = _engine(served, prefix_cache=pc)
         eng.start(segment=2)
         owner = eng.submit(p_owner, 24, rid=1)
         eng.step()                           # owner admitted + decoding
@@ -184,13 +199,13 @@ def test_cow_divergence_leaves_the_owner_intact(model):
     np.testing.assert_array_equal(got[1], want[1], err_msg="cow reader")
 
 
-def test_refcount_survives_owner_retirement(model):
+def test_refcount_survives_owner_retirement(served):
     """Shared pages outlive the request that computed them: a later
     identical-prefix request hits the cache after the owner retired, and
     the pages only return to the pool once the cache lets go."""
     rng = _rng(4)
     pre = _toks(rng, 64)
-    eng = _engine(model)
+    eng = _engine(served)
     subs = [(1, np.concatenate([pre, _toks(rng, 12)]), 8),
             (2, np.concatenate([pre, _toks(rng, 5)]), 8)]
     _, reqs = _serve(eng, subs)              # serialized: 1 retires first
@@ -203,7 +218,7 @@ def test_refcount_survives_owner_retirement(model):
             == kv["pages_total"])
 
 
-def test_chunked_prefill_resume_long_prompts(model):
+def test_chunked_prefill_resume_long_prompts(served):
     """Prompts beyond the largest bucket resume their chunked prefill at
     the first divergent page (page-aligned) — streams identical to the
     cold engine's."""
@@ -212,8 +227,8 @@ def test_chunked_prefill_resume_long_prompts(model):
     subs = [(1, np.concatenate([shared, _toks(rng, 70)]), 8),
             (2, np.concatenate([shared, _toks(rng, 81)]), 8)]
     kw = dict(max_len=256, max_slots=2, prompt_buckets=(16, 64))
-    got, _ = _serve(_engine(model, prefix_cache=True, **kw), subs)
-    want, _ = _serve(_engine(model, prefix_cache=False, **kw), subs)
+    got, _ = _serve(_engine(served, prefix_cache=True, **kw), subs)
+    want, _ = _serve(_engine(served, prefix_cache=False, **kw), subs)
     for i, (g, w) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(g, w, err_msg=f"request {i}")
 
@@ -221,19 +236,19 @@ def test_chunked_prefill_resume_long_prompts(model):
 # ------------------------------------------------ pool-pressure drills
 
 
-def test_pool_exhausted_defers_admission_never_fails(model):
+def test_pool_exhausted_defers_admission_never_fails(served):
     """A pool sized well below max_slots * per_seq: admissions defer
     with ``serving.kv_pool_exhausted`` backpressure, every request still
     finishes ok, and the streams match the uncontended engine's."""
     rng = _rng(6)
     prompts = [_toks(rng, 10) for _ in range(6)]
     subs = [(i, p, 40) for i, p in enumerate(prompts)]
-    tight = _engine(model, max_slots=6, prompt_buckets=(16,),
+    tight = _engine(served, max_slots=6, prompt_buckets=(16,),
                     pool_pages=6)
     got, reqs = _serve(tight, subs, serialize_first=False)
     assert all(r.status == "ok" for r in reqs)
     assert resilience.counters().get("serving.kv_pool_exhausted", 0) > 0
-    roomy = _engine(model, max_slots=6, prompt_buckets=(16,))
+    roomy = _engine(served, max_slots=6, prompt_buckets=(16,))
     want, _ = _serve(roomy, subs, serialize_first=False)
     for i, (g, w) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(g, w, err_msg=f"request {i}")
@@ -243,7 +258,7 @@ def test_pool_exhausted_defers_admission_never_fails(model):
         == kv["pages_total"]
 
 
-def test_preemption_resumes_bit_identically(model):
+def test_preemption_resumes_bit_identically(served):
     """Decode growth outrunning the pool preempts the youngest slot
     (``serving.kv_preempted``) instead of failing it; the preempted
     request re-admits through the prefix cache and its final stream is
@@ -252,18 +267,18 @@ def test_preemption_resumes_bit_identically(model):
     # short prompts, long decode: admission fits but growth collides
     prompts = [_toks(rng, 6) for _ in range(4)]
     subs = [(i, p, 60) for i, p in enumerate(prompts)]
-    tight = _engine(model, max_slots=4, max_len=96, prompt_buckets=(8,),
+    tight = _engine(served, max_slots=4, max_len=96, prompt_buckets=(8,),
                     pool_pages=5)
     got, reqs = _serve(tight, subs, serialize_first=False)
     assert all(r.status == "ok" for r in reqs)
     assert resilience.counters().get("serving.kv_preempted", 0) > 0
-    roomy = _engine(model, max_slots=4, max_len=96, prompt_buckets=(8,))
+    roomy = _engine(served, max_slots=4, max_len=96, prompt_buckets=(8,))
     want, _ = _serve(roomy, subs, serialize_first=False)
     for i, (g, w) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(g, w, err_msg=f"request {i}")
 
 
-def test_preempted_fold_past_chunk_width_stays_compiled(model):
+def test_preempted_fold_past_chunk_width_stays_compiled(served):
     """A preempted request whose folded prompt (orig + emitted) outgrows
     the largest bucket re-admits through the CHUNKED path even on an
     engine whose max_len is NOT a chunk multiple (submit() rejects such
@@ -281,7 +296,7 @@ def test_preempted_fold_past_chunk_width_stays_compiled(model):
               prefix_cache=False, pipeline=False)
     prompts = [_toks(rng, 10) for _ in range(2)]
     subs = [(i, p, 10) for i, p in enumerate(prompts)]
-    tight = _engine(model, pool_pages=4, **kw)
+    tight = _engine(served, pool_pages=4, **kw)
     tight.warmup(segment=4)
     with count_backend_compiles() as compiles:
         got, reqs = _serve(tight, subs, serialize_first=False)
@@ -289,18 +304,18 @@ def test_preempted_fold_past_chunk_width_stays_compiled(model):
     assert resilience.counters().get("serving.kv_preempted", 0) > 0
     assert compiles == [], \
         f"preempted-fold path compiled {len(compiles)} programs"
-    want, _ = _serve(_engine(model, **kw), subs, serialize_first=False)
+    want, _ = _serve(_engine(served, **kw), subs, serialize_first=False)
     for i, (g, w) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(g, w, err_msg=f"request {i}")
 
 
-def test_kv_bytes_count_shared_pages_once(model):
+def test_kv_bytes_count_shared_pages_once(served):
     """Physical byte accounting under prefix sharing: slots mapping the
     same cached pages must not report more bytes in use than the pool
     physically holds (grants stay the fragmentation denominator)."""
     rng = _rng(16)
     pre = _toks(rng, 64)                  # 2 shared pages of 32
-    eng = _engine(model)
+    eng = _engine(served)
     eng.start(segment=2)
     reqs = [eng.submit(np.concatenate([pre, _toks(rng, 4)]), 30, rid=r)
             for r in (1, 2, 3)]
@@ -319,16 +334,16 @@ def test_kv_bytes_count_shared_pages_once(model):
         eng.step()
 
 
-def test_engine_fault_bisection_over_dynamic_allocator(model):
+def test_engine_fault_bisection_over_dynamic_allocator(served):
     """The PR 3 poison-isolation contract holds on the dynamic pool: the
     poisoned request fails alone, its co-batched peers finish with exact
     tokens, and no page leaks (everything not cache-held returns)."""
     rng = _rng(8)
     prompts = [_toks(rng, 12) for _ in range(4)]
     subs = [(i, p, 8) for i, p in enumerate(prompts)]
-    want, _ = _serve(_engine(model), subs, serialize_first=False)
+    want, _ = _serve(_engine(served), subs, serialize_first=False)
     set_flags({"FLAGS_fault_injection": "serving.engine_fault:1"})
-    eng = _engine(model)
+    eng = _engine(served)
     got, reqs = _serve(eng, subs, serialize_first=False)
     statuses = [r.status for r in reqs]
     assert statuses.count("failed") == 1
@@ -345,7 +360,7 @@ def test_engine_fault_bisection_over_dynamic_allocator(model):
 # --------------------------------------------- compile & config hygiene
 
 
-def test_zero_compiles_through_allocator_and_prefix_path(model):
+def test_zero_compiles_through_allocator_and_prefix_path(served):
     """A warmed engine records ZERO XLA compiles while serving through
     dynamic grants, CoW copies, prefix-resume prefill, and decode growth
     — page-table CONTENTS change, traced shapes don't."""
@@ -353,7 +368,7 @@ def test_zero_compiles_through_allocator_and_prefix_path(model):
 
     rng = _rng(9)
     pre = _toks(rng, 48)
-    eng = _engine(model, max_slots=2, max_len=64,
+    eng = _engine(served, max_slots=2, max_len=64,
                   prompt_buckets=(8, 16), page_size=16)
     eng.warmup(segment=3)
     with count_backend_compiles() as compiles:

@@ -26,7 +26,6 @@ import paddle_tpu as paddle
 from paddle_tpu.core import telemetry
 from paddle_tpu.models import (
     ContinuousBatchingEngine,
-    MoEMLAForCausalLM,
     ServingFrontend,
     TPShardedEngine,
     generate,
@@ -34,6 +33,8 @@ from paddle_tpu.models import (
 )
 from paddle_tpu.models.moe_mla import SparseExperts, route
 from paddle_tpu.models.reference import moe_mla_plain as ref
+
+from _tiny_models import sparse_latent_model
 
 LOGIT_TOL = 2e-4
 GAP_TOL = 2e-4
@@ -56,15 +57,7 @@ def _weights(model):
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(27)
-    m = MoEMLAForCausalLM(moe_mla_tiny_config())
-    m.eval()
-    # a correction bias large enough to change choices, as a trained one is
-    for name, p in m.named_parameters():
-        if name.endswith("e_score_correction_bias"):
-            p._value = 0.05 * jax.random.normal(
-                jax.random.PRNGKey(len(name)), p._value.shape, jnp.float32)
-    return m
+    return sparse_latent_model()
 
 
 def _ids(n, seed=0):
@@ -162,8 +155,7 @@ def test_paged_per_slot_decode_matches_the_reference(model):
 
     def make(k, v, length, aligned):
         return _make_paged_cache(k, v, tables, page, length,
-                                 aligned_bases=aligned, attn_pages=4,
-                                 dump_page=8)
+                                 aligned_bases=aligned, attn_pages=4)
 
     aligned = _cached_forward(model, lambda k, v, n: make(k, v, n, True))
     ragged = _cached_forward(model, lambda k, v, n: make(k, v, n, False))
@@ -402,14 +394,9 @@ def test_page_export_import_round_trip_on_the_latent_layout(model):
     dst.shutdown()
 
 
-def test_tp_engine_and_megakernel_decline_it_by_mechanism(model):
-    from paddle_tpu.ops.pallas.decode_megakernel import (
-        megakernel_model_supported)
-
+def test_tp_engine_declines_it_by_mechanism(model):
     with pytest.raises(NotImplementedError, match="latent attention"):
         TPShardedEngine(model, max_slots=2, max_len=32)
-    assert not megakernel_model_supported(model)
-    assert not _engine(model)._megakernel
 
 
 def test_dense_segment_program_has_no_statistics_output():

@@ -473,6 +473,37 @@ def test_recovery_serves_slot_enqueued_in_the_write_gap():
         master.close()
 
 
+def test_inbox_is_purged_before_the_name_is_published(monkeypatch):
+    """A caller takes the worker's name in the store as "addressable"
+    and may enqueue at once. The purge of a dead epoch's inbox
+    (resume_inbox=False) has to be over by then, or it deletes that
+    request too: a fleet router's first ``fingerprint`` call to a replica
+    then got no reply within its 10 s (test_fleet_trace / test_transfer
+    under the suite's load, the replica's serve thread starting late)."""
+    from paddle_tpu.distributed.store import TCPStore
+
+    master = TCPStore(is_master=True)
+    published = []
+    real = rpc._recover_inbox
+
+    def spy(state):
+        published.append(master.check(f"rpc/worker/{state.name}"))
+        return real(state)
+
+    monkeypatch.setattr(rpc, "_recover_inbox", spy)
+    try:
+        rpc.init_rpc("prompt", rank=1,
+                     master_endpoint=f"127.0.0.1:{master.port}",
+                     resume_inbox=False)
+        try:
+            assert master.check("rpc/worker/prompt")
+            assert published == [False]
+        finally:
+            rpc.shutdown()
+    finally:
+        master.close()
+
+
 def test_purge_inbox_on_restart_for_serving_replicas():
     """resume_inbox=False (serving replicas): a fresh incarnation purges
     unacked slots instead of replaying a dead fleet epoch's traffic."""

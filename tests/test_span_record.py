@@ -224,13 +224,28 @@ def test_every_turn_is_one_tree_that_its_children_cover(served):
     turns = [e for e in spans if e["name"] == "serving.turn"]
     assert len(turns) >= 5
     leaves = set()
+    covered = 0.0
     for turn in turns:
         assert by_id[turn["parent"]]["name"] == "serving.frontend_step"
-        children = kids[turn["id"]]
+        children = sorted(kids[turn["id"]], key=lambda c: c["t0"])
         leaves |= {c["name"] for c in children}
-        covered = sum(c["dur"] for c in children)
-        assert covered == pytest.approx(turn["dur"], rel=0.05), \
-            (turn["dur"], sorted((c["name"], c["dur"]) for c in children))
+        # one after another, and inside the turn (1e-5 s: a span's start
+        # and its duration are read from the clock separately)
+        end = turn["t0"]
+        for c in children:
+            assert c["t0"] >= end - 1e-5, (c["name"], c["t0"], end)
+            end = c["t0"] + c["dur"] * 1e-6
+        assert end <= turn["t0"] + turn["dur"] * 1e-6 + 1e-5
+        covered += sum(c["dur"] for c in children)
+    # What the children leave out is the host's code between two spans:
+    # 0.4-2.4 % of a turn with the machine to itself. It is judged over
+    # the turns together and from afar, because a worker of the suite that
+    # loses its core between two spans loses milliseconds there (5.7 of
+    # one 62.8 ms turn in the driver's run on 1adc8e5), whereas a phase
+    # without a span is missing from every turn.
+    assert covered >= 0.9 * sum(t["dur"] for t in turns), \
+        [(t["dur"], sorted((c["name"], c["dur"]) for c in kids[t["id"]]))
+         for t in turns]
     assert leaves >= {"serving.drain", "serving.admit_plan",
                       "serving.prefill_prep", "serving.prefill",
                       "serving.chunked_prefill", "serving.admit_finish",
@@ -397,33 +412,13 @@ def _bdrln():
                 row, row, row, _sds((64, 256), jnp.float32))
 
 
-def _megakernel():
-    from paddle_tpu.ops.pallas.decode_megakernel import fused_decode_layer
-
-    def fn(x, ln1, ln2, wq, wk, wv, wo, cos, sin, kp, vp, tables, lens):
-        return fused_decode_layer(
-            x, ln1_weight=ln1, ln1_eps=1e-6, wq=wq, wk=wk, wv=wv, wo=wo,
-            rope_cos=cos, rope_sin=sin, ln2_weight=ln2, ln2_eps=1e-6,
-            k_pages=kp, v_pages=vp, tables=tables, lengths=lens, heads=2,
-            dump_page=None)
-
-    h, d = 256, 128
-    pool = _sds((9, 128, 2, d))
-    tab = _sds((256, d), jnp.float32)
-    return fn, (_sds((4, 1, h)), _sds((h,)), _sds((h,)), _sds((h, h)),
-                _sds((h, h)), _sds((h, h)), _sds((h, h)), tab, tab, pool,
-                pool, _sds((4, 2), jnp.int32), _sds((4,), jnp.int32))
-
-
 @pytest.mark.parametrize("site,names", [
     (_paged, {"paged_attention"}),
     (_flash, {"flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"}),
     (_rms, {"rms_norm_fwd", "rms_norm_bwd"}),
     (_rope, {"fused_rope"}),
     (_bdrln, {"fused_bias_dropout_residual_ln"}),
-    (_megakernel, {"decode_megakernel"}),
-], ids=["paged_attention", "flash", "rms_norm", "fused_rope", "bdrln",
-        "decode_megakernel"])
+], ids=["paged_attention", "flash", "rms_norm", "fused_rope", "bdrln"])
 def test_every_pallas_call_lowered_for_the_tpu_carries_its_name(
         monkeypatch, site, names):
     """What the chip's trace shows of a Mosaic kernel is its

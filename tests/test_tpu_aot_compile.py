@@ -25,16 +25,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-import paddle_tpu as paddle
-from paddle_tpu.jit.fusion import fuse_elementwise_chains
-from paddle_tpu.models import LlamaConfig
-from paddle_tpu.models.llama import LlamaDecoderLayer
 from paddle_tpu.ops import pallas
 from paddle_tpu.ops.pallas.decode_attention import paged_attention
-from paddle_tpu.ops.pallas.decode_megakernel import (
-    fused_decode_layer,
-    megakernel_layer_supported,
-)
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.fused_ops import fused_rope
 from paddle_tpu.ops.pallas.rms_norm import rms_norm
@@ -124,42 +116,6 @@ def test_flash_attention_fwd_bwd_compiles(topo, seq, heads, kv_heads):
              *[((2, seq, n, 128), BF16) for n in (heads, kv_heads, kv_heads)])
 
 
-def _decode_layer_shapes(hidden, heads, kv_heads, d, b=8, pages=65,
-                         page=128, per_seq=8):
-    return (((b, 1, hidden), BF16), ((hidden,), BF16), ((hidden,), BF16),
-            ((hidden, heads * d), BF16), ((hidden, kv_heads * d), BF16),
-            ((hidden, kv_heads * d), BF16), ((heads * d, hidden), BF16),
-            ((2048, d), jnp.float32), ((2048, d), jnp.float32),
-            ((pages, page, kv_heads, d), BF16),
-            ((pages, page, kv_heads, d), BF16),
-            ((b, per_seq), jnp.int32), ((b,), jnp.int32))
-
-
-def _decode_layer(heads, dump_page):
-    def fn(x, ln1, ln2, wq, wk, wv, wo, cos, sin, kp, vp, tables, lens):
-        return fused_decode_layer(
-            x, ln1_weight=ln1, ln1_eps=1e-6, wq=wq, wk=wk, wv=wv, wo=wo,
-            rope_cos=cos, rope_sin=sin, ln2_weight=ln2, ln2_eps=1e-6,
-            k_pages=kp, v_pages=vp, tables=tables, lengths=lens,
-            heads=heads, dump_page=dump_page)
-    return fn
-
-
-def test_fused_decode_layer_compiles_at_widest_admitted_width(topo):
-    """hidden 1152 = 9 heads x 128 is the widest MHA width whose attention
-    projections fit the probe's VMEM budget (``chip_smoke.MEGAKERNEL``).
-    The fused engine's segment program also runs the elementwise-chain
-    fusion pass over the kernel: it must leave the kernel body alone (the
-    Pallas TPU lowering has no rule for ``closed_call``)."""
-    _compile(fuse_elementwise_chains(_decode_layer(9, dump_page=64)), topo,
-             *_decode_layer_shapes(1152, 9, 9, 128))
-
-
-def test_fused_decode_layer_compiles_in_write_back_mode(topo):
-    _compile(_decode_layer(2, dump_page=None), topo,
-             *_decode_layer_shapes(256, 2, 2, 128))
-
-
 def test_rms_norm_and_fused_rope_compile_at_hidden_4096(topo):
     def norm_loss(x, w):
         return rms_norm(x, w, jnp.zeros_like(w), 1e-6, False).astype(
@@ -219,34 +175,3 @@ def test_kernels_partition_over_a_four_chip_mesh(topo):
     with pytest.raises(NotImplementedError, match="shard_map"):
         jax.jit(lambda q, k, v: flash_attention(q, k, v, is_causal=True)
                 ).lower(qkv, qkv, qkv)
-
-
-def _layer(hidden, heads):
-    cfg = LlamaConfig(vocab_size=64, hidden_size=hidden,
-                      intermediate_size=128, num_hidden_layers=1,
-                      num_attention_heads=heads, max_position_embeddings=8)
-    paddle.seed(0)
-    before = paddle.get_default_dtype()
-    paddle.set_default_dtype("bfloat16")
-    try:
-        return LlamaDecoderLayer(cfg)
-    finally:
-        paddle.set_default_dtype(before)
-
-
-@pytest.mark.parametrize("hidden,heads,admitted", [
-    (1152, 9, True),      # the widest MHA width within the VMEM budget
-    (1024, 16, False),    # head_dim 64: Mosaic cannot lay the rows out
-    (4096, 32, False),    # LLaMA-7B projections: 128 MiB, far past VMEM
-], ids=["h1152_9x128", "head_dim_64", "h4096_32x128"])
-def test_megakernel_probe_declines_what_the_compiler_refuses(
-        hidden, heads, admitted):
-    assert megakernel_layer_supported(_layer(hidden, heads)) is admitted
-
-
-def test_megakernel_probe_admits_head_dim_64_only_interpreted(monkeypatch):
-    """The lane-width rule binds only where Mosaic compiles the kernel."""
-    layer = _layer(256, 4)
-    assert not megakernel_layer_supported(layer)
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    assert megakernel_layer_supported(layer)

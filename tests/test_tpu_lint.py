@@ -534,11 +534,7 @@ def test_baseline_accepts_bare_list_format(tmp_path):
 def test_jit_entries_include_the_serving_programs(repo_report):
     names = {e["name"] for e in repo_report["jit_entries"]}
     assert "ContinuousBatchingEngine._build_programs.prefill" in names
-    assert "ContinuousBatchingEngine._build_programs.segment_unfused" \
-        in names
-    # the decode megakernel reaches pallas_call via a local
-    # functools.partial binding — it must still be swept
-    assert "_megakernel" in names
+    assert "ContinuousBatchingEngine._build_programs.segment" in names
     wrappers = {e["wrapper"] for e in repo_report["jit_entries"]}
     assert {"jit", "shard_map", "pallas_call"} <= wrappers
 

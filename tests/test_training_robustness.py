@@ -100,20 +100,32 @@ def _weights(model):
 
 
 def test_worker_crash_is_respawned_and_epoch_completes():
+    # ONE worker, so the epoch cannot end before the parent has seen the
+    # death: the crash order sits in the task queue behind the first two
+    # batches (prefetch_factor 2), and the other four exist only if the
+    # worker is respawned. With a sibling alive the epoch could finish on
+    # the sibling while the ordered worker was still exiting, and the
+    # respawn count read 0 (the driver's runs, every one since the seed).
     set_flags({"FLAGS_fault_injection": "dataloader.worker_crash:1"})
-    dl = DataLoader(Squares(24), batch_size=4, num_workers=2,
+    dl = DataLoader(Squares(24), batch_size=4, num_workers=1,
                     use_process_workers=True)
     vals = sorted(np.concatenate(
         [np.asarray(b._value) for b in dl]).tolist())
-    # no batch lost to the killed worker: its in-flight work was re-queued
+    # no batch lost to the killed worker: what was submitted and not
+    # delivered was re-queued (and what then ran twice, deduped)
     assert vals == [float(i) for i in range(24)]
     assert resilience.get_counter("dataloader.worker_respawns") == 1
     assert resilience.get_counter("fault_injected:dataloader.worker_crash") == 1
 
 
 def test_worker_crash_respawn_budget_exhaustion_raises_not_hangs():
+    # a crash is ordered before every batch, and batch j >= 4 is queued
+    # behind j - 3 of those orders: the 2 + 2 lives the budget allows
+    # cannot get past batch 6, so an epoch of 12 batches cannot end without
+    # the third death being seen. (At 6 batches the last one sat behind two
+    # orders only: the epoch finished first and nothing was raised.)
     set_flags({"FLAGS_fault_injection": "dataloader.worker_crash:*"})
-    dl = DataLoader(Squares(24), batch_size=4, num_workers=2,
+    dl = DataLoader(Squares(48), batch_size=4, num_workers=2,
                     use_process_workers=True, worker_respawn_limit=2)
     with pytest.raises(DataLoaderWorkerError) as ei:
         list(dl)

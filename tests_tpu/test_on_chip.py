@@ -10,10 +10,9 @@ process holding it:
 
 Covered: bf16 matmul numerics, op spot-checks at bf16 tolerances, the
 Pallas kernels (flash attention fwd+bwd, RMSNorm, paged/masked decode
-attention, fused rope, fused bias-dropout-residual-LN, the fused decode
-layer), one compiled TrainStep and the continuous-batching engine with
-``FLAGS_decode_megakernel`` at its default. A kernel in interpret mode or
-a native library that cannot be built is a failure here, not a warning.
+attention, fused rope, fused bias-dropout-residual-LN), one compiled
+TrainStep and the continuous-batching engine. A kernel in interpret mode
+or a native library that cannot be built is a failure here, not a warning.
 Pass/fail per test is recorded in CHANGES.md by the PR that ran it.
 """
 import math
@@ -387,59 +386,10 @@ def test_fused_linear_cross_entropy_on_chip():
                                rtol=BF16_RTOL, atol=BF16_ATOL)
 
 
-@pytest.mark.parametrize("mode", ["dump", "writeback"])
-def test_fused_decode_layer_on_chip(mode):
-    """The decode megakernel compiled by Mosaic against its jnp oracle
-    (the exact unfused composition), bf16, at head_dim 128: fresh,
-    mid-page, page-boundary and near-full depths, in both page-flush
-    modes. The kernel rounds where the composition rounds, so they
-    agree to a step or two of bf16 at the value scale."""
-    from paddle_tpu.ops.pallas.decode_megakernel import (
-        fused_decode_layer, reference_decode_layer)
-
-    b, hidden, heads, kvh, d, page, per_seq = 4, 1024, 8, 4, 128, 128, 4
-    n_pages = b * per_seq + 1
-    bf = jnp.bfloat16
-    w = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.05, bf)
-    pos = np.arange(page * per_seq)[:, None]
-    inv = 1.0 / (10000.0 ** (np.arange(0, d, 2) / d))
-    ang = np.concatenate([pos * inv, pos * inv], axis=-1)
-    arrays = dict(
-        x=jnp.asarray(rng.standard_normal((b, 1, hidden)), bf),
-        ln1_weight=w(hidden) + 1, ln2_weight=w(hidden) + 1,
-        wq=w(hidden, heads * d), wk=w(hidden, kvh * d),
-        wv=w(hidden, kvh * d), wo=w(heads * d, hidden),
-        rope_cos=jnp.asarray(np.cos(ang), jnp.float32),
-        rope_sin=jnp.asarray(np.sin(ang), jnp.float32),
-        k_pages=jnp.asarray(
-            rng.standard_normal((n_pages, page, kvh, d)), bf),
-        v_pages=jnp.asarray(
-            rng.standard_normal((n_pages, page, kvh, d)), bf),
-        tables=jnp.asarray(rng.permutation(n_pages - 1)
-                           .reshape(b, per_seq).astype(np.int32)),
-        lengths=jnp.asarray([0, page - 1, page, page * per_seq - 2],
-                            jnp.int32))
-    static = dict(ln1_eps=1e-6, ln2_eps=1e-6, heads=heads,
-                  dump_page=n_pages - 1 if mode == "dump" else None)
-    got = jax.jit(lambda a: fused_decode_layer(**a, **static))(arrays)
-    want = jax.jit(lambda a: reference_decode_layer(**a, **static))(arrays)
-    keep = np.arange(n_pages) != n_pages - 1   # dump page: garbage
-    for name, g, r in zip(("h_mid", "mlp_in", "k_pages", "v_pages"),
-                          got, want):
-        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
-        if g.shape[0] == n_pages and mode == "dump":
-            g, r = g[keep], r[keep]
-        assert np.all(np.isfinite(g)), name
-        tol = 2.0 ** -6 * max(1.0, np.abs(r).max())
-        assert np.abs(g - r).max() <= tol, (name, np.abs(g - r).max(), tol)
-
-
 def test_continuous_batching_on_chip():
     """Per-slot-depth decode segments (continuous batching) must emit the
     same greedy tokens as per-request generate() with the REAL paged
-    Pallas kernel in the loop. FLAGS_decode_megakernel is at its default:
-    this model passes the capability probe, so the segment program is
-    the fused one."""
+    Pallas kernel in the loop."""
     import paddle_tpu as paddle
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.models.generation import generate
@@ -457,7 +407,6 @@ def test_continuous_batching_on_chip():
                for n in (7, 19, 12)]
     eng = ContinuousBatchingEngine(m, max_slots=2, max_len=256,
                                    page_size=128, prompt_buckets=(32,))
-    assert eng._megakernel
     outs, stats = eng.run(prompts, max_new_tokens=8, segment=4)
     assert stats["useful_tokens"] == 3 * 8
     for i, p in enumerate(prompts):
