@@ -266,12 +266,19 @@ def cached_attention(q, k, v, cache, offset, s):
     LLaMA and GPT decode paths. Decode steps (s=1) run the Pallas
     paged/masked decode kernel (ops/pallas/decode_attention.py — the
     analogs of block_multi_head_attention / masked_multihead_attention);
-    prefill and the CPU fallback use the masked XLA composition. ``offset``
-    may be a traced scalar (the compiled decode loop)."""
+    s > 1 new tokens behind an offset that is not a static zero (a chunk of
+    a long prompt, the tail behind a prefix hit) run the flash forward over
+    the row's own pages, each row bounded by its own offset; shapes those
+    kernels decline, ``StaticCache`` prefill and the kernels-off fallback
+    use the masked XLA composition. ``offset`` may be a traced scalar (the
+    compiled decode loop) or a per-sequence (B,) array."""
     from ..core.flags import flag as _flag
     from ..ops.pallas.decode_attention import (
         masked_decode_attention, paged_attention,
         paged_attention_supported,
+    )
+    from ..ops.pallas.flash_attention import (
+        flash_attention_paged, flash_attention_paged_supported,
     )
 
     paged = isinstance(cache, PagedKVCache)
@@ -304,6 +311,16 @@ def cached_attention(q, k, v, cache, offset, s):
             # prefill: the new tokens attend only among themselves —
             # plain causal attention while the pages fill
             return scaled_dot_product_attention(q, k, v, is_causal=True)
+        if (s > 1 and _flag("FLAGS_use_pallas_kernels")
+                and flash_attention_paged_supported(q._value,
+                                                    cache.k_pages)):
+            # prefill over a cache: the new keys are in the pages already
+            # (update ran first), so one kernel over the row's pages
+            # covers the chunk's own block; its work follows offset + s
+            bases = (offset if per_seq
+                     else jnp.full((q.shape[0],), offset, jnp.int32))
+            return Tensor._from_value(flash_attention_paged(
+                q._value, cache.k_pages, cache.v_pages, read_tables, bases))
         # jnp fallback (kernel off/unsupported): gather the pages back
         # into the contiguous layout and run the masked composition
         k_all = cache.k_pages[read_tables].reshape(

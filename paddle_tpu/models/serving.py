@@ -126,6 +126,16 @@ _M_ATTN_TABLE = telemetry.counter(
     "serving.attn_pages_table_total", "max_slots x attention-visible "
     "table columns per decode segment dispatched; live over table is the "
     "share of the page table that held tokens")
+_M_PREFILL_LIVE = telemetry.counter(
+    "serving.prefill_attn_cols_live_total", "cache columns a prefill over "
+    "a cache has to score, sum of base + chunk over the real rows of every "
+    "chunk, final-chunk and prefix-resume dispatch (the host's own bases): "
+    "what the paged flash forward walks")
+_M_PREFILL_TABLE = telemetry.counter(
+    "serving.prefill_attn_cols_table_total", "real rows x attention-"
+    "visible table columns x page size per such dispatch: what a table-"
+    "wide masked composition scores; live over table is the share of it "
+    "that held anything to attend")
 # KV-occupancy accounting (perfwatch): the measurement side of the
 # paged-KV roadmap item — logical occupancy of the preallocated page
 # pool, not PJRT allocator bytes (the pool is allocated up front; the
@@ -1414,6 +1424,13 @@ class ContinuousBatchingEngine:
             for i, (slot, req) in enumerate(group):
                 self._finish_admit(slot, req, tok0[i], finished)
 
+    def _count_prefill_cols(self, bases, width):
+        """The two ``serving.prefill_attn_cols_*`` counters for one
+        dispatch of ``width`` new tokens a row at its real rows' ``bases``."""
+        if telemetry.enabled():
+            _M_PREFILL_LIVE.inc(int(np.sum(bases + width)))
+            _M_PREFILL_TABLE.inc(len(bases) * self._cols * self.page_size)
+
     def _dispatch_resume(self, group, bucket, finished):
         """PREFIX-RESUME admission dispatch: each row's shared prefix
         (``_resume_base`` tokens, keyed by request IDENTITY — rids are
@@ -1449,6 +1466,7 @@ class ContinuousBatchingEngine:
                     self._params, self._ks, self._vs, jnp.asarray(padded),
                     jnp.asarray(self._tables_np[rows]), jnp.asarray(bases),
                     jnp.asarray(true_lens), self._prefill_keys(group, g))
+            self._count_prefill_cols(bases[:len(group)], bucket)
             with annotate("serving.first_token_fetch"):
                 tok0 = np.asarray(tok0)
             self._mark_executed(d)
@@ -1533,6 +1551,7 @@ class ContinuousBatchingEngine:
                         jnp.asarray(chunk_arr),
                         jnp.asarray(self._tables_np[rows]),
                         jnp.asarray(bases))
+                self._count_prefill_cols(bases[rows != scratch], chunk_w)
             c += 1
         if live:
             with annotate("serving.prefill_prep"):
@@ -1561,6 +1580,7 @@ class ContinuousBatchingEngine:
                         jnp.asarray(self._tables_np[rows]),
                         jnp.asarray(bases), jnp.asarray(true_rem),
                         self._prefill_keys(live, g))
+                self._count_prefill_cols(bases[:len(live)], chunk_w)
                 with annotate("serving.first_token_fetch"):
                     tok0 = np.asarray(tok0)  # blocking fetch
                 self._mark_executed(d)

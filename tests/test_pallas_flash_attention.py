@@ -12,6 +12,8 @@ import paddle_tpu as paddle
 from paddle_tpu.core.flags import flag
 from paddle_tpu.ops.pallas import flash_attention as fa
 
+from _jaxpr import pallas_names
+
 
 def _naive(q, k, v, causal):
     """Plain attention; kv heads repeated over their group (GQA) and, for
@@ -405,3 +407,154 @@ def test_attention_op_traces_over_the_active_kernel_mesh():
                         for m in in_a)
     assert in_b and all(m.devices.tolist() == mesh_b.devices.tolist()
                         for m in in_b)
+
+
+# ------------------------------------------- forward over a paged cache
+
+PAGE, PER_SEQ = 128, 4          # a row's table: 4 pages = max_len 512
+
+
+def _prefill_over_cache(width, n_real, bases, heads, kv_heads, seed, d=16):
+    """One layer's operands as the engine's chunk / final / resume
+    programs hand them to ``cached_attention``: ``width`` rows of 128 new
+    tokens, the last ``width - n_real`` of them padding rows (base 0, the
+    table's scratch row), a pool whose live pages hold anything."""
+    r = np.random.RandomState(seed)
+    n_pages = n_real * PER_SEQ + 1                      # + the dump page
+    real = r.permutation(n_pages - 1)[:n_real * PER_SEQ].reshape(
+        n_real, PER_SEQ)
+    tables = np.full((width, PER_SEQ + 1), n_pages - 1, np.int32)
+    tables[:n_real, :PER_SEQ] = real
+    offsets = np.zeros((width,), np.int32)
+    offsets[:n_real] = bases
+    q, k, v = (r.randn(width, 128, n, d).astype(np.float32)
+               for n in (heads, kv_heads, kv_heads))
+    pools = [r.randn(n_pages, PAGE, kv_heads, d).astype(np.float32)
+             for _ in range(2)]
+    return q, k, v, pools, tables, offsets
+
+
+def _cached_attention(operands, kernels):
+    """``models.llama.cached_attention`` over those operands, with the
+    Pallas routes on or off (off: the masked composition, the oracle)."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.models.generation import _make_paged_cache
+    from paddle_tpu.models.llama import cached_attention
+
+    q, k, v, pools, tables, offsets = operands
+    cache = _make_paged_cache(
+        jnp.asarray(pools[0]), jnp.asarray(pools[1]), jnp.asarray(tables),
+        PAGE, jnp.asarray(offsets), attn_pages=PER_SEQ)
+    paddle.set_flags({"FLAGS_use_pallas_kernels": kernels})
+    try:
+        out = cached_attention(*(Tensor._from_value(jnp.asarray(x))
+                                 for x in (q, k, v)), cache,
+                               jnp.asarray(offsets), 128)
+    finally:
+        paddle.set_flags({"FLAGS_use_pallas_kernels": True})
+    return np.asarray(out._value), cache
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 1), (2, 2)],
+                         ids=["gqa4to1", "mha"])
+@pytest.mark.parametrize("width,n_real", [(1, 1), (4, 3)],
+                         ids=["width1", "width4_padding_row"])
+@pytest.mark.parametrize("bases", [
+    (0, 0, 0), (128, 256, 384), (5, 200, 383), (0, 77, 256)],
+    ids=["zero", "page_aligned", "mid_page", "per_row"])
+def test_flash_attention_paged_matches_the_masked_composition(
+        bases, width, n_real, heads, kv_heads):
+    """Query ``r`` of row ``b`` sees cache columns ``<= base[b] + r``: the
+    kernel over the row's pages against the composition that gathers the
+    whole table and masks it, through the same ``cached_attention``."""
+    operands = _prefill_over_cache(width, n_real, bases[:n_real], heads,
+                                   kv_heads, seed=len(bases) + width)
+    got, cache = _cached_attention(operands, kernels=True)
+    want, oracle_cache = _cached_attention(operands, kernels=False)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # both wrote the chunk's keys before reading them
+    np.testing.assert_array_equal(np.asarray(cache.k_pages),
+                                  np.asarray(oracle_cache.k_pages))
+
+
+@pytest.mark.parametrize("true_len", [1, 37])
+def test_flash_attention_paged_final_chunk_tail_stays_finite(true_len):
+    """A final chunk is padded to 128: its last real token may be the
+    slot's last position, so the padded tail reaches past the table's
+    attention-visible columns. Rows up to ``true_len`` are exact; the tail
+    may hold anything finite."""
+    base = PER_SEQ * PAGE - true_len
+    operands = _prefill_over_cache(2, 1, (base,), 4, 2, seed=true_len)
+    got, _ = _cached_attention(operands, kernels=True)
+    want, _ = _cached_attention(operands, kernels=False)
+    np.testing.assert_allclose(got[0, :true_len], want[0, :true_len],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got).all()
+
+
+def test_cached_attention_routes_by_what_the_call_shows():
+    """The route is chosen from the call alone: s > 1 behind an offset on
+    a paged cache whose shapes the kernel takes. A scalar offset rides it
+    too; a chunk the flash family declines (not a multiple of 128) and the
+    kernels-off program keep the composition."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.models.generation import _make_paged_cache
+    from paddle_tpu.models.llama import cached_attention
+
+    q, k, v, pools, tables, offsets = _prefill_over_cache(
+        2, 2, (128, 40), 4, 2, seed=0)
+
+    def traced(offset, s, kernels=True):
+        def f(q, k, v, kp, vp, offset):
+            cache = _make_paged_cache(kp, vp, jnp.asarray(tables), PAGE,
+                                      offset, attn_pages=PER_SEQ)
+            return cached_attention(
+                *(Tensor._from_value(x[:, :s]) for x in (q, k, v)), cache,
+                offset, s)._value
+        paddle.set_flags({"FLAGS_use_pallas_kernels": kernels})
+        try:
+            return pallas_names(jax.make_jaxpr(f)(
+                q, k, v, *pools, offset).jaxpr)
+        finally:
+            paddle.set_flags({"FLAGS_use_pallas_kernels": True})
+
+    assert traced(jnp.asarray(offsets), 128) == ["flash_fwd_paged"]
+    assert traced(jnp.int32(128), 128) == ["flash_fwd_paged"]
+    assert traced(jnp.asarray(offsets), 64) == []
+    assert traced(jnp.asarray(offsets), 128, kernels=False) == []
+
+
+def test_flash_attention_without_bases_traces_what_it_traced_before():
+    """The training cell's forward and backward kernels are the same
+    family as the paged forward: a call that names no cache must trace to
+    the program it traced before the paged kernel existed. The digests are
+    of the jaxprs' text at commit 7ef5d9e (source positions and addresses
+    taken out), recorded from that commit's own tree with this JAX."""
+    import hashlib
+    import re
+
+    B, S, H, KVH, D = 2, 256, 4, 2, 128
+    avals = [jax.ShapeDtypeStruct((B, S, n, D), jnp.bfloat16)
+             for n in (H, KVH, KVH)]
+
+    def forward(q, k, v):
+        return fa.flash_attention(q, k, v, is_causal=True)
+
+    def loss(q, k, v):
+        return forward(q, k, v).astype(jnp.float32).sum()
+
+    def digest(fn):
+        jaxpr = jax.make_jaxpr(fn)(*avals)
+        text = re.sub(r" at [^\n]*?:\d+", "", str(jaxpr))
+        text = re.sub(r"\.py:\d+", ".py", text)
+        text = re.sub(r"0x[0-9a-f]+", "0x", text)
+        return jaxpr, hashlib.sha256(text.encode()).hexdigest()
+
+    jaxpr, fwd = digest(forward)
+    assert pallas_names(jaxpr.jaxpr) == ["flash_fwd"]
+    assert fwd == ("f162a9bef57ec8787c7685f9f807a8783ef099a0"
+                   "cf478bf2c5b9a0c8855db848")
+    _, bwd = digest(jax.grad(loss, argnums=(0, 1, 2)))
+    assert bwd == ("9000b69575f67fac6cc927e1dd00aec6ca04bcab"
+                   "0be96302faa8095620238f8d")

@@ -192,6 +192,42 @@ def test_tp_engine_bit_identical_to_single_chip():
     assert st["tp"]["kv_sharded"]  # 2 kv heads over 2 shards
 
 
+def test_tp_engine_chunks_through_the_paged_flash_forward():
+    """Chunks of 128 over pages of 128 take ``flash_fwd_paged``; under the
+    TP engine that kernel runs inside ``shard_map`` with a kv head a
+    shard, and a three-chunk prompt's tokens still equal the single-chip
+    engine's."""
+    from _jaxpr import pallas_calls
+
+    kw = dict(max_slots=2, max_len=384, page_size=128,
+              prompt_buckets=(128,), seed=13)
+    prompts = [np.arange(300, dtype=np.int32) * 7 % _CFG.vocab_size,
+               np.arange(20, dtype=np.int32) % _CFG.vocab_size]
+
+    def model():
+        paddle.seed(0)
+        return LlamaForCausalLM(LlamaConfig(
+            vocab_size=97, hidden_size=16, intermediate_size=32,
+            num_hidden_layers=2, num_attention_heads=2,
+            max_position_embeddings=384, tie_word_embeddings=True))
+
+    outs0, _ = ContinuousBatchingEngine(model(), **kw).run(
+        prompts, max_new_tokens=6, segment=4)
+    e1 = TPShardedEngine(model(), mesh=serving_mesh(2), **kw)
+    outs1, st = e1.run(prompts, max_new_tokens=6, segment=4)
+    for a, b in zip(outs0, outs1):
+        np.testing.assert_array_equal(a, b)
+    assert st["tp"]["kv_sharded"]
+
+    i32 = np.int32
+    jaxpr = e1._chunk_p.trace(
+        e1._params, e1._ks, e1._vs, np.zeros((1, 128), i32),
+        e1._tables_np[:1], np.zeros((1,), i32)).jaxpr.jaxpr
+    calls = pallas_calls(jaxpr)
+    assert [name for name, _ in calls] == ["flash_fwd_paged"] * 2
+    assert all("shard_map" in enclosing for _, enclosing in calls)
+
+
 def test_tp_engine_serial_equals_pipelined():
     """The overlapped scheduler's speculative dispatch must stay
     token-identical on the sharded programs too."""

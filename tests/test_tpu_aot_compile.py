@@ -27,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import pallas
 from paddle_tpu.ops.pallas.decode_attention import paged_attention
-from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                   flash_attention_paged)
 from paddle_tpu.ops.pallas.fused_ops import fused_rope
 from paddle_tpu.ops.pallas.rms_norm import rms_norm
 
@@ -116,6 +117,19 @@ def test_flash_attention_fwd_bwd_compiles(topo, seq, heads, kv_heads):
              *[((2, seq, n, 128), BF16) for n in (heads, kv_heads, kv_heads)])
 
 
+@pytest.mark.parametrize("b", [1, 32], ids=["width1", "width32"])
+def test_flash_attention_paged_compiles_at_the_chat_cell_shape(topo, b):
+    """The chunk / final / resume programs' attention: 128 new tokens a
+    row, 32 query / 8 KV heads, the 513-page pool read through a table of
+    32 attention-visible columns. The pool goes in as it is: no copy of
+    it may appear beside the kernel."""
+    pool = ((513, 128, 8, 128), BF16)
+    compiled = _compile(flash_attention_paged, topo,
+                        ((b, 128, 32, 128), BF16), pool, pool,
+                        ((b, 32), jnp.int32), ((b,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * b * 2 ** 20
+
+
 def test_rms_norm_and_fused_rope_compile_at_hidden_4096(topo):
     def norm_loss(x, w):
         return rms_norm(x, w, jnp.zeros_like(w), 1e-6, False).astype(
@@ -159,6 +173,17 @@ def test_kernels_partition_over_a_four_chip_mesh(topo):
         text = jax.jit(decode).lower(
             aval((b, 32, 128), BF16, None, "mp", None, mesh=over), pool,
             pool, aval((b, cols), jnp.int32, mesh=over),
+            aval((b,), jnp.int32, mesh=over)).compile().as_text()
+        assert MOSAIC_CALL in text
+
+        def chunk(q, kp, vp, tables, bases):
+            with pallas.kernel_mesh(over, head_axis="mp"):
+                return flash_attention_paged(q, kp, vp, tables, bases)
+
+        text = jax.jit(chunk).lower(
+            aval((b, 128, 32, 128), BF16, None, None, "mp", None,
+                 mesh=over), pool, pool,
+            aval((b, cols), jnp.int32, mesh=over),
             aval((b,), jnp.int32, mesh=over)).compile().as_text()
         assert MOSAIC_CALL in text
 

@@ -464,3 +464,87 @@ def test_moe_gmm_on_chip(m, total):
     np.testing.assert_allclose(np.asarray(out, np.float32)[:total],
                                np.asarray(want)[:total], rtol=BF16_RTOL,
                                atol=BF16_ATOL)
+
+
+def _prefill_over_cache_operands(width, bases, seed):
+    """The chat cell's chunk program at one layer: 32 query / 8 kv heads
+    of 128, page 128, ``max_len`` 4096, a 513-page bf16 pool."""
+    r = np.random.RandomState(seed)
+    H, KVH, D, PAGE, PER, NPAGES, S = 32, 8, 128, 128, 32, 513, 128
+    bf = jnp.bfloat16
+    q = jnp.asarray(r.randn(width, S, H, D).astype(np.float32), bf)
+    kp = jnp.asarray(r.randn(NPAGES, PAGE, KVH, D).astype(np.float32), bf)
+    vp = jnp.asarray(r.randn(NPAGES, PAGE, KVH, D).astype(np.float32), bf)
+    tables = jnp.asarray(
+        r.permutation(NPAGES - 1)[:width * PER].reshape(width, PER),
+        jnp.int32)
+    return q, kp, vp, tables, jnp.asarray(bases, jnp.int32)
+
+
+def _masked_composition(q, kp, vp, tables, bases):
+    """What ``cached_attention`` ran for a chunk before the kernel: every
+    table column gathered, every column scored, a dense mask."""
+    from paddle_tpu.ops.nn_kernels import scaled_dot_product_attention
+
+    b, s = q.shape[0], q.shape[1]
+    k = kp[tables].reshape(b, -1, *kp.shape[2:])
+    v = vp[tables].reshape(b, -1, *vp.shape[2:])
+    rows = jnp.arange(s)[None, :] + bases[:, None]
+    mask = (jnp.arange(k.shape[1])[None, None, None, :]
+            <= rows[:, None, :, None])
+    return scaled_dot_product_attention(q, k, v, attn_mask=mask)
+
+
+def _device_ms_a_call(fn, q, *rest, steps=25):
+    """Mean time of one ``fn(q, *rest)`` inside ONE program that calls it
+    ``steps`` times, each call's output the next one's queries (a host
+    that times single calls reads its own dispatch, ~0.2 ms)."""
+    import time
+
+    @jax.jit
+    def many(q, *rest):
+        return jax.lax.scan(lambda q, _: (fn(q, *rest), None), q, None,
+                            length=steps)[0]
+
+    many(q, *rest).block_until_ready()
+    t0 = time.perf_counter()
+    many(q, *rest).block_until_ready()
+    return 1e3 * (time.perf_counter() - t0) / steps
+
+
+@pytest.mark.parametrize("width,bases", [
+    (1, [0]), (1, [128]), (1, [1000]), (2, [0, 1000]), (2, [128, 1000]),
+])
+def test_flash_attention_paged_at_the_chat_cell_shape(width, bases):
+    """Prefill over a cache, kernel against the masked composition it
+    replaced, at the shapes ``mistral7b-chat-open`` runs ``chunk_step``
+    / ``final_chunk`` with."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_paged
+
+    ops = _prefill_over_cache_operands(width, bases, 2147490000 % 2**31)
+    out = jax.jit(flash_attention_paged)(*ops)
+    want = jax.jit(_masked_composition)(*ops)
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=BF16_RTOL, atol=BF16_ATOL)
+
+
+def test_flash_attention_paged_time_follows_the_base(capsys):
+    """A chunk at base 128 looks at 2 pages, one at base 3,968 at 32: the
+    kernel's time has to follow that, and the composition's does not."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_paged
+
+    kernel = jax.jit(flash_attention_paged)
+    composition = jax.jit(_masked_composition)
+    ms = {}
+    for base in (0, 128, 1000, 3968):
+        ops = _prefill_over_cache_operands(1, [base], 7)
+        ms[base] = (_device_ms_a_call(kernel, *ops),
+                    _device_ms_a_call(composition, *ops))
+    with capsys.disabled():
+        for base, (k_ms, c_ms) in ms.items():
+            print(f"\nflash_fwd_paged width 1 base {base}: kernel "
+                  f"{k_ms:.4f} ms a call, masked composition {c_ms:.4f}")
+    assert ms[128][0] < 0.5 * ms[3968][0]
+    assert ms[128][0] < ms[128][1]
