@@ -37,6 +37,16 @@ from .moe_mla import (  # noqa: F401
 __all__ += ["MoEMLAConfig", "MoEMLAForCausalLM", "MoEMLAModel",
             "moe_mla_tiny_config"]
 
+from .power_retention import (  # noqa: F401
+    PowerRetentionConfig,
+    PowerRetentionForCausalLM,
+    PowerRetentionModel,
+    power_retention_tiny_config,
+)
+
+__all__ += ["PowerRetentionConfig", "PowerRetentionForCausalLM",
+            "PowerRetentionModel", "power_retention_tiny_config"]
+
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

@@ -13,6 +13,8 @@ eager loop (each op served from the cached-executable dispatch).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,18 +71,40 @@ def _make_static_cache(k, v, length):
     return c
 
 
-def kv_page_shapes(model):
-    """The trailing shapes of a layer's two cache buffers, after (pages,
-    page) or (batch, max_len): the model's own where it has a say
-    (``kv_page_shapes()``: a latent cache keeps no per-head keys), else
-    (kv heads, head size) twice, from its config."""
+def sequence_keeps(model):
+    """What one sequence of ``model`` keeps a layer, the ONE question every
+    cache is sized from. ``("pages", k_shape, v_shape)``: something a
+    token, as the trailing shapes of a layer's two cache buffers after
+    (pages, page) or (batch, max_len) -- the model's own where it has a say
+    (``kv_page_shapes()``: a latent cache keeps no per-head keys), else (kv
+    heads, head size) twice, from its config. ``("state", (s_shape,
+    s_dtype), (z_shape, z_dtype))``: one fixed-size state a sequence
+    whatever its length (``sequence_state()``: a recurrent layer), as the
+    shapes after the slot dimension."""
+    state = getattr(model, "sequence_state", None)
+    if state is not None:
+        (s_shape, s_dtype), (z_shape, z_dtype) = state()
+        return ("state", (tuple(s_shape), s_dtype), (tuple(z_shape), z_dtype))
     own = getattr(model, "kv_page_shapes", None)
     if own is not None:
         k_shape, v_shape = own()
-        return tuple(k_shape), tuple(v_shape)
+        return "pages", tuple(k_shape), tuple(v_shape)
     cfg = model.config
     kv = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
-    return (kv, cfg.head_dim), (kv, cfg.head_dim)
+    return "pages", (kv, cfg.head_dim), (kv, cfg.head_dim)
+
+
+def kv_page_shapes(model):
+    """The page case of :func:`sequence_keeps`, for the callers that build
+    a cache a token (``generate``, ``build_serve_fn``); a model that keeps
+    a state is served through ``ContinuousBatchingEngine``."""
+    kind, k_shape, v_shape = sequence_keeps(model)
+    if kind != "pages":
+        raise NotImplementedError(
+            f"{type(model).__name__} keeps a recurrent state a sequence, "
+            "not keys and values a token: generate() builds no such cache; "
+            "serve it through ContinuousBatchingEngine (ROADMAP M4)")
+    return k_shape, v_shape
 
 
 def _make_paged_cache(kp, vp, tables, page_size, length,
@@ -98,6 +122,167 @@ def _make_paged_cache(kp, vp, tables, page_size, length,
     c.live = live      # (B,) rows that hold a sequence, or None: all
     c.stats = None     # what a layer counted this step, if it counts
     return c
+
+
+class StateCache:
+    """What the serving engine hands a recurrent layer: the layer's whole
+    state arrays ``s`` / ``z`` (a row a slot, the last the scratch slot),
+    each batch row's slot (``rows``), how many tokens the row already holds
+    (``length``: a static 0, or (B,)), how many of the new ones are real
+    (``true_lens`` (B,), or None: all) and, in a decode step, which rows
+    hold a sequence (``live``). ``stats`` is what the layer counted."""
+
+    __slots__ = ("s", "z", "rows", "length", "true_lens", "live", "stats")
+
+    def __init__(self, s, z, rows, length=0, true_lens=None, live=None):
+        self.s, self.z, self.rows = s, z, rows
+        self.length = length
+        self.true_lens = true_lens
+        self.live = live
+        self.stats = None
+
+
+class PagedStore:
+    """The serving engine's two per-layer device arrays for a model that
+    keeps something a TOKEN: pools of pages, addressed through the engine's
+    page table. One of the two answers to :func:`sequence_store`; the
+    engine asks it for the arrays, for the caches a forward runs over, for
+    the arrays a forward leaves behind and for the logits it samples a
+    first token from, and never looks inside a cache itself."""
+
+    kind = "pages"
+    reset = None          # a granted page needs no zeroing
+    bytes_per_slot = 0
+
+    def __init__(self, k_shape, v_shape, dtype, n_layers, page_size,
+                 attn_pages, chunk_aligned):
+        self._shapes, self._dtype = (tuple(k_shape), tuple(v_shape)), dtype
+        self._nl, self._page = n_layers, page_size
+        self._attn_pages, self._aligned = attn_pages, chunk_aligned
+        self.bytes_per_token = n_layers * sum(
+            math.prod(sh) for sh in self._shapes) * np.dtype(dtype).itemsize
+
+    def pool_pages(self, asked, max_slots, per_seq):
+        """Allocatable pages: by default one full-length sequence a slot."""
+        n = max_slots * per_seq if asked is None else int(asked)
+        if n < per_seq:
+            raise ValueError(
+                f"pool_pages {n} cannot hold one full-length sequence "
+                f"({per_seq} pages of {self._page} tokens)")
+        return n
+
+    def allocate(self, n_pages, max_slots):
+        lead = (n_pages, self._page)
+        return tuple([jnp.zeros(lead + sh, self._dtype)
+                      for _ in range(self._nl)] for sh in self._shapes)
+
+    @staticmethod
+    def tables(max_slots, total_cols, dump_page, scratch_ids):
+        """The host page table: a row a slot, every cell on the dump page
+        until the allocator grants it, and the scratch row."""
+        tables = np.full((max_slots + 1, total_cols), dump_page, np.int32)
+        tables[max_slots] = scratch_ids
+        return tables
+
+    @staticmethod
+    def set_row(row, pages, dump_page):
+        """A slot's granted pages into its table row; the tail aliases the
+        dump page."""
+        row[:len(pages)] = pages
+        row[len(pages):] = dump_page
+
+    def caches(self, ks, vs, tables, length, aligned=None, live=None,
+               true_lens=None):
+        # chunked-prefill bases are chunk multiples: page-aligned (the
+        # bulk-write opt-in) exactly when the chunk is a page multiple;
+        # the prefix-RESUME path passes aligned=False -- its bases start
+        # at the first divergent token, which may sit mid-page.
+        # ``true_lens`` is not a page model's concern: it never reads what
+        # padding wrote
+        if aligned is None:
+            aligned = self._aligned
+        return [_make_paged_cache(ks[i], vs[i], tables, self._page, length,
+                                  aligned_bases=aligned,
+                                  attn_pages=self._attn_pages, live=live)
+                for i in range(self._nl)]
+
+    @staticmethod
+    def pools(caches):
+        return ([c.k_pages for c in caches], [c.v_pages for c in caches])
+
+    @staticmethod
+    def last_logits(logits, true_lens):
+        # each row's TRUE last position (padding rows are never read)
+        idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
+        return jnp.take_along_axis(
+            logits, jnp.broadcast_to(
+                idx, (logits.shape[0], 1, logits.shape[-1])), axis=1)[:, 0]
+
+
+class StateStore:
+    """:class:`PagedStore`'s counterpart for a model that keeps ONE
+    fixed-size state a sequence (``S`` / ``Z`` a layer): a row a slot and
+    a last row, the scratch slot, that an admission group's padding rows
+    write. A slot's page-table row names its state row and nothing else."""
+
+    kind = "state"
+    bytes_per_token = 0
+
+    def __init__(self, s_keep, z_keep, n_layers):
+        self._keeps, self._nl = (s_keep, z_keep), n_layers
+        self.bytes_per_slot = n_layers * sum(
+            math.prod(sh) * np.dtype(dt).itemsize for sh, dt in self._keeps)
+
+    @staticmethod
+    def pool_pages(asked, max_slots, per_seq):
+        return 0                 # a granted slot owns its state
+
+    def allocate(self, n_pages, max_slots):
+        return tuple([jnp.zeros((max_slots + 1,) + sh, dt)
+                      for _ in range(self._nl)] for sh, dt in self._keeps)
+
+    @staticmethod
+    def tables(max_slots, total_cols, dump_page, scratch_ids):
+        # a row names its slot's state row (the last: the scratch slot's)
+        # and nothing else, for good; ``lengths`` keeps its meaning for
+        # positions and budgets
+        return np.arange(max_slots + 1, dtype=np.int32)[:, None]
+
+    @staticmethod
+    def set_row(row, pages, dump_page):
+        pass
+
+    def caches(self, ks, vs, tables, length, aligned=None, live=None,
+               true_lens=None):
+        return [StateCache(ks[i], vs[i], tables[:, 0], length, true_lens,
+                           live) for i in range(self._nl)]
+
+    @staticmethod
+    def pools(caches):
+        return [c.s for c in caches], [c.z for c in caches]
+
+    @staticmethod
+    def last_logits(logits, true_lens):
+        # the model was told the true lengths and took its head at that
+        # position alone: (N, 1, V)
+        return logits[:, 0]
+
+    @staticmethod
+    def reset(ks, vs, rows):
+        # a granted slot starts from the zero state (padding lanes zero
+        # the scratch slot)
+        return ([k.at[rows].set(0) for k in ks],
+                [v.at[rows].set(0) for v in vs])
+
+
+def sequence_store(model, dtype, page_size, attn_pages, chunk_aligned):
+    """The store for what :func:`sequence_keeps` says ``model`` keeps."""
+    kind, k_keep, v_keep = sequence_keeps(model)
+    n_layers = model.config.num_hidden_layers
+    if kind == "state":
+        return StateStore(k_keep, v_keep, n_layers)
+    return PagedStore(k_keep, v_keep, dtype, n_layers, page_size,
+                      attn_pages, chunk_aligned)
 
 
 def _generate_jit(model, ids, max_new_tokens, do_sample, temperature,

@@ -56,7 +56,6 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import math
 import time
 import uuid
 import zlib
@@ -76,7 +75,7 @@ from ..core.resilience import (
 )
 from ..core.tensor import Tensor
 from ..profiler import annotate, record_span
-from .generation import _make_paged_cache, _sample_rows, kv_page_shapes
+from .generation import _sample_rows, sequence_store
 from .kv_pool import PagePool, PrefixCache
 
 __all__ = ["ContinuousBatchingEngine", "Request", "TERMINAL_STATES"]
@@ -136,6 +135,14 @@ _M_PREFILL_TABLE = telemetry.counter(
     "visible table columns x page size per such dispatch: what a table-"
     "wide masked composition scores; live over table is the share of it "
     "that held anything to attend")
+_M_STATE_TOKENS = telemetry.counter(
+    "serving.state_prefill_tokens_total", "real prompt tokens a state "
+    "model's prefill dispatches (prefill, chunk, final chunk) fed through "
+    "the recurrence: the host's own true lengths")
+_M_STATE_PADDED = telemetry.counter(
+    "serving.state_prefill_padded_total", "positions of those dispatches "
+    "that were masked out of the state update (bucket padding past a row's "
+    "true length, padding rows of an admission group)")
 # KV-occupancy accounting (perfwatch): the measurement side of the
 # paged-KV roadmap item — logical occupancy of the preallocated page
 # pool, not PJRT allocator bytes (the pool is allocated up front; the
@@ -232,7 +239,7 @@ class Request:
                  "status", "poisoned", "poison_checked", "error",
                  "token_base", "trace", "t_submit", "t_first", "tenant",
                  "preempted", "hold_kv", "kv_import", "t_queued",
-                 "t_admit")
+                 "t_admit", "slot")
 
     def __init__(self, rid, prompt, max_new_tokens, deadline=None,
                  token_base=0, trace=None, tenant=None, hold_kv=False,
@@ -252,6 +259,7 @@ class Request:
         self.t_submit = self.t_queued = time.monotonic()
         self.t_admit = None
         self.t_first = None
+        self.slot = None          # the slot it holds, or held last
         # set when the engine pulled this request off its slot to free
         # pages (pool exhaustion): re-admission then requires coverage
         # to the request's FULL budget so it cannot thrash in and out
@@ -355,15 +363,23 @@ class ContinuousBatchingEngine:
         self.eos_token_id = eos_token_id
         self.prompt_buckets = tuple(sorted(prompt_buckets))
         self._pipeline = bool(pipeline)
-        # what a token keeps a layer: the model's own page shapes where
-        # it has a say (a latent cache), else (kv heads, head size) twice
-        k_shape, v_shape = kv_page_shapes(model)
         try:
             dtype = next(iter(model.parameters()))._value.dtype
         except StopIteration:
             dtype = jnp.float32
         per_seq = self.max_len // self.page_size
         self._cols = per_seq  # attention-visible table columns
+        # what a sequence keeps a layer (``generation.sequence_store``):
+        # pages a token -- the model's own page shapes where it has a say
+        # (a latent cache), else (kv heads, head size) twice -- or ONE
+        # fixed-size state a slot (a recurrent layer). The store owns the
+        # two per-layer device arrays' format; ``_state`` is left for what
+        # the page allocator has nothing to do for (a granted slot owns
+        # its state: no pool, no prefix cache, no page to move)
+        self._keep = sequence_store(
+            model, dtype, self.page_size, per_seq,
+            self.prompt_buckets[-1] % self.page_size == 0)
+        self._state = self._keep.kind == "state"
         # DYNAMIC POOL: ``pool_pages`` allocatable pages shared by every
         # slot (default: the historical budget of one full-length
         # sequence per slot, so the device arrays are byte-identical to
@@ -375,13 +391,7 @@ class ContinuousBatchingEngine:
         # scratch holds chunk_w/page pages.
         chunk_w = self.prompt_buckets[-1]
         scratch_np = max(chunk_w // self.page_size, 1)
-        n_real = (self.max_slots * per_seq if pool_pages is None
-                  else int(pool_pages))
-        if n_real < per_seq:
-            raise ValueError(
-                f"pool_pages {n_real} cannot hold one full-length "
-                f"sequence ({per_seq} pages of {self.page_size} tokens "
-                f"for max_len {self.max_len})")
+        n_real = self._keep.pool_pages(pool_pages, self.max_slots, per_seq)
         self._pool_pages = n_real
         n_pages = n_real + scratch_np
         # table rows carry EXTRA trailing scratch-aliased columns: a
@@ -392,10 +402,10 @@ class ContinuousBatchingEngine:
         self._extra_cols = -(-chunk_w // self.page_size)
         total_cols = per_seq + self._extra_cols
         self._nl = cfg.num_hidden_layers
-        self._ks = [jnp.zeros((n_pages, self.page_size) + k_shape, dtype)
-                    for _ in range(self._nl)]
-        self._vs = [jnp.zeros((n_pages, self.page_size) + v_shape, dtype)
-                    for _ in range(self._nl)]
+        # the two per-layer device arrays (page pools, or a state row a
+        # slot and the scratch slot's): donated and updated in place
+        # through the same programs either way
+        self._ks, self._vs = self._keep.allocate(n_pages, self.max_slots)
         # any table cell not backed by a granted page aliases the DUMP
         # page (the last scratch page): writes there are garbage by
         # construction and reads never reach it (attention masks by
@@ -408,9 +418,15 @@ class ContinuousBatchingEngine:
         # Kept NUMPY-side for prefill row gathers — the post-warmup hot
         # path must not trigger a single compilation; the device copy
         # (_tables_device) is re-uploaded on grant, never re-traced.
-        self._tables_np = np.full((self.max_slots + 1, total_cols),
-                                  self._dump_page, np.int32)
-        self._tables_np[self.max_slots] = scratch_ids
+        self._tables_np = self._keep.tables(
+            self.max_slots, total_cols, self._dump_page, scratch_ids)
+        if self._state:
+            if prefix_cache:
+                logger.info(
+                    "ContinuousBatchingEngine: %s keeps a state a slot; the "
+                    "prefix cache is off (a snapshot a boundary: ROADMAP M4)",
+                    type(model).__name__)
+            prefix_cache = False
         # per-segment invariants hoisted out of the dispatch loop: the
         # device table/limits copies change only at grant/admission and
         # are invalidated there
@@ -444,10 +460,10 @@ class ContinuousBatchingEngine:
         self._zeros_cache: dict[tuple, jnp.ndarray] = {}
         self._aot: dict[tuple, object] = {}
         # KV accounting invariants (perfwatch): bytes one token's K+V
-        # rows cost across all layers, at the cache dtype
-        self._kv_bytes_per_token = int(
-            self._nl * (math.prod(k_shape) + math.prod(v_shape))
-            * np.dtype(dtype).itemsize)
+        # rows cost across all layers, at the cache dtype (0 for a state),
+        # and bytes a slot's state holds (0 for pages)
+        self._kv_bytes_per_token = int(self._keep.bytes_per_token)
+        self._state_bytes_per_slot = int(self._keep.bytes_per_slot)
         # a model that counts (sparse experts: expert load) names what
         # its layers' per-step statistics are; the decode segment carries
         # their sum out and ``_consume`` feeds ``serving.<name>_total``
@@ -507,10 +523,8 @@ class ContinuousBatchingEngine:
         """Mirror the slot's granted pages into its host table row (tail
         columns alias the dump page) and invalidate the device copy —
         contents change, the traced shape never does."""
-        row = self._tables_np[slot]
-        pages = self._slot_pages[slot]
-        row[:len(pages)] = pages
-        row[len(pages):] = self._dump_page
+        self._keep.set_row(self._tables_np[slot], self._slot_pages[slot],
+                           self._dump_page)
         self._tables_active = None
 
     def _tables_device(self):
@@ -531,18 +545,6 @@ class ContinuousBatchingEngine:
             self._set_table_row(slot)
 
     # ------------------------------------------------------------ programs
-
-    def _caches(self, ks, vs, tables, length, aligned=None, live=None):
-        # chunked-prefill bases are chunk_w multiples: page-aligned (the
-        # bulk-write opt-in) exactly when chunk_w is a page multiple;
-        # the prefix-RESUME path passes aligned=False — its bases start
-        # at the first divergent token, which may sit mid-page
-        if aligned is None:
-            aligned = self.prompt_buckets[-1] % self.page_size == 0
-        return [_make_paged_cache(ks[i], vs[i], tables, self.page_size,
-                                  length, aligned_bases=aligned,
-                                  attn_pages=self._cols, live=live)
-                for i in range(self._nl)]
 
     def _build_programs(self):
         functional = self._functional
@@ -566,30 +568,34 @@ class ContinuousBatchingEngine:
                 return _sample_rows(last, keys, temperature, top_k, top_p,
                                     greedy).astype(jnp.int32)
 
+        # the store builds the caches a forward runs over and names the
+        # two per-layer arrays it leaves behind
+        make_caches, pools = self._keep.caches, self._keep.pools
+
         def sample_true_last(logits, true_lens, keys):
             # first token from each row's TRUE last position (padding
             # rows are never read — causal)
-            idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
-            last = jnp.take_along_axis(
-                logits, jnp.broadcast_to(
-                    idx, (logits.shape[0], 1, logits.shape[-1])),
-                axis=1)[:, 0]
-            return sample_batch(last, keys)
+            return sample_batch(self._keep.last_logits(logits, true_lens),
+                                keys)
 
-        def write_prompts(params, ks, vs, prompts, table_rows, base):
+        def write_prompts(params, ks, vs, prompts, table_rows, base,
+                          true_lens=None):
             # run the model over (N, L) prompt rows writing each row's
             # slot pages at ``base`` (0 = fresh slots, (N,) array =
-            # chunked-prefill offsets); returns (logits, pools)
-            caches = self._caches(ks, vs, table_rows, base)
+            # chunked-prefill offsets); returns (logits, pools). A state
+            # model continues each row's state instead, and is told how
+            # many of the row's tokens are real: padding must not enter a
+            # recurrence (a page model never reads what padding wrote)
+            caches = make_caches(ks, vs, table_rows, base,
+                                 true_lens=true_lens)
             (logits, caches2), _ = run_model(params, prompts, caches)
-            return (logits, [c.k_pages for c in caches2],
-                    [c.v_pages for c in caches2])
+            return (logits, *pools(caches2))
 
         def prefill(params, ks, vs, prompts, table_rows, true_lens, keys):
             # N same-bucket admissions in ONE dispatch (static zero base:
             # the fast causal prefill path)
             logits, ks2, vs2 = write_prompts(
-                params, ks, vs, prompts, table_rows, 0)
+                params, ks, vs, prompts, table_rows, 0, true_lens)
             return sample_true_last(logits, true_lens, keys), ks2, vs2
 
         def chunk_step(params, ks, vs, chunk, table_rows, bases):
@@ -604,7 +610,7 @@ class ContinuousBatchingEngine:
                         keys):
             # last (padded) chunk of a long prompt: write + sample
             logits, ks2, vs2 = write_prompts(
-                params, ks, vs, chunk, table_rows, bases)
+                params, ks, vs, chunk, table_rows, bases, true_lens)
             return sample_true_last(logits, true_lens, keys), ks2, vs2
 
         def resume_final(params, ks, vs, chunk, table_rows, bases,
@@ -613,12 +619,10 @@ class ContinuousBatchingEngine:
             # head was served from the prefix cache — written at per-row
             # bases that may sit MID-PAGE (unaligned scatter path; the
             # CoW page copy ran first), sampling at the true last token
-            caches = self._caches(ks, vs, table_rows, bases,
-                                  aligned=False)
+            caches = make_caches(ks, vs, table_rows, bases, aligned=False)
             (logits, caches2), _ = run_model(params, chunk, caches)
-            ks2 = [c.k_pages for c in caches2]
-            vs2 = [c.v_pages for c in caches2]
-            return sample_true_last(logits, true_lens, keys), ks2, vs2
+            return (sample_true_last(logits, true_lens, keys),
+                    *pools(caches2))
 
         def cow_copy(params, ks, vs, src, dst):
             # copy-on-write page copy: duplicate shared pages a writer
@@ -648,11 +652,17 @@ class ContinuousBatchingEngine:
             vs2 = [v.at[idx].set(payv[i]) for i, v in enumerate(vs)]
             return ks2, vs2
 
+        reset = self._keep.reset
+
+        def reset_state(params, ks, vs, rows):
+            # what the store zeroes when slots are granted (a state)
+            return reset(ks, vs, rows)
+
         def segment(params, ks, vs, tables, lengths, toks, active, limits,
                     keys):
             def body(carry, key):
                 tok, ks, vs, lengths, active = carry
-                caches = self._caches(ks, vs, tables, lengths, live=active)
+                caches = make_caches(ks, vs, tables, lengths, live=active)
                 (logits, caches2), _ = run_model(params, tok[:, None],
                                                  caches)
                 # a counting model's layers leave their step statistics
@@ -669,8 +679,7 @@ class ContinuousBatchingEngine:
                 new_active = active & (new_lengths < limits)
                 if eos is not None:
                     new_active = new_active & (nxt != eos)
-                ks2 = [c.k_pages for c in caches2]
-                vs2 = [c.v_pages for c in caches2]
+                ks2, vs2 = pools(caches2)
                 return ((nxt, ks2, vs2, new_lengths, new_active),
                         (nxt, active, sum(counted) if counted else None))
 
@@ -689,6 +698,8 @@ class ContinuousBatchingEngine:
         self._export_p = jax.jit(export_pages, donate_argnums=(1, 2))
         self._import_p = jax.jit(import_pages, donate_argnums=(1, 2))
         self._segment_p = jax.jit(segment, donate_argnums=(1, 2))
+        self._reset_p = (jax.jit(reset_state, donate_argnums=(1, 2))
+                         if reset is not None else None)
 
     # --------------------------------------------------- program dispatch
 
@@ -872,13 +883,20 @@ class ContinuousBatchingEngine:
         # KV page transfer (prefill/decode disaggregation): the fixed-
         # width export/import chunk programs, warmed so page payloads
         # move between replicas without a single post-warmup trace
-        xfer_idx_s = self._op_aval((_XFER_WIDTH,), i32)
-        payk_s, payv_s = (self._op_aval(
-            (len(pool), _XFER_WIDTH) + tuple(pool[0].shape[1:]),
-            pool[0].dtype) for pool in (self._ks, self._vs))
-        compile_(("export", _XFER_WIDTH), self._export_p, xfer_idx_s)
-        compile_(("import", _XFER_WIDTH), self._import_p, xfer_idx_s,
-                 payk_s, payv_s)
+        if self._state:
+            # no page moves between replicas; the one extra program zeroes
+            # the slots an admission group was granted
+            for g in self.group_widths():
+                compile_(("reset", g), self._reset_p,
+                         self._op_aval((g,), i32))
+        else:
+            xfer_idx_s = self._op_aval((_XFER_WIDTH,), i32)
+            payk_s, payv_s = (self._op_aval(
+                (len(pool), _XFER_WIDTH) + tuple(pool[0].shape[1:]),
+                pool[0].dtype) for pool in (self._ks, self._vs))
+            compile_(("export", _XFER_WIDTH), self._export_p, xfer_idx_s)
+            compile_(("import", _XFER_WIDTH), self._import_p, xfer_idx_s,
+                     payk_s, payv_s)
         seg = int(segment if segment is not None
                   else getattr(self, "_segment_len", 16))
         m = self.max_slots
@@ -1028,7 +1046,8 @@ class ContinuousBatchingEngine:
         self._quarantine = []
         self._disp_n = 0
         self._exec_floor = 0
-        self._tables_np[:self.max_slots] = self._dump_page
+        for slot in range(self.max_slots):
+            self._keep.set_row(self._tables_np[slot], (), self._dump_page)
         self._tables_active = None
         self._slot_adm = [0] * self.max_slots  # admission seq per slot
         self._adm_seq = 0
@@ -1097,6 +1116,8 @@ class ContinuousBatchingEngine:
         ``export_pages``/``import_kv_chunk``."""
         prompt = np.asarray(prompt).astype(np.int32).ravel()
         self._validate(prompt, max_new_tokens)
+        if hold_kv or kv_import is not None:
+            self._refuse_state("a prefill / decode handoff")
         if rid is None:
             rid = self._auto_rid
             self._auto_rid += 1
@@ -1150,6 +1171,15 @@ class ContinuousBatchingEngine:
                 return req
         return None
 
+    def _refuse_state(self, what):
+        """A state model has no pages to pin, export or land."""
+        if self._state:
+            raise NotImplementedError(
+                f"{type(self.model).__name__} keeps a recurrent state a "
+                f"slot, not pages: {what} needs a snapshot of the state to "
+                "move (models/transfer.py export_pages / import_pages), "
+                "which is not built yet: ROADMAP M4")
+
     # ----------------------------------------------- failure isolation
 
     def _retire(self, req, status, finished=None, slot=None):
@@ -1187,7 +1217,8 @@ class ContinuousBatchingEngine:
                 pages = (pages_held if pages_held else
                          -(-(req.prompt.size + len(req.tokens))
                            // self.page_size))
-                _M_KV_REQ.observe(pages * self.page_size
+                _M_KV_REQ.observe(self._state_bytes_per_slot
+                                  or pages * self.page_size
                                   * self._kv_bytes_per_token)
             if req.t_first is not None and len(req.tokens) > 1:
                 per_tok = ((time.monotonic() - req.t_first)
@@ -1307,6 +1338,7 @@ class ContinuousBatchingEngine:
         already counts those emissions, so the key stream, budget, and
         limit arithmetic stay globally indexed."""
         self._slot_req[slot] = req
+        req.slot = int(slot)
         fresh_first = not req.tokens
         req.tokens.append(int(tok))
         self._useful += 1  # the prefill-sampled token
@@ -1371,6 +1403,7 @@ class ContinuousBatchingEngine:
         self._slot_adm[slot] = self._adm_seq
         self._adm_seq += 1
         self._slot_req[slot] = req
+        req.slot = int(slot)
         req.tokens.append(first)
         if req.t_first is None:
             req.t_first = time.monotonic()
@@ -1405,9 +1438,12 @@ class ContinuousBatchingEngine:
                 padded[i, :req.prompt.size] = req.prompt
                 true_lens[i] = req.prompt.size
                 rows[i] = slot
+        self._reset_state(group)
         with annotate("serving.prefill", phase="prefill") as sp:
             d = self._mark_dispatch()
             sp.set(**self._group_trace_args(group))
+            self._count_state_prefill(sp, true_lens[:len(group)].sum(),
+                                      g * bucket)
             with annotate("serving.prefill_dispatch"):
                 tok0, self._ks, self._vs = self._call(
                     ("prefill", bucket, g), self._prefill_p,
@@ -1424,10 +1460,38 @@ class ContinuousBatchingEngine:
             for i, (slot, req) in enumerate(group):
                 self._finish_admit(slot, req, tok0[i], finished)
 
+    def _reset_state(self, group):
+        """Zero the state of the slots an admission group was granted (a
+        state model; nothing for a page model). Inside the group's
+        isolation scope and ahead of its prefill in device order, so a
+        bisection replay starts its rows from the zero state again."""
+        if self._reset_p is None:
+            return
+        g = self._group_width(len(group))
+        rows = np.full((g,), self.max_slots, np.int32)   # padding: scratch
+        rows[:len(group)] = [slot for slot, _ in group]
+        with annotate("serving.state_reset", slots=len(group)):
+            self._mark_dispatch()
+            self._ks, self._vs = self._call(
+                ("reset", g), self._reset_p, self._params,
+                self._ks, self._vs, jnp.asarray(rows))
+
+    def _count_state_prefill(self, sp, real, positions):
+        """A state model's two prefill counters for one dispatch: the real
+        tokens fed through the recurrence and the positions masked out.
+        They ride on the dispatch's span too, so a reader can sum them over
+        any interval."""
+        if self._state and telemetry.enabled():
+            real, padded = int(real), int(positions) - int(real)
+            _M_STATE_TOKENS.inc(real)
+            _M_STATE_PADDED.inc(padded)
+            sp.set(state_tokens=real, state_padded=padded)
+
     def _count_prefill_cols(self, bases, width):
         """The two ``serving.prefill_attn_cols_*`` counters for one
-        dispatch of ``width`` new tokens a row at its real rows' ``bases``."""
-        if telemetry.enabled():
+        dispatch of ``width`` new tokens a row at its real rows' ``bases``
+        (a state model attends to no column)."""
+        if telemetry.enabled() and not self._state:
             _M_PREFILL_LIVE.inc(int(np.sum(bases + width)))
             _M_PREFILL_TABLE.inc(len(bases) * self._cols * self.page_size)
 
@@ -1516,6 +1580,7 @@ class ContinuousBatchingEngine:
         # ``timed_out`` without dispatching its remaining chunks.
         chunk_w = self.prompt_buckets[-1]
         scratch = self.max_slots
+        self._reset_state(group)
         start = {id(req): self._resume_base.get(id(req), 0)
                  for _, req in group}
         n_full = {id(req): (req.prompt.size - start[id(req)] - 1) // chunk_w
@@ -1552,6 +1617,8 @@ class ContinuousBatchingEngine:
                         jnp.asarray(self._tables_np[rows]),
                         jnp.asarray(bases))
                 self._count_prefill_cols(bases[rows != scratch], chunk_w)
+                self._count_state_prefill(
+                    sp, np.sum(rows != scratch) * chunk_w, g * chunk_w)
             c += 1
         if live:
             with annotate("serving.prefill_prep"):
@@ -1581,6 +1648,8 @@ class ContinuousBatchingEngine:
                         jnp.asarray(bases), jnp.asarray(true_rem),
                         self._prefill_keys(live, g))
                 self._count_prefill_cols(bases[:len(live)], chunk_w)
+                self._count_state_prefill(sp, true_rem[:len(live)].sum(),
+                                          g * chunk_w)
                 with annotate("serving.first_token_fetch"):
                     tok0 = np.asarray(tok0)  # blocking fetch
                 self._mark_executed(d)
@@ -1626,7 +1695,7 @@ class ContinuousBatchingEngine:
                         self._tables_device(),
                         lengths, toks, active, self._limits_device(), keys)
             self._seg_runs += 1
-            if telemetry.enabled():
+            if telemetry.enabled() and not self._state:
                 _M_ATTN_LIVE.inc(int(
                     (-(-self._lengths // self.page_size)).sum()))
                 _M_ATTN_TABLE.inc(self.max_slots * self._cols)
@@ -1729,10 +1798,54 @@ class ContinuousBatchingEngine:
         with annotate("serving.drain", cause=cause):
             try:
                 self._consume(h, finished)
-            except Exception:  # isolation boundary: replay serially + bisect
-                live = np.array([r is not None for r in self._slot_req])
-                self._stall_cause = "replay"
-                self._segment_round(h["mask"] & live, finished)
+            except Exception as e:  # isolation boundary: replay + bisect
+                self._replay_window(h["mask"], finished, e)
+
+    def _replay_window(self, mask, finished, error):
+        """A dispatched segment failed at its fetch: take its window again
+        from the last synced host state. Pages are rewritten identically
+        by a replay, so a page model decodes the window serially, bisecting
+        to isolate. A STATE may already hold the window's tokens (the
+        segment, and a speculative one built on it, update it in place),
+        and a recurrence applied twice is silently wrong: its rows go back
+        through prefill instead (:meth:`_readmit_window`)."""
+        live = np.array([r is not None for r in self._slot_req])
+        self._stall_cause = "replay"
+        if self._state:
+            self._readmit_window(mask & live, finished, error)
+        else:
+            self._segment_round(mask & live, finished)
+
+    def _readmit_window(self, mask, finished, error):
+        """The rows of a failed window, for a state model: each request
+        goes back to the FRONT of the queue with its emitted tokens folded
+        into its prompt (the preemption shape: the slot's grant zeroes the
+        state and prefill rebuilds it, so the continuation is the
+        uninterrupted run's). A request that was already taken back once
+        retires as ``"failed"``: bisection cannot single out an offender
+        here, so nothing is retried for ever."""
+        taken = []
+        for slot in np.flatnonzero(mask):
+            req = self._slot_req[slot]
+            if req is None:
+                continue
+            if req.preempted:
+                bump_counter("serving.poison_request")
+                req.error = error
+                telemetry.flight_dump("poison_request", rid=req.rid,
+                                      error=repr(error))
+                self._retire(req, "failed", finished, slot=int(slot))
+                continue
+            bump_counter("serving.state_readmitted")
+            if req.tokens:
+                req.prompt = np.concatenate(
+                    [req.prompt, np.asarray(req.tokens, np.int32)])
+            req.preempted = True
+            req.t_queued = time.monotonic()
+            self._slot_req[slot] = None
+            self._lengths[slot] = 1
+            taken.append(req)
+        self._queue.extendleft(reversed(taken))
 
     def _segment_round(self, mask, finished):
         """One compiled decode segment over the slots in ``mask`` + host
@@ -1745,10 +1858,16 @@ class ContinuousBatchingEngine:
         run."""
         if not mask.any():
             return
+        h = None
         try:
             h = self._dispatch_segment(mask)
             self._consume(h, finished)
         except Exception as e:  # isolation boundary: bisect, never crash
+            if h is not None and self._state:
+                # the segment was dispatched and may have run: a state
+                # cannot take its tokens a second time
+                self._readmit_window(mask, finished, e)
+                return
             idx = np.flatnonzero(mask)
             if len(idx) == 1:
                 slot = int(idx[0])
@@ -1815,15 +1934,13 @@ class ContinuousBatchingEngine:
         self._inflight = h
         try:
             self._consume(prev, finished)
-        except Exception:  # isolation boundary: bisect, never crash
+        except Exception as e:  # isolation boundary: bisect, never crash
             # prev's ASYNC execution failed (surfaced at the fetch, not
             # the dispatch): the speculative segment was built on its
             # outputs — discard it and replay prev's window serially from
             # the last synced host state, bisecting to isolate
             self._inflight = None
-            live = np.array([r is not None for r in self._slot_req])
-            self._stall_cause = "replay"
-            self._segment_round(prev["mask"] & live, finished)
+            self._replay_window(prev["mask"], finished, e)
             return
 
     def step(self):
@@ -2046,6 +2163,8 @@ class ContinuousBatchingEngine:
         first-window decode growth: the caller defers the queue head.
         A previously PREEMPTED request requires coverage of its FULL
         remaining budget, so it cannot thrash straight back out."""
+        if self._state:
+            return [], 0, None, []   # a granted slot owns its state
         P = int(req.prompt.size)
         page = self.page_size
         chunk_w = self.prompt_buckets[-1]
@@ -2102,6 +2221,8 @@ class ContinuousBatchingEngine:
         to the queue — its stream resumes bit-identically via the
         per-request key stream, and the prefix cache usually makes the
         re-prefill one page of work. A running decode never fails."""
+        if self._state:
+            return                   # nothing grows with a sequence
         horizon = self._growth_horizon()
         while True:
             need = []
@@ -2174,6 +2295,18 @@ class ContinuousBatchingEngine:
             telemetry.trace_event("serving.kv_preempt", trace=req.trace,
                                   rid=req.rid, emitted=len(req.tokens))
 
+    def read_state(self, slot):
+        """A state model: what ``slot``'s state rows hold now, a layer a
+        pair of host arrays in the model's own layout (a retired request's
+        stay until its slot is granted again; ``Request.slot`` names it).
+        For tests and for a benchmark's comparison: it waits for whatever
+        is in flight on the device."""
+        if not self._state:
+            raise ValueError(
+                f"{type(self.model).__name__} keeps pages, not a state")
+        return [(np.asarray(s[slot]), np.asarray(z[slot]))
+                for s, z in zip(self._ks, self._vs)]
+
     # -------------------------- KV page transfer (disaggregation handoff)
     #
     # Engine-side primitive surface for prefill/decode disaggregation:
@@ -2206,6 +2339,7 @@ class ContinuousBatchingEngine:
         exactly-once. Returns the ticket dict, or None when the rid
         holds no exportable pages (never prefilled here, already
         released, or a respawned engine)."""
+        self._refuse_state("export_pages")
         tid = self._export_by_rid.get(rid)
         if tid is not None and tid in self._exports:
             return dict(self._exports[tid]["ticket"])
@@ -2278,6 +2412,7 @@ class ContinuousBatchingEngine:
         partial landing, ``"dup"`` for an already-landed index,
         ``"crc_mismatch"`` for a corrupt frame (caller re-sends), or
         ``"no_capacity"`` when the pool cannot grant the pages."""
+        self._refuse_state("import_pages (import_kv_chunk)")
         try:
             inject("transfer.import_fail")
         except InjectedFault:
@@ -2361,12 +2496,17 @@ class ContinuousBatchingEngine:
         lookups = getattr(self, "_prefix_lookup_tokens", 0)
         hits = getattr(self, "_prefix_hit_tokens", 0)
         return {
+            # pages at what a token costs, or (a state model: no page, 0
+            # of 0 below) live slots at what a slot's state holds
             "bytes_in_use": (phys * self.page_size
-                             * self._kv_bytes_per_token),
+                             * self._kv_bytes_per_token
+                             + n * self._state_bytes_per_slot),
             "slot_occupancy": n / self.max_slots if self.max_slots else 0.0,
             "fragmentation_pct": (100.0 * (1.0 - used / cap_tokens)
                                   if cap_tokens else 0.0),
             "bytes_per_token": self._kv_bytes_per_token,
+            "state_bytes_per_slot": self._state_bytes_per_slot,
+            "slots_live": n,
             "pages_total": self._pool_pages,
             "pages_free": free,
             "pages_granted": phys,

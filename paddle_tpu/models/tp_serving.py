@@ -68,6 +68,7 @@ from ..core.resilience import (
 from ..distributed.api import to_named_sharding
 from ..distributed.placement import Replicate, Shard
 from ..distributed.process_mesh import ProcessMesh
+from .generation import sequence_keeps
 from .serving import ContinuousBatchingEngine
 
 __all__ = ["TPShardedEngine", "TPGroupMembership", "plan_tp_shardings",
@@ -168,6 +169,14 @@ class TPShardedEngine(ContinuousBatchingEngine):
 
     def __init__(self, model, max_slots, max_len, mesh=None, tp_axis="mp",
                  plan=None, **kwargs):
+        if sequence_keeps(model)[0] == "state":
+            # the state's kv heads would shard cleanly, but nothing here
+            # places a state or partitions its kernels yet
+            raise NotImplementedError(
+                f"TPShardedEngine cannot serve {type(model).__name__}: it "
+                "keeps a recurrent state a slot, and this engine shards "
+                "page pools; serve it with ContinuousBatchingEngine on one "
+                "chip (ROADMAP M4)")
         if hasattr(model, "kv_page_shapes"):
             # this engine shards the page pools over kv heads and plans
             # placements for a dense block's projections; a latent cache

@@ -91,6 +91,39 @@ def test_paged_mla_attention_compiles_at_the_reason_cell_shapes(topo):
              ((32, 33), jnp.int32), ((32,), jnp.int32))
 
 
+@pytest.mark.parametrize("kernel", ["decode", "chunk_g4", "chunk_g32"])
+def test_power_retention_kernels_compile_at_the_continue_cell_shapes(
+        topo, kernel):
+    """brumby14b-continue-open: 32 slots + the scratch slot, 40 query heads
+    over 8 kv heads of 128, the float32 state in its 65 x 128 x 128 layout
+    a head (a 4.26 MB block in and out: the raised VMEM limit), updated in
+    place."""
+    from paddle_tpu.ops.pallas import retention as R
+
+    s_shape, z_shape = R.state_shapes(8, 128)
+    state = (((33,) + s_shape, jnp.float32), ((33,) + z_shape, jnp.float32))
+    if kernel == "decode":
+        fn, shapes = R.power_retention_decode, (
+            ((32, 40, 128), BF16), ((32, 8, 128), BF16),
+            ((32, 8, 128), BF16), ((32, 8), jnp.float32), *state,
+            ((32,), jnp.int32), ((32,), jnp.bool_))
+    else:
+        g = int(kernel.split("_g")[1])
+        fn, shapes = R.power_retention_chunk, (
+            ((g, 128, 40, 128), BF16), ((g, 128, 8, 128), BF16),
+            ((g, 128, 8, 128), BF16), ((g, 128, 8), jnp.float32), *state,
+            ((g,), jnp.int32))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in shapes]
+    # donated as the engine donates it: the state is aliased through the
+    # kernel, not copied (1.13 GB of it a layer)
+    compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(*avals).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes > 1.1e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
 @pytest.mark.parametrize("m,k,n", [
     (256, 2048, 1536), (256, 768, 2048),        # a decode step: 32 x top-8
     (32768, 2048, 1536), (32768, 768, 2048),    # a 32 x 128 prefill

@@ -548,3 +548,80 @@ def test_flash_attention_paged_time_follows_the_base(capsys):
                   f"{k_ms:.4f} ms a call, masked composition {c_ms:.4f}")
     assert ms[128][0] < 0.5 * ms[3968][0]
     assert ms[128][0] < ms[128][1]
+
+
+def _retention_operands(b, n, slots, seed):
+    """The continue cell's layer: 40 query heads over 8 kv heads of 128,
+    bf16 activations, the float32 state of ``slots`` slots made by a first
+    chunk so that it is one a sequence could hold."""
+    from paddle_tpu.ops.pallas import retention as R
+
+    r = np.random.RandomState(seed)
+    H, KV, D = 40, 8, 128
+    bf = jnp.bfloat16
+    q = jnp.asarray(r.randn(b, n, H, D).astype(np.float32), bf)
+    k = jnp.asarray(r.randn(b, n, KV, D).astype(np.float32) / 11.3, bf)
+    v = jnp.asarray(r.randn(b, n, KV, D).astype(np.float32), bf)
+    logg = jax.nn.log_sigmoid(jnp.asarray(
+        4.0 + 4.0 * r.rand(b, n, KV).astype(np.float32)))
+    s_shape, z_shape = R.state_shapes(KV, D)
+    rows = jnp.asarray(r.permutation(slots)[:b], jnp.int32)
+    return q, k, v, logg, jnp.zeros((slots,) + s_shape), \
+        jnp.zeros((slots,) + z_shape), rows
+
+
+def test_power_retention_kernels_at_the_continue_cell_widths(capsys):
+    """A chunk of 128 from the zero state, a second on the state it left,
+    then a decode step over a work list with a dead row: each kernel
+    against its jnp form in float32, the dead row's state untouched, and
+    the decode kernel's time a call a live row printed beside its bytes'
+    floor (2 x 34.08 MB at 819 GB/s = 83 us)."""
+    from paddle_tpu.ops.pallas import retention as R
+
+    q, k, v, logg, s, z, rows = _retention_operands(3, 256, 5, 11)
+    f32 = jnp.float32
+    chunk = jax.jit(R.power_retention_chunk)
+    oracle = jax.jit(R.retention_chunk_reference)
+    for sl in (slice(0, 128), slice(128, 256)):
+        args = (q[:, sl], k[:, sl], v[:, sl], logg[:, sl])
+        y, s1, z1 = chunk(*args, s, z, rows)
+        with jax.default_matmul_precision("highest"):
+            want, s0, z0 = oracle(*(a.astype(f32) for a in args), s, z, rows)
+        np.testing.assert_allclose(np.asarray(y, np.float32),
+                                   np.asarray(want), rtol=BF16_RTOL,
+                                   atol=BF16_ATOL)
+        np.testing.assert_allclose(np.asarray(s1), np.asarray(s0),
+                                   rtol=BF16_RTOL, atol=BF16_ATOL)
+        np.testing.assert_allclose(np.asarray(z1), np.asarray(z0),
+                                   rtol=BF16_RTOL, atol=BF16_ATOL)
+        s, z = s0, z0
+    live = jnp.asarray([True, False, True])
+    args = (q[:, 0], k[:, 1], v[:, 2], logg[:, 3])
+    decode = jax.jit(R.power_retention_decode)
+    y, s1, z1 = decode(*args, s, z, rows, live)
+    with jax.default_matmul_precision("highest"):
+        want, s0, z0 = jax.jit(R.retention_decode_reference)(
+            *(a.astype(f32) for a in args), s, z, rows, live)
+    np.testing.assert_allclose(np.asarray(y, np.float32)[[0, 2]],
+                               np.asarray(want)[[0, 2]], rtol=BF16_RTOL,
+                               atol=BF16_ATOL)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), rtol=1e-5,
+                               atol=1e-5)
+    dead = int(rows[1])
+    assert (np.asarray(s1)[dead] == np.asarray(s)[dead]).all()
+    import time
+
+    @jax.jit
+    def many(q0, s, z):      # the state carried, so updated in place
+        def one(c, _):
+            return R.power_retention_decode(c[0], *args[1:], c[1], c[2],
+                                            rows, live), None
+        return jax.lax.scan(one, (q0, s, z), None, length=25)[0]
+
+    jax.block_until_ready(many(args[0], s, z))
+    t0 = time.perf_counter()
+    jax.block_until_ready(many(args[0], s, z))
+    ms = 1e3 * (time.perf_counter() - t0) / 25
+    with capsys.disabled():
+        print(f"\npower_retention_decode, 2 live rows of 3: {ms:.4f} ms a "
+              f"call = {1e3 * ms / 2:.1f} us a live row (floor 83.2)")
