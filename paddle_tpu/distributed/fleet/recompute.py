@@ -5,7 +5,21 @@ recompute.py:124 (``RecomputeFunction``: PyLayer that stows inputs + RNG
 state, reruns forward during backward). Two regimes here:
 
 * **traced** (inside jit/TrainStep): ``jax.checkpoint`` — XLA-native
-  rematerialization, the mechanism the whole reference file hand-builds.
+  rematerialization, the mechanism the whole reference file hand-builds —
+  with ONE policy for every caller: keep the two residuals the flash
+  forward names (``out`` and ``lse``, ``ops/pallas/flash_attention.py``),
+  recompute everything else. The backward of a checkpointed block then
+  rebuilds q, k and v from the block's input (two products and a rope) and
+  does not run ``flash_fwd`` a second time: on v5e at
+  ``internlm2-d12-pretrain-1chip`` the step runs the kernel 12 times, not
+  24, 525.3 -> 497.5 ms (PERF.md section 6, PR 32). There is no parameter
+  for it because there is nothing to choose: the saved set follows what
+  the traced block contains. A block with no flash attention has no such
+  names and is recomputed whole, as a bare ``jax.checkpoint`` would (to
+  the lowered text: ``tests/test_recompute_flash_residuals.py``); one
+  with it keeps 32 MiB + 64 MiB a layer at that cell's shape, beside the
+  block's input that the checkpoint keeps anyway, and the program's peak
+  did not rise (12.92 -> 12.75 GiB).
 * **eager**: a GradNode that saves inputs + host RNG state; its backward
   restores the RNG, reruns ``function`` with grad enabled, and routes
   cotangents with ``autograd.grad`` — same structure as the reference's
@@ -46,7 +60,14 @@ def recompute(function, *args, **kwargs):
                 return tuple(o._value if isinstance(o, Tensor) else o for o in out)
             return out._value if isinstance(out, Tensor) else out
 
-        out_vals = jax.checkpoint(pure)(values)
+        # imported here: the Pallas stack is a quarter of a second that
+        # ``import paddle_tpu`` does not otherwise pay
+        from ...ops.pallas.flash_attention import (FLASH_LSE_NAME,
+                                                   FLASH_OUT_NAME)
+
+        keep = jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME)
+        out_vals = jax.checkpoint(pure, policy=keep)(values)
         if isinstance(out_vals, tuple):
             return tuple(Tensor._from_value(v) for v in out_vals)
         return Tensor._from_value(out_vals)

@@ -590,7 +590,7 @@ def _flash_bhsd(q, k, v, q_seg, k_seg, causal, scale):
 
 
 def _flash_fwd_rule(q, k, v, q_seg, k_seg, causal, scale):
-    out, lse = _fwd(q, k, v, causal, scale, q_seg, k_seg)
+    out, lse = _name_residuals(*_fwd(q, k, v, causal, scale, q_seg, k_seg))
     return out, (q, k, v, q_seg, k_seg, out, lse)
 
 
@@ -664,3 +664,26 @@ def flash_attention(q, k, v, is_causal=False, seq_lens=None,
     out = _over_mesh(run, (qh, kh, vh) + segs,
                      ("bh..",) * 3 + ("b..",) * len(segs), "bh..")
     return jnp.swapaxes(out, 1, 2)
+
+
+# ------------------------------------------- residuals a checkpoint may keep
+# At the END of the file on purpose: a Mosaic module carries the line numbers
+# of its kernel body and of the frames that called it, so a line added above
+# ``flash_attention`` would change the text (and the compile-cache key) of
+# every serving program that prefills through ``flash_fwd``.
+
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+FLASH_OUT_NAME = "flash_out"
+FLASH_LSE_NAME = "flash_lse"
+__all__ += ["FLASH_OUT_NAME", "FLASH_LSE_NAME"]
+
+
+def _name_residuals(out, lse):
+    """The forward rule's two dear residuals under the names a checkpoint
+    policy can keep (``fleet.recompute`` does, so a recomputed block does not
+    run ``flash_fwd`` a second time: q, k and v are two products and a rope
+    away from the block's input, ``out`` and ``lse`` are the kernel). Outside
+    such a policy a name is the identity and lowers to nothing."""
+    return (checkpoint_name(out, FLASH_OUT_NAME),
+            checkpoint_name(lse, FLASH_LSE_NAME))

@@ -525,12 +525,18 @@ def test_cached_attention_routes_by_what_the_call_shows():
     assert traced(jnp.asarray(offsets), 128, kernels=False) == []
 
 
-def test_flash_attention_without_bases_traces_what_it_traced_before():
+def test_flash_attention_without_bases_traces_what_it_traced_before(
+        monkeypatch):
     """The training cell's forward and backward kernels are the same
     family as the paged forward: a call that names no cache must trace to
     the program it traced before the paged kernel existed. The digests are
     of the jaxprs' text at commit 7ef5d9e (source positions and addresses
-    taken out), recorded from that commit's own tree with this JAX."""
+    taken out), recorded from that commit's own tree with this JAX. Since
+    PR 32 the gradient also holds two ``name`` equations (the residuals a
+    checkpoint policy may keep), which that commit had not: its digest is
+    of the trace with the names taken out, and
+    ``tests/test_recompute_flash_residuals.py`` holds that they lower to
+    nothing."""
     import hashlib
     import re
 
@@ -555,6 +561,7 @@ def test_flash_attention_without_bases_traces_what_it_traced_before():
     assert pallas_names(jaxpr.jaxpr) == ["flash_fwd"]
     assert fwd == ("f162a9bef57ec8787c7685f9f807a8783ef099a0"
                    "cf478bf2c5b9a0c8855db848")
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
     _, bwd = digest(jax.grad(loss, argnums=(0, 1, 2)))
     assert bwd == ("9000b69575f67fac6cc927e1dd00aec6ca04bcab"
                    "0be96302faa8095620238f8d")

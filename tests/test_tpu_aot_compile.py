@@ -150,6 +150,51 @@ def test_flash_attention_fwd_bwd_compiles(topo, seq, heads, kv_heads):
              *[((2, seq, n, 128), BF16) for n in (heads, kv_heads, kv_heads)])
 
 
+def test_recomputed_gradient_program_compiles_at_the_train_cell_widths(topo):
+    """Two layers of ``internlm2-d12-pretrain-1chip`` (hidden 2048, 16 / 8
+    heads of 128, SwiGLU 8192, vocabulary 92,544, 2 x 4096 tokens, bf16,
+    fused lm-head + CE) under ``use_recompute``: the chip's compiler takes
+    the gradient program with the forward's ``out`` and ``lse`` kept across
+    the checkpoint (``lse`` as the kernel writes it, ``f32[b, h, s, 1]``),
+    and what it compiled runs ``flash_fwd`` once a layer."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import _FunctionalModel
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    layers = 2
+    paddle.set_default_dtype("bfloat16")
+    try:
+        with paddle.LazyGuard():
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=92544, hidden_size=2048, intermediate_size=8192,
+                num_hidden_layers=layers, num_attention_heads=16,
+                num_key_value_heads=8, max_position_embeddings=4096,
+                rope_theta=1e6, use_recompute=True))
+    finally:
+        paddle.set_default_dtype("float32")
+    functional = _FunctionalModel(model)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = {k: jax.ShapeDtypeStruct(p._lazy_init[1], BF16,
+                                      sharding=one_chip)
+              for k, p in model.named_parameters()}
+    buffers = {k: b._value for k, b in model.named_buffers()}
+
+    def loss(params, ids, key):
+        return functional(params, buffers, (ids,), {"labels": ids}, key)[0]
+
+    text = jax.jit(jax.grad(loss)).lower(
+        params,
+        jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv"):
+        calls = re.findall(rf"^\s*%?{kernel}(?:\.\d+)? = .*{MOSAIC_CALL}",
+                           text, re.M)
+        assert len(calls) == layers, (kernel, len(calls))
+
+
 @pytest.mark.parametrize("b", [1, 32], ids=["width1", "width32"])
 def test_flash_attention_paged_compiles_at_the_chat_cell_shape(topo, b):
     """The chunk / final / resume programs' attention: 128 new tokens a

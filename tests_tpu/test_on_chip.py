@@ -300,6 +300,86 @@ def test_pallas_flash_attention_gqa_backward_at_the_train_cell_shape():
             assert rel < 1e-2, (n, row, rel)
 
 
+def test_recompute_keeps_flash_residuals_at_the_train_cell_widths(capsys):
+    """Two layers at ``internlm2-d12-pretrain-1chip``'s widths (hidden 2048,
+    16 / 8 heads of 128, SwiGLU 8192, 2 x 4096 tokens, bfloat16, fused
+    lm-head + CE; a vocabulary of 8,192 keeps the oracle small). The
+    recomputed gradient program keeps the forward's ``out`` and ``lse`` and
+    runs ``flash_fwd`` once a layer; its loss and every gradient have to be
+    those of the program with no recompute, both against the same weights in
+    float32 at ``highest`` with every Pallas route off, a batch row at a
+    time (a row's scores are 1 GiB a layer). By the norm of the difference
+    over the oracle's norm, a leaf at a time."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import _FunctionalModel
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    layers, batch, seq = 2, 2, 4096
+    paddle.seed(32)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=8192, hidden_size=2048, intermediate_size=8192,
+        num_hidden_layers=layers, num_attention_heads=16,
+        num_key_value_heads=8, max_position_embeddings=seq, rope_theta=1e6))
+    functional = _FunctionalModel(model)
+    p16 = {k: p._value.astype(jnp.bfloat16)
+           for k, p in model.named_parameters()}
+    p32 = {k: v.astype(jnp.float32) for k, v in p16.items()}
+    buffers = {k: b._value for k, b in model.named_buffers()}
+    key = jax.random.key_data(jax.random.key(0))
+    ids = jnp.asarray(rng.randint(0, 8192, (batch, seq)), jnp.int32)
+
+    def loss(params, ids):
+        return functional(params, buffers, (ids,), {"labels": ids}, key)[0]
+
+    got = {}
+    for rc in (False, True):
+        model.config.use_recompute = rc
+        step = jax.jit(jax.value_and_grad(loss))
+        if rc:
+            text = step.lower(p16, ids).compile().as_text()
+            for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv"):
+                calls = re.findall(
+                    rf"^\s*%?{kernel}(?:\.\d+)? = .*tpu_custom_call", text,
+                    re.M)
+                assert len(calls) == layers, (kernel, len(calls))
+        got[rc] = jax.block_until_ready(step(p16, ids))
+
+    model.config.use_recompute = False
+    paddle.set_flags({"FLAGS_use_pallas_kernels": False})
+    try:
+        with jax.default_matmul_precision("highest"):
+            oracle = jax.jit(jax.value_and_grad(loss))
+            rows = [oracle(p32, ids[r:r + 1]) for r in range(batch)]
+    finally:
+        paddle.set_flags({"FLAGS_use_pallas_kernels": True})
+    want_loss = float(sum(r[0] for r in rows)) / batch
+    want = {k: sum(np.asarray(r[1][k], np.float32) for r in rows) / batch
+            for k in p32}
+
+    def f32(grads):
+        return {k: np.asarray(v, np.float32) for k, v in grads.items()}
+
+    def worst_leaf(a, b):
+        return max(float(np.linalg.norm(a[k] - b[k])
+                         / np.linalg.norm(want[k])) for k in want)
+
+    plain, kept = f32(got[False][1]), f32(got[True][1])
+    worst = {"recompute - oracle": worst_leaf(kept, want),
+             "plain - oracle": worst_leaf(plain, want),
+             "recompute - plain": worst_leaf(kept, plain)}
+    with capsys.disabled():
+        print(f"\nrecompute at the train cell's widths: loss "
+              f"{float(got[True][0]):.6f} (plain {float(got[False][0]):.6f},"
+              f" float32 {want_loss:.6f}); worst leaf {worst}")
+    assert abs(float(got[True][0]) - float(got[False][0])) < 1e-3
+    assert abs(float(got[True][0]) - want_loss) < 2e-2
+    assert worst["recompute - oracle"] < 3e-2, worst
+    # the two bfloat16 programs stand closer to each other than to float32
+    assert worst["recompute - plain"] <= worst["plain - oracle"], worst
+
+
 def test_pallas_flash_attention_masked_on_chip():
     """seq_lens padding + segment-id masking must lower through Mosaic
     ((1, S) int32 seg blocks in all three kernels) and match the masked
