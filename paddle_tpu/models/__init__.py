@@ -47,6 +47,16 @@ from .power_retention import (  # noqa: F401
 __all__ += ["PowerRetentionConfig", "PowerRetentionForCausalLM",
             "PowerRetentionModel", "power_retention_tiny_config"]
 
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHForCausalLM,
+    NemotronHModel,
+    nemotron_h_tiny_config,
+)
+
+__all__ += ["NemotronHConfig", "NemotronHForCausalLM", "NemotronHModel",
+            "nemotron_h_tiny_config"]
+
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

@@ -72,38 +72,54 @@ def _make_static_cache(k, v, length):
 
 
 def sequence_keeps(model):
-    """What one sequence of ``model`` keeps a layer, the ONE question every
-    cache is sized from. ``("pages", k_shape, v_shape)``: something a
-    token, as the trailing shapes of a layer's two cache buffers after
-    (pages, page) or (batch, max_len) -- the model's own where it has a say
-    (``kv_page_shapes()``: a latent cache keeps no per-head keys), else (kv
-    heads, head size) twice, from its config. ``("state", (s_shape,
-    s_dtype), (z_shape, z_dtype))``: one fixed-size state a sequence
-    whatever its length (``sequence_state()``: a recurrent layer), as the
-    shapes after the slot dimension."""
-    state = getattr(model, "sequence_state", None)
-    if state is not None:
-        (s_shape, s_dtype), (z_shape, z_dtype) = state()
-        return ("state", (tuple(s_shape), s_dtype), (tuple(z_shape), z_dtype))
-    own = getattr(model, "kv_page_shapes", None)
+    """What one sequence of ``model`` keeps, a LAYER: the ONE question every
+    cache is sized from. A tuple with an entry a layer. ``("pages", k_shape,
+    v_shape)``: something a token, as the trailing shapes of the layer's two
+    cache buffers after (pages, page) or (batch, max_len). ``("state",
+    (s_shape, s_dtype), (z_shape, z_dtype))``: one fixed-size state a
+    sequence whatever its length, as the shapes after the slot dimension.
+    ``None``: nothing (a layer that mixes channels alone). The answer is the
+    model's own where it has a say, a layer (``layer_keeps()``: layers of
+    several kinds in one stack) or once for all of them
+    (``sequence_state()``: a recurrent layer; ``kv_page_shapes()``: a latent
+    cache keeps no per-head keys), else (kv heads, head size) twice, from
+    its config."""
+    def entry(keep):
+        if keep is None:
+            return None
+        kind, k, v = keep
+        if kind == "state":
+            return kind, (tuple(k[0]), k[1]), (tuple(v[0]), v[1])
+        return kind, tuple(k), tuple(v)
+
+    own = getattr(model, "layer_keeps", None)
     if own is not None:
-        k_shape, v_shape = own()
-        return "pages", tuple(k_shape), tuple(v_shape)
+        return tuple(entry(keep) for keep in own())
     cfg = model.config
-    kv = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
-    return "pages", (kv, cfg.head_dim), (kv, cfg.head_dim)
+    if hasattr(model, "sequence_state"):
+        one = ("state", *model.sequence_state())
+    elif hasattr(model, "kv_page_shapes"):
+        one = ("pages", *model.kv_page_shapes())
+    else:
+        kv = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+        one = ("pages", (kv, cfg.head_dim), (kv, cfg.head_dim))
+    return (entry(one),) * cfg.num_hidden_layers
 
 
 def kv_page_shapes(model):
-    """The page case of :func:`sequence_keeps`, for the callers that build
-    a cache a token (``generate``, ``build_serve_fn``); a model that keeps
-    a state is served through ``ContinuousBatchingEngine``."""
-    kind, k_shape, v_shape = sequence_keeps(model)
-    if kind != "pages":
+    """The case of :func:`sequence_keeps` in which every layer keeps the
+    same pages, for the callers that build one cache a token a layer
+    (``generate``, ``build_serve_fn``); any other model is served through
+    ``ContinuousBatchingEngine``."""
+    keeps = set(sequence_keeps(model))
+    if len(keeps) != 1 or next(iter(keeps)) is None \
+            or next(iter(keeps))[0] != "pages":
         raise NotImplementedError(
-            f"{type(model).__name__} keeps a recurrent state a sequence, "
-            "not keys and values a token: generate() builds no such cache; "
-            "serve it through ContinuousBatchingEngine (ROADMAP M4)")
+            f"{type(model).__name__} keeps a recurrent state a sequence in "
+            "some layer, or not the same in every layer: generate() builds "
+            "keys and values a token for every layer alike; serve it "
+            "through ContinuousBatchingEngine (ROADMAP M4)")
+    _, k_shape, v_shape = next(iter(keeps))
     return k_shape, v_shape
 
 
@@ -142,28 +158,54 @@ class StateCache:
         self.stats = None
 
 
-class PagedStore:
-    """The serving engine's two per-layer device arrays for a model that
-    keeps something a TOKEN: pools of pages, addressed through the engine's
-    page table. One of the two answers to :func:`sequence_store`; the
-    engine asks it for the arrays, for the caches a forward runs over, for
-    the arrays a forward leaves behind and for the logits it samples a
-    first token from, and never looks inside a cache itself."""
+class LayerPass:
+    """What the serving engine hands a layer that keeps nothing: which rows
+    hold a sequence (``live``, in a decode step) in, and what the layer
+    counted (``stats``) out."""
 
-    kind = "pages"
-    reset = None          # a granted page needs no zeroing
-    bytes_per_slot = 0
+    __slots__ = ("live", "stats")
 
-    def __init__(self, k_shape, v_shape, dtype, n_layers, page_size,
-                 attn_pages, chunk_aligned):
-        self._shapes, self._dtype = (tuple(k_shape), tuple(v_shape)), dtype
-        self._nl, self._page = n_layers, page_size
+    def __init__(self, live=None):
+        self.live = live
+        self.stats = None
+
+
+class SequenceStore:
+    """The serving engine's per-layer device arrays, two a layer that keeps
+    something, for whatever :func:`sequence_keeps` says of each layer: pools
+    of pages addressed through the engine's page table (something a TOKEN),
+    or a row a slot and a last row, the scratch slot, that an admission
+    group's padding rows write (ONE state a sequence). All on ONE table: a
+    slot's row names its pages and, where some layer keeps a state, in its
+    last column the slot's state row. The engine asks the store for the
+    arrays, for the caches a forward runs over, for the arrays a forward
+    leaves behind and for the logits it samples a first token from, and
+    never looks inside a cache itself; what it may do with a slot follows
+    from ``has_pages`` (a pool to plan and grow) and ``has_state`` (granted
+    slots are reset; no prefix cache, no page moves)."""
+
+    def __init__(self, keeps, dtype, page_size, attn_pages, chunk_aligned):
+        self._keeps, self._dtype = tuple(keeps), dtype
+        self._page = page_size
         self._attn_pages, self._aligned = attn_pages, chunk_aligned
-        self.bytes_per_token = n_layers * sum(
-            math.prod(sh) for sh in self._shapes) * np.dtype(dtype).itemsize
+        held = [k for k in self._keeps if k is not None]
+        self.has_pages = any(k[0] == "pages" for k in held)
+        self.has_state = any(k[0] == "state" for k in held)
+        # which of the held arrays are a state's (``reset``, ``states``)
+        self._is_state = [k[0] == "state" for k in held]
+        self.bytes_per_token = sum(
+            math.prod(sh) * np.dtype(dtype).itemsize
+            for k in held if k[0] == "pages" for sh in k[1:])
+        self.bytes_per_slot = sum(
+            math.prod(sh) * np.dtype(dt).itemsize
+            for k in held if k[0] == "state" for sh, dt in k[1:])
+        self.reset = self._reset if self.has_state else None
 
     def pool_pages(self, asked, max_slots, per_seq):
-        """Allocatable pages: by default one full-length sequence a slot."""
+        """Allocatable pages: by default one full-length sequence a slot;
+        none where no layer keeps pages (a granted slot owns its state)."""
+        if not self.has_pages:
+            return 0
         n = max_slots * per_seq if asked is None else int(asked)
         if n < per_seq:
             raise ValueError(
@@ -172,24 +214,34 @@ class PagedStore:
         return n
 
     def allocate(self, n_pages, max_slots):
-        lead = (n_pages, self._page)
-        return tuple([jnp.zeros(lead + sh, self._dtype)
-                      for _ in range(self._nl)] for sh in self._shapes)
+        def one(keep, i):
+            if keep[0] == "state":
+                shape, dt = keep[i]
+                return jnp.zeros((max_slots + 1,) + shape, dt)
+            return jnp.zeros((n_pages, self._page) + keep[i], self._dtype)
 
-    @staticmethod
-    def tables(max_slots, total_cols, dump_page, scratch_ids):
+        return tuple([one(k, i) for k in self._keeps if k is not None]
+                     for i in (1, 2))
+
+    def tables(self, max_slots, total_cols, dump_page, scratch_ids):
         """The host page table: a row a slot, every cell on the dump page
-        until the allocator grants it, and the scratch row."""
+        until the allocator grants it, and the scratch row; behind them,
+        where a layer keeps a state, the column that names each row's state
+        row (the last: the scratch slot's), for good."""
+        slot = np.arange(max_slots + 1, dtype=np.int32)[:, None]
+        if not self.has_pages:
+            return slot              # ``lengths`` keeps positions and budgets
         tables = np.full((max_slots + 1, total_cols), dump_page, np.int32)
         tables[max_slots] = scratch_ids
-        return tables
+        return np.hstack([tables, slot]) if self.has_state else tables
 
-    @staticmethod
-    def set_row(row, pages, dump_page):
+    def set_row(self, row, pages, dump_page):
         """A slot's granted pages into its table row; the tail aliases the
         dump page."""
-        row[:len(pages)] = pages
-        row[len(pages):] = dump_page
+        if self.has_pages:
+            end = row.size - self.has_state
+            row[:len(pages)] = pages
+            row[len(pages):end] = dump_page
 
     def caches(self, ks, vs, tables, length, aligned=None, live=None,
                true_lens=None):
@@ -197,92 +249,62 @@ class PagedStore:
         # bulk-write opt-in) exactly when the chunk is a page multiple;
         # the prefix-RESUME path passes aligned=False -- its bases start
         # at the first divergent token, which may sit mid-page.
-        # ``true_lens`` is not a page model's concern: it never reads what
-        # padding wrote
+        # ``true_lens`` is a state's concern alone: a page layer never
+        # reads what padding wrote
         if aligned is None:
             aligned = self._aligned
-        return [_make_paged_cache(ks[i], vs[i], tables, self._page, length,
-                                  aligned_bases=aligned,
-                                  attn_pages=self._attn_pages, live=live)
-                for i in range(self._nl)]
+        rows = tables[:, -1] if self.has_state else None
+        if self.has_state and self.has_pages:
+            tables = tables[:, :-1]
+        out, held = [], iter(zip(ks, vs))
+        for keep in self._keeps:
+            if keep is None:
+                out.append(LayerPass(live))
+            elif keep[0] == "state":
+                out.append(StateCache(*next(held), rows, length, true_lens,
+                                      live))
+            else:
+                out.append(_make_paged_cache(
+                    *next(held), tables, self._page, length,
+                    aligned_bases=aligned, attn_pages=self._attn_pages,
+                    live=live))
+        return out
 
     @staticmethod
     def pools(caches):
-        return ([c.k_pages for c in caches], [c.v_pages for c in caches])
+        pairs = [(c.s, c.z) if isinstance(c, StateCache)
+                 else (c.k_pages, c.v_pages)
+                 for c in caches if not isinstance(c, LayerPass)]
+        return [p[0] for p in pairs], [p[1] for p in pairs]
 
-    @staticmethod
-    def last_logits(logits, true_lens):
+    def last_logits(self, logits, true_lens):
+        if self.has_state:
+            # the model was told the true lengths and took its head at that
+            # position alone: (N, 1, V)
+            return logits[:, 0]
         # each row's TRUE last position (padding rows are never read)
         idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
         return jnp.take_along_axis(
             logits, jnp.broadcast_to(
                 idx, (logits.shape[0], 1, logits.shape[-1])), axis=1)[:, 0]
 
-
-class StateStore:
-    """:class:`PagedStore`'s counterpart for a model that keeps ONE
-    fixed-size state a sequence (``S`` / ``Z`` a layer): a row a slot and
-    a last row, the scratch slot, that an admission group's padding rows
-    write. A slot's page-table row names its state row and nothing else."""
-
-    kind = "state"
-    bytes_per_token = 0
-
-    def __init__(self, s_keep, z_keep, n_layers):
-        self._keeps, self._nl = (s_keep, z_keep), n_layers
-        self.bytes_per_slot = n_layers * sum(
-            math.prod(sh) * np.dtype(dt).itemsize for sh, dt in self._keeps)
-
-    @staticmethod
-    def pool_pages(asked, max_slots, per_seq):
-        return 0                 # a granted slot owns its state
-
-    def allocate(self, n_pages, max_slots):
-        return tuple([jnp.zeros((max_slots + 1,) + sh, dt)
-                      for _ in range(self._nl)] for sh, dt in self._keeps)
-
-    @staticmethod
-    def tables(max_slots, total_cols, dump_page, scratch_ids):
-        # a row names its slot's state row (the last: the scratch slot's)
-        # and nothing else, for good; ``lengths`` keeps its meaning for
-        # positions and budgets
-        return np.arange(max_slots + 1, dtype=np.int32)[:, None]
-
-    @staticmethod
-    def set_row(row, pages, dump_page):
-        pass
-
-    def caches(self, ks, vs, tables, length, aligned=None, live=None,
-               true_lens=None):
-        return [StateCache(ks[i], vs[i], tables[:, 0], length, true_lens,
-                           live) for i in range(self._nl)]
-
-    @staticmethod
-    def pools(caches):
-        return [c.s for c in caches], [c.z for c in caches]
-
-    @staticmethod
-    def last_logits(logits, true_lens):
-        # the model was told the true lengths and took its head at that
-        # position alone: (N, 1, V)
-        return logits[:, 0]
-
-    @staticmethod
-    def reset(ks, vs, rows):
+    def _reset(self, ks, vs, rows):
         # a granted slot starts from the zero state (padding lanes zero
-        # the scratch slot)
-        return ([k.at[rows].set(0) for k in ks],
-                [v.at[rows].set(0) for v in vs])
+        # the scratch slot); its pages need no zeroing
+        return tuple([a.at[rows].set(0) if state else a
+                      for a, state in zip(arrays, self._is_state)]
+                     for arrays in (ks, vs))
+
+    def states(self, ks, vs):
+        """The state layers' pairs of arrays, in layer order."""
+        return [(k, v) for k, v, state in zip(ks, vs, self._is_state)
+                if state]
 
 
 def sequence_store(model, dtype, page_size, attn_pages, chunk_aligned):
     """The store for what :func:`sequence_keeps` says ``model`` keeps."""
-    kind, k_keep, v_keep = sequence_keeps(model)
-    n_layers = model.config.num_hidden_layers
-    if kind == "state":
-        return StateStore(k_keep, v_keep, n_layers)
-    return PagedStore(k_keep, v_keep, dtype, n_layers, page_size,
-                      attn_pages, chunk_aligned)
+    return SequenceStore(sequence_keeps(model), dtype, page_size, attn_pages,
+                         chunk_aligned)
 
 
 def _generate_jit(model, ids, max_new_tokens, do_sample, temperature,

@@ -67,7 +67,7 @@ from .llama import (
     _rope_tables,
 )
 
-__all__ = ["MoEMLAConfig", "LatentAttention", "SparseExperts",
+__all__ = ["MoEMLAConfig", "LatentAttention", "SparseExperts", "UngatedMLP",
            "MoEMLADecoderLayer", "MoEMLAModel", "MoEMLAForCausalLM",
            "moe_mla_tiny_config", "route", "STEP_STAT_NAMES"]
 
@@ -285,12 +285,42 @@ def route(x, gate_w, bias, top_k, scaling, norm_topk_prob=True):
     return ids.astype(jnp.int32), w * scaling
 
 
+class UngatedMLP(Layer):
+    """``act(x W_up) W_down`` with ``act = relu(.)^2``: the expert of a
+    family that does not gate (``LlamaMLP`` is the gated one)."""
+
+    def __init__(self, config):
+        super().__init__()
+        self.up_proj = _linear(config.hidden_size, config.intermediate_size,
+                               config)
+        self.down_proj = _linear(config.intermediate_size,
+                                 config.hidden_size, config)
+
+    def forward(self, x):
+        h = self.up_proj(x)._value
+        return self.down_proj(Tensor._from_value(
+            jnp.square(jax.nn.relu(h)).astype(h.dtype)))
+
+
 class SparseExperts(Layer):
     """Router over ALL routed experts, the experts this chip holds, and
     the shared expert. ``forward(x, live)`` returns the layer's output and
-    its step statistics (int32, ``STEP_STAT_NAMES``)."""
+    its step statistics (int32, ``STEP_STAT_NAMES``).
 
-    def __init__(self, config: MoEMLAConfig):
+    The expert is the model's: ``config.expert_activation`` ``"swiglu"``
+    (the default: ``(silu(x W_gate) * x W_up) W_down``, the held experts
+    stacked as ``experts_gate_up`` = [gate | up]) or ``"relu2"``
+    (``relu(x W_up)^2 W_down``, stacked as ``experts_up``); the shared
+    expert is ``config.shared_intermediate_size`` wide (default: the routed
+    width times ``n_shared_experts``). ``experts_up`` has its columns padded
+    to a multiple of 128 (1,856 -> 1,920; the grouped product reads and
+    multiplies the padding with the rest, 3.4 % more bytes an expert, and
+    its 64 columns of the result are sliced away before the activation): the
+    chip lays a matrix out in 128-lane tiles whatever its width, and a
+    stack whose width is no multiple is transposed whole, every call, on
+    its way to the kernel."""
+
+    def __init__(self, config):
         super().__init__()
         self.config = config
         c = config
@@ -301,15 +331,20 @@ class SparseExperts(Layer):
             (c.n_routed_experts,), dtype="float32",
             default_initializer=I.Constant(0.0))
         f = c.moe_intermediate_size
-        # the held experts, stacked: [gate | up] side by side, then down
-        self.experts_gate_up = self.create_parameter(
-            (count, c.hidden_size, 2 * f), default_initializer=init)
+        self.gated = getattr(c, "expert_activation", "swiglu") == "swiglu"
+        self._stack_in = "experts_gate_up" if self.gated else "experts_up"
+        setattr(self, self._stack_in, self.create_parameter(
+            (count, c.hidden_size, 2 * f if self.gated
+             else -(-f // 128) * 128), default_initializer=init))
         self.experts_down = self.create_parameter(
             (count, f, c.hidden_size), default_initializer=init)
-        self.shared_experts = LlamaMLP(SimpleNamespace(
+        shared = SimpleNamespace(
             hidden_size=c.hidden_size,
-            intermediate_size=f * c.n_shared_experts,
-            initializer_range=c.initializer_range))
+            intermediate_size=getattr(c, "shared_intermediate_size", None)
+            or f * c.n_shared_experts,
+            initializer_range=c.initializer_range)
+        self.shared_experts = (LlamaMLP if self.gated
+                               else UngatedMLP)(shared)
 
     def forward(self, x, live=None):
         c = self.config
@@ -354,9 +389,13 @@ class SparseExperts(Layer):
         if pad:
             lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
         gmm = moe_gmm if _flag("FLAGS_use_pallas_kernels") else gmm_reference
-        hcat = gmm(lhs, self.experts_gate_up._value, sizes)
-        act = (jax.nn.silu(hcat[:, :f].astype(jnp.float32))
-               * hcat[:, f:].astype(jnp.float32)).astype(xv.dtype)
+        hcat = gmm(lhs, getattr(self, self._stack_in)._value, sizes)
+        if self.gated:
+            act = (jax.nn.silu(hcat[:, :f].astype(jnp.float32))
+                   * hcat[:, f:].astype(jnp.float32)).astype(xv.dtype)
+        else:
+            act = jnp.square(jax.nn.relu(
+                hcat[:, :f].astype(jnp.float32))).astype(xv.dtype)
         y = gmm(act, self.experts_down._value, sizes)[:m]
         # back to (token, choice) order; an assignment that is nobody's
         # here (another chip's expert, a dead slot) adds nothing: its row

@@ -369,17 +369,26 @@ class ContinuousBatchingEngine:
             dtype = jnp.float32
         per_seq = self.max_len // self.page_size
         self._cols = per_seq  # attention-visible table columns
-        # what a sequence keeps a layer (``generation.sequence_store``):
+        # what a sequence keeps in EACH layer (``generation.sequence_store``):
         # pages a token -- the model's own page shapes where it has a say
-        # (a latent cache), else (kv heads, head size) twice -- or ONE
-        # fixed-size state a slot (a recurrent layer). The store owns the
-        # two per-layer device arrays' format; ``_state`` is left for what
-        # the page allocator has nothing to do for (a granted slot owns
-        # its state: no pool, no prefix cache, no page to move)
+        # (a latent cache), else (kv heads, head size) twice -- ONE
+        # fixed-size state a slot (a recurrent layer), or nothing. The
+        # store owns the per-layer device arrays' format, and its two facts
+        # are the engine's rules: ``_paged`` (some layer keeps pages: a pool
+        # to plan, grow and account) and ``_state`` (some layer keeps a
+        # state: granted slots are reset, a failed window is re-admitted,
+        # and there is no prefix cache and no page to move)
         self._keep = sequence_store(
             model, dtype, self.page_size, per_seq,
             self.prompt_buckets[-1] % self.page_size == 0)
-        self._state = self._keep.kind == "state"
+        self._state, self._paged = self._keep.has_state, self._keep.has_pages
+        if self._state and self._paged and pool_pages is not None \
+                and int(pool_pages) < self.max_slots * per_seq:
+            raise NotImplementedError(
+                f"{type(model).__name__} keeps a state beside pages: a pool "
+                f"of {pool_pages} pages (under one full-length sequence a "
+                "slot) would preempt, and a preempted row's state is not "
+                "rebuilt yet (re-prefill over a reset state: ROADMAP M4)")
         # DYNAMIC POOL: ``pool_pages`` allocatable pages shared by every
         # slot (default: the historical budget of one full-length
         # sequence per slot, so the device arrays are byte-identical to
@@ -1172,11 +1181,13 @@ class ContinuousBatchingEngine:
         return None
 
     def _refuse_state(self, what):
-        """A state model has no pages to pin, export or land."""
+        """A model with a state in any layer has no whole sequence in pages
+        to pin, export or land."""
         if self._state:
             raise NotImplementedError(
                 f"{type(self.model).__name__} keeps a recurrent state a "
-                f"slot, not pages: {what} needs a snapshot of the state to "
+                f"slot, not pages alone: {what} needs a snapshot of the "
+                "state to "
                 "move (models/transfer.py export_pages / import_pages), "
                 "which is not built yet: ROADMAP M4")
 
@@ -1491,7 +1502,7 @@ class ContinuousBatchingEngine:
         """The two ``serving.prefill_attn_cols_*`` counters for one
         dispatch of ``width`` new tokens a row at its real rows' ``bases``
         (a state model attends to no column)."""
-        if telemetry.enabled() and not self._state:
+        if telemetry.enabled() and self._paged:
             _M_PREFILL_LIVE.inc(int(np.sum(bases + width)))
             _M_PREFILL_TABLE.inc(len(bases) * self._cols * self.page_size)
 
@@ -1695,7 +1706,7 @@ class ContinuousBatchingEngine:
                         self._tables_device(),
                         lengths, toks, active, self._limits_device(), keys)
             self._seg_runs += 1
-            if telemetry.enabled() and not self._state:
+            if telemetry.enabled() and self._paged:
                 _M_ATTN_LIVE.inc(int(
                     (-(-self._lengths // self.page_size)).sum()))
                 _M_ATTN_TABLE.inc(self.max_slots * self._cols)
@@ -2163,7 +2174,7 @@ class ContinuousBatchingEngine:
         first-window decode growth: the caller defers the queue head.
         A previously PREEMPTED request requires coverage of its FULL
         remaining budget, so it cannot thrash straight back out."""
-        if self._state:
+        if not self._paged:
             return [], 0, None, []   # a granted slot owns its state
         P = int(req.prompt.size)
         page = self.page_size
@@ -2221,7 +2232,7 @@ class ContinuousBatchingEngine:
         to the queue — its stream resumes bit-identically via the
         per-request key stream, and the prefix cache usually makes the
         re-prefill one page of work. A running decode never fails."""
-        if self._state:
+        if not self._paged:
             return                   # nothing grows with a sequence
         horizon = self._growth_horizon()
         while True:
@@ -2305,7 +2316,7 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"{type(self.model).__name__} keeps pages, not a state")
         return [(np.asarray(s[slot]), np.asarray(z[slot]))
-                for s, z in zip(self._ks, self._vs)]
+                for s, z in self._keep.states(self._ks, self._vs)]
 
     # -------------------------- KV page transfer (disaggregation handoff)
     #
@@ -2496,8 +2507,8 @@ class ContinuousBatchingEngine:
         lookups = getattr(self, "_prefix_lookup_tokens", 0)
         hits = getattr(self, "_prefix_hit_tokens", 0)
         return {
-            # pages at what a token costs, or (a state model: no page, 0
-            # of 0 below) live slots at what a slot's state holds
+            # pages at what a token costs, and live slots at what a
+            # slot's state holds (either is 0 where no layer keeps it)
             "bytes_in_use": (phys * self.page_size
                              * self._kv_bytes_per_token
                              + n * self._state_bytes_per_slot),

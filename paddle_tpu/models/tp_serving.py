@@ -169,7 +169,8 @@ class TPShardedEngine(ContinuousBatchingEngine):
 
     def __init__(self, model, max_slots, max_len, mesh=None, tp_axis="mp",
                  plan=None, **kwargs):
-        if sequence_keeps(model)[0] == "state":
+        if any(k is not None and k[0] == "state"
+               for k in sequence_keeps(model)):
             # the state's kv heads would shard cleanly, but nothing here
             # places a state or partitions its kernels yet
             raise NotImplementedError(

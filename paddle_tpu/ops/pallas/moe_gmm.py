@@ -40,9 +40,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
 
-__all__ = ["moe_gmm", "gmm_reference", "row_tile"]
+__all__ = ["moe_gmm", "gmm_reference", "row_tile", "col_tile"]
 
 _TN = 512          # columns of rhs a grid step reads: (K, 512) bf16 = 2 MiB
+
+
+def col_tile(n: int) -> int:
+    """Columns of rhs a grid step reads: the largest multiple of 128 up to
+    512 that divides ``n`` (512 for 1,536 and 2,048; 384 for 1,920 and
+    2,688), else all of them."""
+    for tn in range(_TN, 0, -128):
+        if n % tn == 0:
+            return tn
+    return n
 
 
 def row_tile(m: int) -> int:
@@ -104,7 +114,7 @@ def moe_gmm(lhs, rhs, group_sizes):
     if m % tm:
         raise ValueError(f"moe_gmm: {m} rows are no multiple of the row "
                          f"tile {tm}; pad them")
-    tn = _TN if n % _TN == 0 else n
+    tn = col_tile(n)
     group_sizes = group_sizes.astype(jnp.int32)
     groups, tiles, offsets, total = _gmm_work_list(group_sizes, m, tm)
 

@@ -135,6 +135,71 @@ def test_moe_gmm_compiles_at_the_published_expert_shapes(topo, m, k, n):
              ((256,), jnp.int32))
 
 
+@pytest.mark.parametrize("m,k,n", [
+    (256, 2688, 1920), (256, 1856, 2688),       # a decode step: 32 x top-6
+    (24576, 2688, 1920),                        # a 32 x 128 prefill
+], ids=["decode_up", "decode_down", "prefill_up"])
+def test_moe_gmm_compiles_at_the_think_cells_ungated_expert_shapes(topo, m, k,
+                                                                   n):
+    """nemotron3nano-think-open: 64 held experts of 1,856, the first stack
+    stored 1,920 columns wide. Neither width is a multiple of 512, so the
+    column tile is 384 (a whole (2688, 1856) matrix a block would need 20
+    MB of VMEM; a stack 1,856 wide is laid out transposed by the chip's
+    compiler and copied whole, 640 MB, on its way to the kernel). No copy of
+    the stack is in what compiled."""
+    from paddle_tpu.ops.pallas.moe_gmm import col_tile, moe_gmm
+
+    assert col_tile(n) == 384 and col_tile(1536) == col_tile(2048) == 512
+    compiled = _compile(moe_gmm, topo, ((m, k), BF16), ((64, k, n), BF16),
+                        ((64,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * k * n
+
+
+@pytest.mark.parametrize("kernel", ["decode", "chunk_g1", "chunk_g32"])
+def test_ssd_kernels_compile_at_the_think_cell_shapes(topo, kernel):
+    """nemotron3nano-think-open: 32 slots + the scratch slot, 64 Mamba-2
+    heads of 64 in 8 groups, state 128, the float32 state (2.1 MB a slot a
+    layer) updated in place."""
+    from paddle_tpu.ops.pallas import ssd as S
+
+    f32 = jnp.float32
+    state = ((33, 64, 64, 128), f32)
+    if kernel == "decode":
+        fn, shapes, donate = S.ssd_decode, (
+            ((32, 64, 64), BF16), ((32, 64), f32), ((32, 64), f32),
+            ((32, 8, 128), BF16), ((32, 8, 128), BF16), state,
+            ((32,), jnp.int32), ((32,), jnp.bool_)), 5
+    else:
+        g = int(kernel.split("_g")[1])
+        fn, shapes, donate = S.ssd_chunk, (
+            ((g, 128, 64, 64), BF16), ((g, 128, 64), f32),
+            ((g, 128, 64), f32), ((g, 128, 8, 128), BF16),
+            ((g, 128, 8, 128), BF16), state, ((g,), jnp.int32)), 5
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in shapes]
+    compiled = jax.jit(fn, donate_argnums=(donate,)).lower(*avals).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+    # the state is aliased through the kernel, not copied
+    assert compiled.memory_analysis().alias_size_in_bytes >= 33 * 2_097_152
+
+
+def test_a_two_kv_head_page_pool_is_not_padded_on_the_chip(topo):
+    """A page of (128 tokens, 2 kv heads, 128) bfloat16: the chip's compiler
+    lays the pool out in (2, 128) tiles, 1,024 bytes a token a layer and not
+    the eightfold of a (16, 128) tile, so the hybrid's attention keeps
+    ``paged_attention``'s (pages, page, kv heads, head size) layout."""
+    pages = 1025
+    compiled = _compile(
+        paged_attention, topo, ((32, 32, 128), BF16),
+        ((pages, 128, 2, 128), BF16), ((pages, 128, 2, 128), BF16),
+        ((32, 32), jnp.int32), ((32,), jnp.int32))
+    text = compiled.as_text()
+    assert "bf16[1025,128,2,128]{3,2,1,0:T(2,128)(2,1)}" in text
+    assert compiled.memory_analysis().argument_size_in_bytes < \
+        2 * pages * 128 * 2 * 128 * 2 * 1.01 + 1e6
+
+
 @pytest.mark.parametrize("seq,heads,kv_heads", [
     (2048, 32, 32),
     # the internlm2-d12-pretrain-1chip cell: the dk/dv kernel holds a query

@@ -705,3 +705,97 @@ def test_power_retention_kernels_at_the_continue_cell_widths(capsys):
     with capsys.disabled():
         print(f"\npower_retention_decode, 2 live rows of 3: {ms:.4f} ms a "
               f"call = {1e3 * ms / 2:.1f} us a live row (floor 83.2)")
+
+
+def test_ssd_kernels_at_the_think_cell_widths(capsys):
+    """Mamba-2's two kernels at Nemotron 3 Nano's widths (64 heads of 64, 8
+    groups, state 128, bf16 activations, the float32 state of 33 slots): a
+    chunk of 128 from the zero state and a second on the state it left, then
+    a decode step over a work list with a dead row, each against its jnp
+    form in float32, the dead row's state untouched; and the decode
+    kernel's time a call at 19 live rows of 32 beside its bytes' floor
+    (2 x 2.10 MB a row at 819 GB/s = 5.1 us a row)."""
+    import functools
+    import time
+
+    from paddle_tpu.ops.pallas import ssd as S
+
+    r = np.random.RandomState(5)
+    H, P, G, N, L, SLOTS = 64, 64, 8, 128, 128, 33
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def operands(b, n):
+        x = jnp.asarray(r.randn(b, n, H, P).astype(np.float32), bf)
+        bm = jnp.asarray(r.randn(b, n, G, N).astype(np.float32), bf)
+        cm = jnp.asarray(r.randn(b, n, G, N).astype(np.float32), bf)
+        dt = jnp.asarray(np.exp(r.uniform(np.log(1e-3), np.log(0.1),
+                                          (b, n, H))).astype(np.float32))
+        a = jnp.asarray(r.uniform(1.0, 16.0, (H,)).astype(np.float32))
+        return x, dt, -dt * a, bm, cm
+
+    rows = jnp.asarray(r.permutation(SLOTS)[:3], jnp.int32)
+    state = jnp.zeros((SLOTS, H, P, N), f32)
+    x, dt, da, bm, cm = operands(3, 2 * L)
+    dt = S.mask_steps(dt, jnp.asarray([2 * L, L + 37, 2 * L]))
+    da = jnp.where(dt > 0, da, 0.0)
+    for sl in (slice(0, L), slice(L, 2 * L)):
+        args = (x[:, sl], dt[:, sl], da[:, sl], bm[:, sl], cm[:, sl])
+        y, s1 = jax.jit(S.ssd_chunk)(*args, state, rows)
+        with jax.default_matmul_precision("highest"):
+            want, s0 = jax.jit(S.ssd_chunk_reference)(
+                *(a.astype(f32) for a in args), state, rows)
+        scale = float(np.abs(np.asarray(want)).max())
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                                   rtol=BF16_RTOL, atol=BF16_ATOL * scale)
+        np.testing.assert_allclose(np.asarray(s1), np.asarray(s0),
+                                   rtol=BF16_RTOL, atol=BF16_ATOL)
+        state = s0
+    live = jnp.asarray([True, False, True])
+    args = (x[:, 0], dt[:, 1], jnp.exp(da[:, 1]), bm[:, 2], cm[:, 3])
+    y, s1 = jax.jit(S.ssd_decode)(*args, state, rows, live)
+    with jax.default_matmul_precision("highest"):
+        want, s0 = jax.jit(S.ssd_decode_reference)(
+            *(a.astype(f32) for a in args), state, rows, live)
+    # the state's update is exact products in float32; y is one float32
+    # product at ``highest``
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), rtol=1e-5,
+                               atol=1e-5)
+    scale = float(np.abs(np.asarray(want)).max())
+    np.testing.assert_allclose(np.asarray(y)[[0, 2]],
+                               np.asarray(want)[[0, 2]], rtol=1e-3,
+                               atol=1e-3 * scale)
+    dead = int(rows[1])
+    assert (np.asarray(s1)[dead] == np.asarray(state)[dead]).all()
+
+    xb, dtb, dab, bmb, cmb = operands(32, 1)
+    live32 = jnp.asarray(np.arange(32) < 19)
+    rows32 = jnp.arange(32, dtype=jnp.int32)
+    forms = {f"heads_block {hb}": functools.partial(S.ssd_decode,
+                                                    heads_block=hb)
+             for hb in (16, 32, 64)}
+    want32, _ = jax.jit(S.ssd_decode_reference)(
+        xb[:, 0].astype(f32), dtb[:, 0], jnp.exp(dab[:, 0]),
+        bmb[:, 0].astype(f32), cmb[:, 0].astype(f32), state, rows32, live32)
+    for hb, form in forms.items():
+        got32, _ = jax.jit(form)(xb[:, 0], dtb[:, 0], jnp.exp(dab[:, 0]),
+                                 bmb[:, 0], cmb[:, 0], state, rows32, live32)
+        np.testing.assert_allclose(np.asarray(got32), np.asarray(want32),
+                                   rtol=1e-3, atol=1e-3 * float(
+                                       np.abs(np.asarray(want32)).max()))
+
+        @jax.jit
+        def many(x0, s):     # the state carried, so updated in place
+            def one(c, _):
+                y, s = form(c[0], dtb[:, 0], jnp.exp(dab[:, 0]),
+                            bmb[:, 0], cmb[:, 0], c[1], rows32, live32)
+                return (c[0] + y.astype(bf) * 0, s), None
+            return jax.lax.scan(one, (x0, s), None, length=25)[0]
+
+        jax.block_until_ready(many(xb[:, 0], state))
+        t0 = time.perf_counter()
+        jax.block_until_ready(many(xb[:, 0], state))
+        ms = 1e3 * (time.perf_counter() - t0) / 25
+        with capsys.disabled():
+            print(f"\nssd_decode {hb}, 19 live rows of 32: "
+                  f"{ms:.4f} ms a call = {1e3 * ms / 19:.2f} us a live row "
+                  f"(floor 5.12)")
