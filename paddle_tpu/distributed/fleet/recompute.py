@@ -6,20 +6,34 @@ state, reruns forward during backward). Two regimes here:
 
 * **traced** (inside jit/TrainStep): ``jax.checkpoint`` — XLA-native
   rematerialization, the mechanism the whole reference file hand-builds —
-  with ONE policy for every caller: keep the two residuals the flash
-  forward names (``out`` and ``lse``, ``ops/pallas/flash_attention.py``),
-  recompute everything else. The backward of a checkpointed block then
-  rebuilds q, k and v from the block's input (two products and a rope) and
-  does not run ``flash_fwd`` a second time: on v5e at
-  ``internlm2-d12-pretrain-1chip`` the step runs the kernel 12 times, not
-  24, 525.3 -> 497.5 ms (PERF.md section 6, PR 32). There is no parameter
-  for it because there is nothing to choose: the saved set follows what
-  the traced block contains. A block with no flash attention has no such
-  names and is recomputed whole, as a bare ``jax.checkpoint`` would (to
-  the lowered text: ``tests/test_recompute_flash_residuals.py``); one
-  with it keeps 32 MiB + 64 MiB a layer at that cell's shape, beside the
-  block's input that the checkpoint keeps anyway, and the program's peak
-  did not rise (12.92 -> 12.75 GiB).
+  with ONE policy for every caller: keep the five residuals the flash
+  forward rule names (q, k, v, ``out`` and ``lse``,
+  ``ops/pallas/flash_attention.py``), recompute everything else. In one
+  sentence: *a recomputed block never rebuilds anything the attention
+  kernel's backward reads.* Its backward then runs no second ``flash_fwd``
+  (PR 32) and no second q / k / v product, rope or swap to ``(b, h, s, d)``
+  (PR 34); it still rebuilds the two norms, the o product and the whole
+  SwiGLU from the block's input. There is no parameter for it because
+  there is nothing to choose: the saved set follows what the traced block
+  contains. A block with no flash attention has no such names and is
+  recomputed whole, as a bare ``jax.checkpoint`` would (to the lowered
+  text: ``tests/test_recompute_flash_residuals.py``).
+
+  What a caller pays is memory, in bytes a checkpointed block that holds
+  one attention: tokens x hidden for the block's input (any checkpoint
+  keeps that), plus tokens x (2 x q heads + 2 x kv heads) x head_dim
+  elements for q, k, v and ``out``, plus ``lse`` (``f32[b, h, s, 1]``,
+  which the chip pads to 128 lanes: tokens x q heads x 512 B). At
+  ``internlm2-d12-pretrain-1chip``'s shape (2 x 4096 tokens, hidden 2048,
+  16 / 8 heads of 128, bf16) that is 32 + 96 + 64 = 192 MiB a layer, where
+  PR 32 left 128, a bare checkpoint 32 and an unrecomputed layer ~900.
+  What it buys on v5e there, twelve layers a step: PERF.md section 6
+  (PR 32: 525.3 -> 497.5 ms; PR 34: 498.7 -> 492.3 ms, half of what the
+  products were reckoned at, because that step is compiled to the brim of
+  the chip's memory and XLA rematerializes products of its own to make
+  room). Nothing else joins the set by size: the SwiGLU's inputs are 128
+  MiB a layer each, and a set sized to the last MiB of one cell would be a
+  knob in disguise (ROADMAP S4a).
 * **eager**: a GradNode that saves inputs + host RNG state; its backward
   restores the RNG, reruns ``function`` with grad enabled, and routes
   cotangents with ``autograd.grad`` — same structure as the reference's
@@ -62,11 +76,10 @@ def recompute(function, *args, **kwargs):
 
         # imported here: the Pallas stack is a quarter of a second that
         # ``import paddle_tpu`` does not otherwise pay
-        from ...ops.pallas.flash_attention import (FLASH_LSE_NAME,
-                                                   FLASH_OUT_NAME)
+        from ...ops.pallas.flash_attention import FLASH_RESIDUAL_NAMES
 
         keep = jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT_NAME, FLASH_LSE_NAME)
+            *FLASH_RESIDUAL_NAMES)
         out_vals = jax.checkpoint(pure, policy=keep)(values)
         if isinstance(out_vals, tuple):
             return tuple(Tensor._from_value(v) for v in out_vals)

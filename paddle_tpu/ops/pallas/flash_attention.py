@@ -590,7 +590,7 @@ def _flash_bhsd(q, k, v, q_seg, k_seg, causal, scale):
 
 
 def _flash_fwd_rule(q, k, v, q_seg, k_seg, causal, scale):
-    out, lse = _name_residuals(*_fwd(q, k, v, causal, scale, q_seg, k_seg))
+    q, k, v, out, lse = _name_residuals(q, k, v, causal, scale, q_seg, k_seg)
     return out, (q, k, v, q_seg, k_seg, out, lse)
 
 
@@ -676,14 +676,32 @@ from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 
 FLASH_OUT_NAME = "flash_out"
 FLASH_LSE_NAME = "flash_lse"
-__all__ += ["FLASH_OUT_NAME", "FLASH_LSE_NAME"]
+FLASH_Q_NAME = "flash_q"
+FLASH_K_NAME = "flash_k"
+FLASH_V_NAME = "flash_v"
+FLASH_RESIDUAL_NAMES = (FLASH_Q_NAME, FLASH_K_NAME, FLASH_V_NAME,
+                        FLASH_OUT_NAME, FLASH_LSE_NAME)
+__all__ += ["FLASH_OUT_NAME", "FLASH_LSE_NAME", "FLASH_Q_NAME",
+            "FLASH_K_NAME", "FLASH_V_NAME", "FLASH_RESIDUAL_NAMES"]
 
 
-def _name_residuals(out, lse):
-    """The forward rule's two dear residuals under the names a checkpoint
-    policy can keep (``fleet.recompute`` does, so a recomputed block does not
-    run ``flash_fwd`` a second time: q, k and v are two products and a rope
-    away from the block's input, ``out`` and ``lse`` are the kernel). Outside
-    such a policy a name is the identity and lowers to nothing."""
-    return (checkpoint_name(out, FLASH_OUT_NAME),
+def _name_residuals(q, k, v, causal, scale, q_seg, k_seg):
+    """The forward rule's ``_fwd`` with the five arrays ``_bwd`` reads under
+    names a checkpoint policy can keep: q, k and v as the kernel takes them
+    (``(b, h, s, d)``, rope applied; the named values are the ones ``_fwd``
+    reads AND the ones in the residual tuple), ``out`` and ``lse`` as it
+    writes them. ``fleet.recompute`` keeps all five, so a recomputed block
+    rebuilds nothing the attention's backward reads: no second ``flash_fwd``
+    (PR 32), and no second q / k / v product, rope or swap to ``(b, h, s,
+    d)`` (PR 34). Kept bytes a layer: tokens x (2 x q heads + 2 x kv heads)
+    x head_dim elements for q, k, v and ``out``, plus ``lse``, an
+    ``f32[b, h, s, 1]`` that the chip pads to 128 lanes (at 2 x 4096 tokens,
+    16 / 8 heads of 128, bf16: 32 + 16 + 16 + 32 + 64 = 160 MiB). Outside
+    such a policy a name is the identity and lowers to nothing. Returns
+    ``(q, k, v, out, lse)``."""
+    q = checkpoint_name(q, FLASH_Q_NAME)
+    k = checkpoint_name(k, FLASH_K_NAME)
+    v = checkpoint_name(v, FLASH_V_NAME)
+    out, lse = _fwd(q, k, v, causal, scale, q_seg, k_seg)
+    return (q, k, v, checkpoint_name(out, FLASH_OUT_NAME),
             checkpoint_name(lse, FLASH_LSE_NAME))

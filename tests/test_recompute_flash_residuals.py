@@ -1,12 +1,14 @@
-"""What the traced ``recompute`` keeps: the flash forward's ``out`` and ``lse``.
+"""What the traced ``recompute`` keeps: the five arrays the flash backward reads.
 
 ``fleet.recompute`` under a trace is ``jax.checkpoint`` with a policy that
-saves the two residuals ``_flash_fwd_rule`` names, so the backward of a
-checkpointed block rebuilds q, k and v (two products and a rope) and does NOT
-run ``flash_fwd`` again. These cases count the kernels in the gradient
-program (a property of the traced program: nothing runs for the counts),
-hold the results to those of the unrecomputed model, and hold everything
-that has no such names to what it lowered to before.
+saves the residuals ``_flash_fwd_rule`` names: q, k and v as the kernel
+takes them, ``out`` and ``lse`` as it writes them. The backward of a
+checkpointed block then does NOT run ``flash_fwd`` again (PR 32) and does
+NOT rebuild q, k and v: no second q / k / v product, rope or swap to
+``(b, h, s, d)`` (PR 34). These cases count the kernels and the products in
+the gradient program (a property of the traced program: nothing runs for the
+counts), hold the results to those of the unrecomputed model, and hold
+everything that has no such names to what it lowered to before.
 """
 import collections
 import functools
@@ -26,7 +28,7 @@ from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import kernel_mesh
 
-from _jaxpr import pallas_names
+from _jaxpr import pallas_names, walk
 
 LAYERS, BATCH, SEQ = 3, 2, 128
 CASES = pytest.mark.parametrize("kv_heads,packed", [
@@ -85,12 +87,57 @@ def test_recomputed_gradient_program_runs_flash_fwd_once_a_layer(
         assert kernels[name] == LAYERS, (name, kernels)
 
 
+def _program_counts(fn, *operands):
+    """Primitive names (outside any kernel's body) and kernel names of the
+    traced ``fn``, each with how often the program holds it."""
+    jaxpr = jax.make_jaxpr(fn)(*operands).jaxpr
+    primitives = collections.Counter(
+        eqn.primitive.name for eqn, enclosing in walk(jaxpr)
+        if "pallas_call" not in enclosing)
+    return primitives, collections.Counter(pallas_names(jaxpr))
+
+
+@CASES
+def test_recomputed_gradient_program_rebuilds_neither_q_nor_k_nor_v(
+        monkeypatch, kv_heads, packed):
+    """A recomputed layer runs six products more than an unrecomputed one
+    without the three names (q, k, v, o, gate, up) and three with them (o,
+    gate, up); its ropes are the forward's and the backward's alone, as if
+    nothing were recomputed; and the swaps of q, k and v to ``(b, h, s, d)``
+    go with them (7 of the 8 transposes a recomputed layer added: what is
+    left is ``out``'s swap back for the o product)."""
+    if packed:
+        _pack(monkeypatch)
+    (plain, plain_kernels), (kept, kept_kernels) = (
+        _program_counts(jax.value_and_grad(loss), params, ids)
+        for loss, params, ids in (_loss_fn(rc, kv_heads)
+                                  for rc in (False, True)))
+    assert kept["dot_general"] == plain["dot_general"] + 3 * LAYERS
+    assert kept_kernels["fused_rope"] == plain_kernels["fused_rope"] \
+        == 4 * LAYERS
+    assert kept["transpose"] == plain["transpose"] + LAYERS
+
+
+@CASES
+def test_gradient_program_names_the_five_residuals_once_a_layer(
+        monkeypatch, kv_heads, packed):
+    """In the rule's order, inside every layer's checkpoint; the policy's
+    saved set is these five and nothing else."""
+    if packed:
+        _pack(monkeypatch)
+    loss, params, ids = _loss_fn(True, kv_heads)
+    named = [eqn.params["name"] for eqn, _ in walk(
+        jax.make_jaxpr(jax.value_and_grad(loss))(params, ids).jaxpr)
+        if eqn.primitive.name == "name"]
+    assert named == list(fa.FLASH_RESIDUAL_NAMES) * LAYERS
+
+
 @CASES
 def test_recompute_changes_neither_the_loss_nor_any_gradient(
         monkeypatch, kv_heads, packed):
-    """To the bit on the CPU: the kept ``out`` / ``lse`` are what the
-    recomputation produced, from the same kernel on the same operands, and
-    everything else is recomputed as before."""
+    """To the bit on the CPU: the kept arrays are what the recomputation
+    produced, from the same operations on the same operands, and everything
+    else is recomputed as before."""
     if packed:
         _pack(monkeypatch)
     (l0, g0), (l1, g1) = (
@@ -163,8 +210,9 @@ def test_outside_a_policy_the_names_lower_to_nothing(monkeypatch,
                                                      differentiated):
     """Lowered for the TPU (nothing runs). The forward alone, which is what
     every serving cell's ``jit_prefill`` traces, never reaches the rule: no
-    ``name`` equation. A gradient with no checkpoint around it holds the two
-    names and lowers to the text it lowers to with the names taken out."""
+    ``name`` equation. A gradient with no checkpoint around it holds the five
+    names, in the rule's order, and lowers to the text it lowers to with the
+    names taken out."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     B, S, H, KVH, D = 2, 256, 4, 2, 128
     avals = [jax.ShapeDtypeStruct((B, S, n, D), jnp.bfloat16)
@@ -179,7 +227,8 @@ def test_outside_a_policy_the_names_lower_to_nothing(monkeypatch,
     fn = jax.grad(loss, argnums=(0, 1, 2)) if differentiated else forward
     named = [e.params["name"] for e in jax.make_jaxpr(fn)(*avals).jaxpr.eqns
              if e.primitive.name == "name"]
-    assert named == ([fa.FLASH_OUT_NAME, fa.FLASH_LSE_NAME]
+    assert named == ([fa.FLASH_Q_NAME, fa.FLASH_K_NAME, fa.FLASH_V_NAME,
+                      fa.FLASH_OUT_NAME, fa.FLASH_LSE_NAME]
                      if differentiated else [])
     text = _tpu_text(fn, *avals)
     monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)

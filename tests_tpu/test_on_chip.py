@@ -304,12 +304,13 @@ def test_recompute_keeps_flash_residuals_at_the_train_cell_widths(capsys):
     """Two layers at ``internlm2-d12-pretrain-1chip``'s widths (hidden 2048,
     16 / 8 heads of 128, SwiGLU 8192, 2 x 4096 tokens, bfloat16, fused
     lm-head + CE; a vocabulary of 8,192 keeps the oracle small). The
-    recomputed gradient program keeps the forward's ``out`` and ``lse`` and
-    runs ``flash_fwd`` once a layer; its loss and every gradient have to be
-    those of the program with no recompute, both against the same weights in
-    float32 at ``highest`` with every Pallas route off, a batch row at a
-    time (a row's scores are 1 GiB a layer). By the norm of the difference
-    over the oracle's norm, a leaf at a time."""
+    recomputed gradient program keeps the five arrays the flash backward
+    reads (q, k, v, ``out``, ``lse``), runs ``flash_fwd`` once a layer and
+    ``fused_rope`` four times (no rope in the recomputation); its loss and
+    every gradient have to be those of the program with no recompute, both
+    against the same weights in float32 at ``highest`` with every Pallas
+    route off, a batch row at a time (a row's scores are 1 GiB a layer). By
+    the norm of the difference over the oracle's norm, a leaf at a time."""
     import re
 
     import paddle_tpu as paddle
@@ -339,11 +340,13 @@ def test_recompute_keeps_flash_residuals_at_the_train_cell_widths(capsys):
         step = jax.jit(jax.value_and_grad(loss))
         if rc:
             text = step.lower(p16, ids).compile().as_text()
-            for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv"):
+            for kernel, a_layer in (("flash_fwd", 1), ("flash_bwd_dq", 1),
+                                    ("flash_bwd_dkdv", 1),
+                                    ("fused_rope", 4)):
                 calls = re.findall(
                     rf"^\s*%?{kernel}(?:\.\d+)? = .*tpu_custom_call", text,
                     re.M)
-                assert len(calls) == layers, (kernel, len(calls))
+                assert len(calls) == a_layer * layers, (kernel, len(calls))
         got[rc] = jax.block_until_ready(step(p16, ids))
 
     model.config.use_recompute = False
