@@ -34,6 +34,7 @@ from __future__ import annotations
 import argparse
 import gc
 import json
+import shutil
 import sys
 import time
 import traceback
@@ -44,7 +45,7 @@ import numpy as np
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
-from paddle_tpu import native
+from paddle_tpu import native, profiler
 from paddle_tpu.core import telemetry
 from paddle_tpu.jit import count_backend_compiles
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, llama_shard_fn
@@ -111,6 +112,22 @@ def device_bytes_in_use():
 
 def mosaic_calls(compiled):
     return compiled.as_text().count(MOSAIC_CALL)
+
+
+def device_time_split(prof, program):
+    """How the program table splits a profiled session's device time of
+    one program (``Profiler.summary()`` prints it), as shares of that
+    program's seconds; None where the trace has no device plane (a CPU).
+    ``unmatched`` is the share under instructions the table does not know:
+    near 0 says the table is the traced executable's."""
+    split = ((prof.summary() or {}).get(program) or {}).get("split")
+    shutil.rmtree(prof.log_dir, ignore_errors=True)
+    if not split:
+        return None
+    total = split["seconds"]
+    return {"unmatched": round(split["unmatched"] / total, 4),
+            **{kind: {k: round(v / total, 4) for k, v in split[kind].items()}
+               for kind in ("by_pass", "by_scope")}}
 
 
 def make_prompts(rng, vocab, prompt_lens, shared_prefix):
@@ -303,7 +320,7 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
     serving_compiles = telemetry.counter("xla.compiles_total")
     before = serving_compiles.value(phase="serving")
     t1 = time.monotonic()
-    with count_backend_compiles() as compiles:
+    with count_backend_compiles() as compiles, profiler.Profiler() as prof:
         piped = serve_session(engine, prompts, max_new, segment)
         check(engine.stats()["pipelined"], "first session was not pipelined")
         kv = engine.kv_stats()
@@ -337,6 +354,18 @@ def serve_phase(cfg, *, seed, max_slots, max_len, page_size, prompt_buckets,
         f"all ok, streams identical, 0 compiles after warm-up in the "
         f"pipelined one, {kv['prefix_tokens_saved']} prompt tokens served "
         f"from the prefix cache")
+    # both engines are gone; what the first one's warm-up filed for its
+    # decode program is still in the profiler's program table, and splits
+    # the profiled session's device time by scope
+    seg_ops = profiler.program_ops("jit_segment") or {}
+    scopes = {r["scopes"][0] for r in seg_ops.values() if r["scopes"]}
+    check({"attn", "mlp", "lm_head", "sample"} <= scopes,
+          f"program_ops('jit_segment') after both engines were deleted: "
+          f"{len(seg_ops)} instructions, scopes {sorted(scopes)}")
+    report["segment_time_split"] = device_time_split(prof, "jit_segment")
+    log(f"serve: program table holds jit_segment's {len(seg_ops)} "
+        f"instructions after both engines were deleted; the profiled "
+        f"session's device time: {report['segment_time_split']}")
 
     plain = paddle.jit.to_static(model)
     report["cache_vs_plain"] = check_cache_against_plain_forward(
@@ -382,15 +411,22 @@ def train_phase(cfg, *, seed, batch, seq, steps, lr):
         compiled = step.lower(ids, None, None, ids).compile()
         mem = compiled.memory_analysis()
         calls = mosaic_calls(compiled)
-        losses = [float(step(ids, None, None, ids)) for _ in range(steps)]
+        losses = [float(step(ids, None, None, ids))
+                  for _ in range(steps - 1)]
+        # the last step under the profiler: its summary splits the step's
+        # device time by pass and by scope where the trace has a device
+        with profiler.Profiler() as prof:
+            losses.append(float(step(ids, None, None, ids)))
     check(all(np.isfinite(losses)), f"loss is not finite: {losses}")
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    split = device_time_split(prof, "jit_one_step")
     report = {
         "layers": cfg.num_hidden_layers, "batch": batch, "seq": seq,
         "weight_bytes": param_bytes(model),
         "step_argument_bytes": mem.argument_size_in_bytes,
         "step_temp_bytes": mem.temp_size_in_bytes,
         "mosaic_in_step": calls > 0, "mosaic_calls_in_step": calls,
+        "step_time_split": split,
         "losses": [round(x, 4) for x in losses],
         "wall_s": round(time.monotonic() - t0, 2),
     }
@@ -631,6 +667,19 @@ def run(chips, device):
     else:
         phase("serve", serve_phase, SERVE, ("mosaic_in_segment",))
         phase("train", train_phase, TRAIN, ("mosaic_in_step",))
+        # the program table is the traced executables' own: the profiled
+        # sessions' device time is split by scope and by pass with next to
+        # nothing under instructions the table does not know
+        for name, key, kind, want in (
+                ("serve", "segment_time_split", "by_scope",
+                 ("attn", "mlp", "lm_head")),
+                ("train", "step_time_split", "by_pass",
+                 ("forward", "backward", "recompute", "none"))):
+            split = summary[name][key] or {"unmatched": 1.0, kind: {}}
+            check(split["unmatched"] < 0.05
+                  and all(split[kind].get(k, 0) > 0 for k in want),
+                  f"{name}: device time is not attributed {kind} "
+                  f"(Profiler.summary() over the program table): {split}")
     summary["compile_cache"] = cache
     log(f"persistent compile cache: {cache['hits']} hits, "
         f"{cache['misses']} misses")

@@ -28,7 +28,7 @@ import numpy as np
 from ..core import autograd, random as _random
 from ..core.autograd import GradNode
 from ..core.tensor import Tensor, TracedConcretizationError
-from ..profiler import annotate
+from ..profiler import annotate, note_program
 
 __all__ = [
     "to_static", "TrainStep", "cond", "while_loop", "scan",
@@ -691,9 +691,12 @@ class TrainStep:
 
             if getattr(optimizer, "_master_grad", False):
                 # fp32 grads before clip/update (amp master_grad semantics)
-                grads = {k: g.astype(jnp.float32) for k, g in grads.items()}
+                with jax.named_scope("cast"):
+                    grads = {k: g.astype(jnp.float32)
+                             for k, g in grads.items()}
             if has_clip:
-                grads = clip_grads(grads)
+                with jax.named_scope("clip"):
+                    grads = clip_grads(grads)
 
             with jax.named_scope("optimizer"):
                 new_p, new_accs, new_masters = optimizer.functional_update(
@@ -788,13 +791,31 @@ class TrainStep:
                 self._compiled = self._build()
             traced = self._compiled._cache_size()
             self.optimizer._step_count += 1
+            operands = self._step_operands(
+                self.optimizer._step_count, args, kwargs, labels)
             loss, new_params, new_buffers, self._accs, self._masters = \
-                self._compiled(*self._step_operands(
-                    self.optimizer._step_count, args, kwargs, labels))
+                self._compiled(*operands)
             self.model.load_raw_state(new_params, new_buffers)
-            sp.set(step=self.optimizer._step_count,
-                   compiled=self._compiled._cache_size() != traced)
+            compiled = self._compiled._cache_size() != traced
+            sp.set(step=self.optimizer._step_count, compiled=compiled)
+            if compiled:
+                self._note_program(operands)
         return Tensor._from_value(loss)
+
+    def _note_program(self, operands):
+        """File the step that the call just compiled in the profiler's
+        program table (which scope and pass each of its instructions came
+        from). The donated operands are gone but their shapes are not;
+        lowering from those comes out of JAX's in-process cache, so nothing
+        is compiled a second time (``tests/test_program_ops.py`` holds
+        that), and only a call that compiled comes here."""
+        def aval(x):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None)
+
+        note_program("one_step", self._compiled.lower(
+            *jax.tree_util.tree_map(aval, operands)).compile())
 
     def state_dict(self):
         """Optimizer accumulator state for checkpointing the compiled path.

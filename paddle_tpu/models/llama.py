@@ -507,7 +507,8 @@ class LlamaModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, attn_mask=None, caches=None):
-        hidden = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             if caches is not None:
@@ -522,7 +523,8 @@ class LlamaModel(Layer):
                     lambda h, _l=layer: _l(h, attn_mask=attn_mask), hidden)
             else:
                 hidden = layer(hidden, attn_mask=attn_mask)
-        hidden = self.norm(hidden)
+        with jax.named_scope("final_norm"):
+            hidden = self.norm(hidden)
         if caches is not None:
             return hidden, new_caches
         return hidden

@@ -454,7 +454,8 @@ class MoEMLAModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, attn_mask=None, caches=None):
-        hidden = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             if caches is not None:
@@ -463,7 +464,8 @@ class MoEMLAModel(Layer):
                 new_caches.append(cache)
             else:
                 hidden = layer(hidden, attn_mask=attn_mask)
-        hidden = self.norm(hidden)
+        with jax.named_scope("final_norm"):
+            hidden = self.norm(hidden)
         return (hidden, new_caches) if caches is not None else hidden
 
 

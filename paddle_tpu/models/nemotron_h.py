@@ -378,7 +378,8 @@ class NemotronHModel(Layer):
                             epsilon=config.layer_norm_epsilon)
 
     def forward(self, input_ids, caches=None):
-        hidden = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             if caches is not None:
@@ -424,12 +425,13 @@ class NemotronHForCausalLM(Layer):
         hidden = out[0] if caches is not None else out
         true_lens = next((c.true_lens for c in caches or ()
                           if isinstance(c, StateCache)), None)
-        if true_lens is not None:
-            idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
-            hidden = Tensor._from_value(jnp.take_along_axis(
-                hidden._value, jnp.broadcast_to(
-                    idx, (hidden.shape[0], 1, hidden.shape[-1])), axis=1))
-        hidden = self.model.norm(hidden)
+        with jax.named_scope("final_norm"):
+            if true_lens is not None:
+                idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
+                hidden = Tensor._from_value(jnp.take_along_axis(
+                    hidden._value, jnp.broadcast_to(
+                        idx, (hidden.shape[0], 1, hidden.shape[-1])), axis=1))
+            hidden = self.model.norm(hidden)
         with jax.named_scope("lm_head"):
             logits = self.lm_head(hidden)
         return (logits, out[1]) if caches is not None else logits

@@ -266,7 +266,8 @@ class PowerRetentionModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, attn_mask=None, caches=None):
-        hidden = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             if caches is not None:
@@ -311,12 +312,13 @@ class PowerRetentionForCausalLM(Layer):
         out = self.model(input_ids, attn_mask=attn_mask, caches=caches)
         hidden = out[0] if caches is not None else out
         true_lens = caches[0].true_lens if caches else None
-        if true_lens is not None:
-            idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
-            hidden = Tensor._from_value(jnp.take_along_axis(
-                hidden._value, jnp.broadcast_to(
-                    idx, (hidden.shape[0], 1, hidden.shape[-1])), axis=1))
-        hidden = self.model.norm(hidden)
+        with jax.named_scope("final_norm"):
+            if true_lens is not None:
+                idx = (true_lens - 1).astype(jnp.int32)[:, None, None]
+                hidden = Tensor._from_value(jnp.take_along_axis(
+                    hidden._value, jnp.broadcast_to(
+                        idx, (hidden.shape[0], 1, hidden.shape[-1])), axis=1))
+            hidden = self.model.norm(hidden)
         with jax.named_scope("lm_head"):
             logits = self.lm_head(hidden)
         return (logits, out[1]) if caches is not None else logits

@@ -74,7 +74,7 @@ from ..core.resilience import (
     inject,
 )
 from ..core.tensor import Tensor
-from ..profiler import annotate, record_span
+from ..profiler import annotate, note_program, record_span
 from .generation import _sample_rows, sequence_store
 from .kv_pool import PagePool, PrefixCache
 
@@ -847,6 +847,14 @@ class ContinuousBatchingEngine:
                 self._aot[key] = jitted.lower(
                     p_s, ks_s, vs_s, *avals).compile()
                 stats["programs"] += 1
+                # which scope each of its instructions came from, for
+                # whoever reads a trace of it. The decode program is asked
+                # about after this engine is gone (the benchmark frees the
+                # engine before it reads), so its module is taken now
+                # (50 ms); the admission programs' on the first question,
+                # while this engine holds them (28 of them would be 1.4 s)
+                note_program(key, self._aot[key], len(self._aot),
+                             keep=key[0] == "segment")
                 n, hit = wd.thread_compiles()
                 sp.set(cached="memory" if n == seen
                        else "compile_cache" if hit else "no")

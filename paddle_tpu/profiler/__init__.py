@@ -5,23 +5,34 @@ scheduler states, export_chrome_tracing, RecordEvent spans; C++ CUPTI
 tracers in paddle/fluid/platform/profiler/). TPU-natively device timelines
 come from the XLA/XPlane profiler (``jax.profiler``) — the CUPTI
 equivalent — and host-side phases from RecordEvent spans recorded here and
-via ``jax.profiler.TraceAnnotation``.
+via ``jax.profiler.TraceAnnotation``. ``programs.py`` keeps, for every
+program the engine and the train step compile, which ``jax.named_scope``
+and which pass each HLO instruction came from (``note_program`` /
+``program_ops`` / ``attribute_device_time``): what turns a device trace's
+``fusion.2244`` into "the MLP's backward".
 """
 from __future__ import annotations
 
+import glob
 import json
 import os
+import tempfile
 import time
 
 import jax.profiler
 from jax.profiler import TraceAnnotation
 
 from ..core import perfwatch, telemetry
+from .programs import (ProgramTable, attribute_device_time,  # noqa: F401
+                       device_op_seconds, note_program, program_ops,
+                       program_table)
 
 __all__ = [
     "Profiler", "ProfilerResult", "RecordEvent", "ProfilerTarget",
     "ProfilerState", "annotate", "record_span", "make_scheduler",
     "export_chrome_tracing", "load_profiler_result",
+    "note_program", "program_ops", "attribute_device_time", "program_table",
+    "ProgramTable", "device_op_seconds",
 ]
 
 # a Profiler session is running: spans record into the sink for its
@@ -151,14 +162,22 @@ class Profiler:
     also with ``FLAGS_telemetry=0`` while the session runs."""
 
     def __init__(self, targets=None, scheduler=None, on_trace_ready=None,
-                 timer_only=False, profile_memory=False, with_flops=False):
+                 timer_only=False, profile_memory=False, with_flops=False,
+                 log_dir=None, python_tracer=False):
         self.targets = targets or [ProfilerTarget.CPU, ProfilerTarget.TPU]
         self.scheduler = scheduler
         self.on_trace_ready = on_trace_ready
         self.timer_only = timer_only
-        self._log_dir = None
+        # where the XPlane dump goes: this argument, else
+        # $PADDLE_PROFILER_LOGDIR, else a fresh temporary directory a
+        # session (``log_dir`` says which after ``start()``)
+        self._log_dir = log_dir
+        # the Python tracer floods a serving trace and slows the host it
+        # measures: off unless asked for
+        self.python_tracer = bool(python_tracer)
         self._step = 0
         self._tracing = False
+        self._traced = False
         self._step_times = []
         self._last_step_t = None
         self._t_start_wall = None
@@ -173,14 +192,24 @@ class Profiler:
         self._t_start_wall = time.time()  # wall-clock: x-process trace epoch
         self._last_step_t = time.perf_counter()
         if not self.timer_only:
+            self._log_dir = (self._log_dir
+                             or os.environ.get("PADDLE_PROFILER_LOGDIR")
+                             or tempfile.mkdtemp(prefix="paddle_tpu_profile_"))
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if self.python_tracer else 0
             try:
-                self._log_dir = os.environ.get(
-                    "PADDLE_PROFILER_LOGDIR", "/tmp/paddle_tpu_profile")
-                jax.profiler.start_trace(self._log_dir)
-                self._tracing = True
+                jax.profiler.start_trace(self._log_dir,
+                                         profiler_options=options)
+                self._tracing = self._traced = True
             except Exception:
                 self._tracing = False
         return self
+
+    @property
+    def log_dir(self):
+        """The directory of this session's XPlane dump (None before
+        ``start()`` and for a ``timer_only`` session)."""
+        return self._log_dir
 
     def stop(self):
         global _active
@@ -208,11 +237,60 @@ class Profiler:
                 f"(min {arr.min()*1e3:.2f}, max {arr.max()*1e3:.2f}, "
                 f"n={len(arr)})")
 
+    def device_time(self):
+        """This session's device seconds by program and, per program, by
+        pass and by scope: ``attribute_device_time`` over the newest
+        ``.xplane.pb`` under ``log_dir``. None where the session traced
+        nothing or the trace holds no device plane (a CPU)."""
+        if not self._traced or self._tracing:
+            return None
+        paths = sorted(glob.glob(os.path.join(
+            self._log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        events = device_op_seconds(paths[-1]) if paths else []
+        if not events:
+            return None
+        totals: dict = {}
+        for program, _, seconds in events:
+            totals[program] = totals.get(program, 0.0) + seconds
+        split = attribute_device_time(events)
+        return {p: {"seconds": s, "split": split[p]}
+                for p, s in totals.items()}
+
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms"):
+        """The reference's "Model Summary" (forward / backward /
+        optimization / others) for a TPU: device seconds by compiled
+        program, and for a program the table knows by pass and by scope."""
         print(self.step_info())
-        print("host events recorded: "
-              f"{len(_sink_events(self._t_start_wall))}")
+        programs = self.device_time()
+        if programs is None:
+            where = "timer only" if self.timer_only else jax.default_backend()
+            print(f"no device plane in this session's trace ({where}): "
+                  "device time by program, pass and scope needs a TPU trace")
+            return None
+
+        def shares(table, total):
+            return "  ".join(
+                f"{k or '(no scope)'} {100.0 * v / total:.1f} %"
+                for k, v in sorted(table.items(), key=lambda kv: -kv[1]))
+
+        print("device time by program (self seconds of its HLO operations):")
+        for program, rec in sorted(programs.items(),
+                                   key=lambda kv: -kv[1]["seconds"]):
+            print(f"  {program:<28s} {rec['seconds']:10.6f} s")
+            split = rec["split"]
+            if split is None:
+                print("    not in the program table (note_program)")
+                continue
+            total = split["seconds"] or 1.0
+            print(f"    by pass:  {shares(split['by_pass'], total)}")
+            if op_detail:
+                print(f"    by scope: {shares(split['by_scope'], total)}")
+            print("    " + shares({
+                "compiler clones": split["compiler_clone"],
+                "in fusions that mix scopes or passes": split["mixed"],
+                "unmatched": split["unmatched"]}, total))
+        return programs
 
     def export(self, path, format="json"):
         # scoped to THIS profiler session (start() → now); the

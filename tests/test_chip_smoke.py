@@ -43,6 +43,10 @@ def test_serve_phase_tiny():
         <= rep["cache_vs_plain"]["tolerance"]
     # interpreted kernels leave no Mosaic call: run() would refuse this
     assert rep["mosaic_in_segment"] is False
+    # a CPU trace has no device plane: Profiler.summary() says so and the
+    # report carries no split (run() requires one on the chip); that the
+    # program table outlived both engines is checked inside the phase
+    assert rep["segment_time_split"] is None
     assert rep["kv_pool_bytes"] > 0 and rep["weight_bytes"] > 0
 
 
@@ -53,6 +57,9 @@ def test_train_phase_tiny():
     assert len(rep["losses"]) == 3
     assert rep["losses"][-1] < rep["losses"][0]
     assert rep["mosaic_in_step"] is False
+    # a CPU trace has no device plane: Profiler.summary() says so and the
+    # report carries no split (run() requires one on the chip)
+    assert rep["step_time_split"] is None
 
 
 def test_train_phase_refuses_the_sdpa_fallback():
