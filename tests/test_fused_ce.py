@@ -6,6 +6,8 @@ and the LLaMA labels= training fast path (eager AND TrainStep-compiled).
 Reference anchors: mp_ops.py:414 `_c_softmax_with_cross_entropy`,
 c_softmax_with_cross_entropy_op.cu.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,24 +38,93 @@ def test_forward_parity(v, block):
                                rtol=1e-5, atol=1e-5)
 
 
+def _labels(rng, n, v, case):
+    lab = rng.integers(0, v, (n,))
+    if case == "divides":
+        return lab
+    if case == "padded":  # labels in the padded last block's real columns
+        lab[:4] = [v - 1, v - 2, 256, 257]
+        lab[5] = -100
+        return lab
+    if case == "block_edges":  # first and last column of every block
+        edges = [c for s in range(0, v, 128) for c in (s, min(s + 127,
+                                                              v - 1))]
+        lab[:len(edges)] = edges
+        return lab
+    if case == "ignored":
+        lab[::3] = -100
+        return lab
+    return np.full((n,), -100)  # all_ignored
+
+
+# (vocab, block, labels, upstream cotangent): 512 / 128 divides; 300 / 128
+# pads its last block to 384 columns
+_GRAD_CASES = {
+    "divides": (512, 128, "divides", "mean"),
+    "padded": (300, 128, "padded", "mean"),
+    "block_edges": (300, 128, "block_edges", "mean"),
+    "ignored": (300, 128, "ignored", "mean"),
+    "all_ignored": (300, 128, "all_ignored", "mean"),
+    "weighted": (300, 128, "padded", "weighted"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GRAD_CASES))
 @pytest.mark.parametrize("transpose_y", [True, False])
-def test_grad_parity(transpose_y):
+def test_grad_parity(transpose_y, case):
+    """dx and dW against the dense f32 gradient: each vocabulary block
+    folds its own one-hot columns into softmax - onehot."""
+    v, block, labels, cot = _GRAD_CASES[case]
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((17, 24)), jnp.float32)
-    w0 = jnp.asarray(rng.standard_normal((300, 24)) * 0.2, jnp.float32)
+    w0 = jnp.asarray(rng.standard_normal((v, 24)) * 0.2, jnp.float32)
     w = w0 if transpose_y else w0.T
-    lab = jnp.asarray(rng.integers(0, 300, (17,)), jnp.int32).at[2].set(-100)
+    lab = jnp.asarray(_labels(rng, 17, v, labels), jnp.int32)
+    g = (jnp.full((17,), 1.0 / 17, jnp.float32) if cot == "mean" else
+         jnp.asarray(rng.standard_normal(17), jnp.float32))
 
-    gf = jax.grad(lambda x, w: flce(x, w, lab, transpose_y=transpose_y,
-                                    block_size=128).mean(),
+    gf = jax.grad(lambda x, w: (flce(x, w, lab, transpose_y=transpose_y,
+                                     block_size=block) * g).sum(),
                   argnums=(0, 1))(x, w)
-    gr = jax.grad(lambda x, w0: _dense(x, w0, lab).mean(),
+    gr = jax.grad(lambda x, w0: (_dense(x, w0, lab) * g).sum(),
                   argnums=(0, 1))(x, w0)
     np.testing.assert_allclose(np.asarray(gf[0]), np.asarray(gr[0]),
                                rtol=1e-4, atol=1e-5)
     dw = gf[1] if transpose_y else gf[1].T
     np.testing.assert_allclose(np.asarray(dw), np.asarray(gr[1]),
                                rtol=1e-4, atol=1e-5)
+
+
+def _hlo_ops(text):
+    """(dtype, dims, opcode) of every instruction of an HLO module's text."""
+    return [(dt, tuple(int(d) for d in dims.split(",") if d), op)
+            for dt, dims, op in re.findall(
+                r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", text)]
+
+
+@pytest.mark.parametrize("transpose_y", [True, False])
+def test_grad_program_has_no_label_gather_or_f32_stack(transpose_y):
+    """The gradient program of a padded bf16 head holds no gather, no
+    scatter and no float32 (nblk, block, H) stack: each block's dW is
+    final inside the block and written once in the weight's dtype. (The
+    CPU itself widens some bf16 weight-sized arrays to f32 around its
+    dots; tests/test_tpu_aot_compile.py asks the chip's compiler.)"""
+    n, h, v, block = 64, 32, 1000, 256
+    nblk = -(-v // block)
+    x = jnp.zeros((n, h), jnp.bfloat16)
+    w = jnp.zeros((v, h) if transpose_y else (h, v), jnp.bfloat16)
+    lab = jnp.zeros((n,), jnp.int32)
+    grad = jax.jit(jax.grad(
+        lambda x, w: flce(x, w, lab, transpose_y=transpose_y,
+                          block_size=block).sum(), argnums=(0, 1)))
+    ops = _hlo_ops(grad.lower(x, w).compile().as_text())
+    assert not [op for _, _, op in ops if op in ("gather", "scatter")]
+    stacks = [(dt, dims) for dt, dims, _ in ops
+              if dt == "f32" and len(dims) == 3
+              and int(np.prod(dims)) == nblk * block * h]
+    assert not stacks, stacks
+    gx, gw = grad(x, w)
+    assert gx.dtype == jnp.bfloat16 and gw.dtype == jnp.bfloat16
 
 
 def test_bf16_accumulates_f32():

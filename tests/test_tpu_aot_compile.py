@@ -224,10 +224,12 @@ def test_recomputed_gradient_program_compiles_at_the_train_cell_widths(topo):
     as the kernel writes it, ``f32[b, h, s, 1]``), and what it compiled runs
     ``flash_fwd`` once a layer and ``fused_rope`` four times (forward and
     backward, q and k; six with q, k and v rebuilt in the recomputation).
-    The compiler's temporaries read 1,956,190,208 B here (1.82 GiB; 1.73
-    with ``out`` and ``lse`` alone): the bound leaves 3 % over that (58 MiB),
-    less than two layers of the next candidates' own bytes (a kept gate 256
-    MiB, a kept ``h1`` 64), so a later name cannot grow the kept set unseen."""
+    The compiler's temporaries read 1,049,316,352 B here (0.98 GiB; 1.82
+    until PR 36, when the lm-head's float32 weight-gradient stack, its
+    relayout and its scatter-add went): the bound leaves 3 % over that (31
+    MiB), less than two layers of the next candidates' own bytes (a kept
+    gate 256 MiB, a kept ``h1`` 64), so a later name cannot grow the kept
+    set unseen."""
     import re
 
     import paddle_tpu as paddle
@@ -266,7 +268,41 @@ def test_recomputed_gradient_program_compiles_at_the_train_cell_widths(topo):
         calls = re.findall(rf"^\s*%?{kernel}(?:\.\d+)? = .*{MOSAIC_CALL}",
                            text, re.M)
         assert len(calls) == a_layer * layers, (kernel, len(calls))
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.88 * 2 ** 30
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.01 * 2 ** 30
+
+
+@pytest.mark.parametrize("transpose_y", [False, True], ids=["HV", "VH"])
+def test_fused_lm_head_gradient_at_the_train_cell_head(topo, transpose_y):
+    """The fused lm-head + CE's gradient program at the training cell's
+    head (2 x 4096 tokens, hidden 2048, vocabulary 92,544 in 23 blocks of
+    4,096, the last padded; bf16, a weighted sum of the per-token loss):
+    the chip's compiler keeps no gather and no scatter of the backward
+    (the forward's label pick is one gather of a logit a token) and no
+    float32 array of the vocabulary's size: each block's dW is written
+    once, in bf16, into the weight's own layout."""
+    import re
+
+    from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
+
+    n, h, v = 8192, 2048, 92544
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(x, w, lab, g):
+        return (fused_linear_cross_entropy(x, w, lab, transpose_y=transpose_y)
+                * g).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((n, h), BF16), ((v, h) if transpose_y else (h, v), BF16),
+            ((n,), jnp.int32), ((n,), jnp.float32))]).compile().as_text()
+    ops = re.findall(r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(.*?"
+                     r"op_name=\"([^\"]*)\"", text)
+    backward = [(op, name) for _, _, op, name in ops
+                if op in ("gather", "scatter") and "transpose(" in name]
+    assert not backward, backward
+    big = [(dt, dims, op) for dt, dims, op, _ in ops if dt == "f32"
+           and int(np.prod([int(d) for d in dims.split(",") if d])) >= v * h]
+    assert not big, big
 
 
 @pytest.mark.parametrize("b", [1, 32], ids=["width1", "width32"])

@@ -468,6 +468,39 @@ def test_fused_linear_cross_entropy_on_chip():
                                np.asarray(gr[1], np.float32),
                                rtol=BF16_RTOL, atol=BF16_ATOL)
 
+    # InternLM2's untied (H, V) head (the training cell's: hidden 2048,
+    # vocabulary 92,544, 23 blocks of 4,096, the last padded) on a slice of
+    # tokens: bf16 dx / dW against the float32 dense gradient of the same
+    # bf16 values. The one-hot rides the MXU with softmax * g, so the
+    # label's entry is rounded as the rest of the block's (PERF.md §6).
+    N, H, V = 1024, 2048, 92544
+    x = jnp.asarray(rng.standard_normal((N, H)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((H, V)) * 0.02, jnp.bfloat16)
+    lab = jnp.asarray(rng.randint(0, V, (N,)), jnp.int32)
+
+    def dense32(x, w):
+        logits = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+        logp = jax.nn.log_softmax(logits, -1)
+        return -jnp.take_along_axis(logp, lab[:, None], 1)[:, 0]
+
+    gf = jax.jit(jax.grad(lambda x, w: flce(x, w, lab,
+                                            transpose_y=False).mean(),
+                          argnums=(0, 1)))(x, w)
+    gr = jax.jit(jax.grad(lambda x, w: dense32(x, w).mean(),
+                          argnums=(0, 1)))(x.astype(jnp.float32),
+                                           w.astype(jnp.float32))
+    assert gf[0].dtype == gf[1].dtype == jnp.bfloat16
+    gaps = {}
+    for name, got, want in (("dx", gf[0], gr[0]), ("dW", gf[1], gr[1])):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want)
+        gaps[name] = {
+            "norm": float(np.linalg.norm(got - want) / np.linalg.norm(want)),
+            "max": float(np.abs(got - want).max() / np.abs(want).max())}
+    print("FUSED_CE_HEAD_GAPS", gaps)
+    for name, gap in gaps.items():
+        assert gap["norm"] < 1e-2 and gap["max"] < 1e-2, (name, gap)
+
 
 def test_continuous_batching_on_chip():
     """Per-slot-depth decode segments (continuous batching) must emit the
