@@ -9,6 +9,11 @@ returns. After the window closes the requests in flight are pumped to their
 end (their first tokens complete the TTFT sample; nothing seen after the close
 counts as served), the peak memory is read, the program's state is freed, and
 the plain reference judges a seeded sample of the finished requests.
+
+The model is the configuration's: its ``program`` module builds the model and
+the serving stack, its ``compare`` module holds the reference's judgement
+(``harness.module_of``), and what the program module's ``bag_extras`` gives
+goes into the bag for the readers.
 """
 from __future__ import annotations
 
@@ -18,7 +23,7 @@ import time
 
 import numpy as np
 
-from benchmark import compare, program, stats, tracing
+from benchmark import harness, stats, tracing
 
 DRAIN_LIMIT_S = 60.0
 
@@ -120,6 +125,8 @@ def run(ctx: dict) -> dict:
     import paddle_tpu as paddle
 
     log, config, traffic = ctx["log"], ctx["config"], ctx["traffic"]
+    program = harness.module_of(config, "program")
+    compare = harness.module_of(config, "compare")
     m = config["model"]
     seconds = ctx["seconds"]
     kind = ctx["device"]["kind"]
@@ -157,8 +164,7 @@ def run(ctx: dict) -> dict:
     log(f"window {seconds} s, {sent} requests sent, drained in "
         f"{time.monotonic() - t_drain:.1f} s on {kind}")
 
-    from benchmark.harness import memory_peak_bytes
-    peak = memory_peak_bytes(ctx["chips"])
+    peak = harness.memory_peak_bytes(ctx["chips"])
     sent_stamps = [stamps[r["rid"]] for r in requests[:sent]]
     failed = [st for st in sent_stamps if st.status != "ok"]
     for st in failed[:5]:
@@ -211,6 +217,9 @@ def run(ctx: dict) -> dict:
                   "reference": detail, "device": kind},
     }
     bag["trace"] = tracer.reduce(bag)
+    extras = getattr(program, "bag_extras", None)
+    if extras is not None:
+        bag.update(extras(config))
     return bag
 
 

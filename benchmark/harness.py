@@ -2,8 +2,10 @@
 on the device, runs the cell's driver, reads the per-layer metrics through
 their readers, decides ``correct`` and prints the result line.
 
-It is driven by data. A cell names a configuration and a traffic mix; the mix
-names its generator and its driver by module; a per-layer metric is
+It is driven by data. A cell names a configuration and a traffic mix; the
+configuration names its ``program`` and ``compare`` modules (the dense
+decoder's where it names none), the mix its generator and its driver, by
+module; a per-layer metric is
 ``benchmark/metrics/<name>.json`` naming its reader module and arguments; a
 cell's limits are ``benchmark/limits/<cell>.json``. Adding any of them is
 adding files and entries, never editing one that is there.
@@ -45,6 +47,13 @@ def load_cell(workload: str) -> dict:
         "limits": load_json(limits_path) if os.path.exists(limits_path)
         else {},
     }
+
+
+def module_of(config: dict, role: str):
+    """The ``program`` or ``compare`` module that a configuration names:
+    what builds the system under test, and what judges it against the plain
+    reference. A configuration that names none is the dense decoder's."""
+    return importlib.import_module(config.get(role, "benchmark." + role))
 
 
 def applies(metric: dict, workload: str) -> bool:

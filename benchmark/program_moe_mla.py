@@ -1,9 +1,8 @@
-"""``program.py``'s twin for the sparse-expert / latent-attention model: the
-one place this configuration touches the system under test. ``program.py``
-names ``LlamaForCausalLM`` and this PR may edit no benchmark file that is
-there, so the new architecture comes in beside it; the serving stack is built
-by ``program.build_serving`` itself (ROADMAP, Metrics and harness: a
-configuration should name its program module, and this twin folds back).
+"""The program module of the sparse-expert / latent-attention model, which its
+configuration file names under ``program``: the one place this configuration
+touches the system under test. The serving stack is built by
+``program.build_serving`` itself, and ``bag_extras`` hands the serving driver
+what this model's readers need besides.
 
 Importing the model is the first thing ``build_model`` does: on a checkout
 that lacks it (the parent commit under this PR's benchmark files) the cell
@@ -26,6 +25,12 @@ def build_serving(model, config: dict):
     ENGINE_FACTS["kv_bytes_per_token"] = \
         engine.kv_stats()["bytes_per_token"]
     return engine, frontend
+
+
+def bag_extras(config: dict) -> dict:
+    """What ``readers/moe_mla.py`` reads beside the serving driver's bag."""
+    return {"model": model_section(config),
+            "kv_bytes_per_token": ENGINE_FACTS["kv_bytes_per_token"]}
 
 
 def model_config(m: dict, positions: int, experts_held=None):

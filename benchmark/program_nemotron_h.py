@@ -1,9 +1,8 @@
-"""``program.py``'s twin for the ``nemotron_h`` hybrid model: the one place
-this configuration touches the system under test. ``program.py`` names
-``LlamaForCausalLM`` and this PR may edit no benchmark file that is there, so
-the new architecture comes in beside it; the serving stack is built by
-``program.build_serving`` itself (ROADMAP, Metrics and harness: a
-configuration should name its program module, and this twin folds back).
+"""The program module of the ``nemotron_h`` hybrid model, which its
+configuration file names under ``program``: the one place this configuration
+touches the system under test. The serving stack is built by
+``program.build_serving`` itself, and ``bag_extras`` hands the serving driver
+what this model's readers need besides.
 
 Importing the model is the first thing ``build_model`` does: on a checkout
 that lacks it (the parent commit under this PR's benchmark files) the cell
@@ -79,6 +78,14 @@ def model_section(config: dict) -> dict:
         "experts_held", (0, m["n_routed_experts"])))
     m["router_calibration"] = config["assumed"]["router_calibration"]
     return m
+
+
+def bag_extras(config: dict) -> dict:
+    """What ``readers/nemotron_h.py`` reads beside the serving driver's
+    bag."""
+    return {"model": model_section(config),
+            "state_bytes_per_slot": ENGINE_FACTS.get("state_bytes_per_slot"),
+            "kv_bytes_per_token": ENGINE_FACTS.get("kv_bytes_per_token")}
 
 
 def model_config(m: dict, positions: int):

@@ -1,9 +1,8 @@
-"""``program.py``'s twin for the power-retention model: the one place this
-configuration touches the system under test. ``program.py`` names
-``LlamaForCausalLM`` and this PR may edit no benchmark file that is there, so
-the new architecture comes in beside it; the serving stack is built by
-``program.build_serving`` itself (ROADMAP, Metrics and harness: a
-configuration should name its program module, and this twin folds back).
+"""The program module of the power-retention model, which its configuration
+file names under ``program``: the one place this configuration touches the
+system under test. The serving stack is built by ``program.build_serving``
+itself, and ``bag_extras`` hands the serving driver what this model's readers
+need besides.
 
 Importing the model is the first thing ``build_model`` does: on a checkout
 that lacks it (the parent commit under this PR's benchmark files) the cell
@@ -78,6 +77,13 @@ def model_section(config: dict) -> dict:
     m = dict(config["model"])
     m["gate_bias_range"] = tuple(config["assumed"]["gate_bias_range"])
     return m
+
+
+def bag_extras(config: dict) -> dict:
+    """What ``readers/retention.py`` reads beside the serving driver's
+    bag."""
+    return {"model": model_section(config),
+            "state_bytes_per_slot": ENGINE_FACTS.get("state_bytes_per_slot")}
 
 
 def model_config(m: dict, positions: int):

@@ -2,6 +2,7 @@
 that decides ``correct`` shown to fail: under the lower-precision control and
 under each fault the cells can have, planted beneath the timed path. Nothing
 here is a device metric and none is printed as one."""
+import importlib
 import json
 import os
 import time
@@ -48,6 +49,21 @@ def run(config, traffic, limits, seed, seconds=1.5, control=None):
                             control=control)
 
 
+def drive(config, traffic, seed):
+    """A driver's own bag, as it hands it to the harness."""
+    import paddle_tpu as paddle
+
+    paddle.seed(0)
+    traffic = data(traffic)
+    driver = importlib.import_module(traffic["driver"])
+    ctx = {"workload": "rehearsal", "seed": seed, "seconds": 1.5,
+           "trace": False, "t_process": time.monotonic(), "device": dict(CPU),
+           "chips": 1, "config": data(config), "traffic": traffic,
+           "limits": {}, "workdir": os.path.join(harness.ROOT, ".bench_tmp"),
+           "log": lambda msg: None, "control": None}
+    return driver.run(ctx)
+
+
 def test_serving_rehearsal_is_correct_and_prints_no_device_metric():
     r = run("tiny-serve", "tiny-chat", SERVE_LIMITS, 2 ** 31 + 5)
     assert r["correct"] and r["failed"] == 0 and r["attempted"] > 5
@@ -56,6 +72,33 @@ def test_serving_rehearsal_is_correct_and_prints_no_device_metric():
     assert r["compared"]["logit_gap_max"]["value"] <= 1e-3
     assert r["notes"]["compiles_in_window"] == 0
     assert r["notes"]["reference"]["requests"] == 3
+
+
+# what the configuration's program module adds to the serving driver's bag
+# (PR 37 folded the three twin drivers back into ``drivers/serve.py``; these
+# are the keys and values each twin added): the model's keys with the
+# deployment's share beside them, the pool's bytes a token (latent: 3 layers
+# x (32 + 8) x 4; hybrid: 1 attention layer x 2 x 2 KV heads x 16 x 4) and
+# the state's bytes a slot (retention: 2 layers as the engine lays them out;
+# hybrid: 2 Mamba-2 layers x (4 x 8 x 16 + 3 x 96) x 4)
+@pytest.mark.parametrize("config,traffic,program,extras", [
+    ("tiny-serve", "tiny-chat", "benchmark.program", {}),
+    ("tiny-moe-mla", "tiny-reason", "benchmark.program_moe_mla",
+     {"kv_bytes_per_token": 480}),
+    ("tiny-retention", "tiny-continue", "benchmark.program_retention",
+     {"state_bytes_per_slot": 39168}),
+    ("tiny-nemotron-h", "tiny-think", "benchmark.program_nemotron_h",
+     {"kv_bytes_per_token": 256, "state_bytes_per_slot": 6400})])
+def test_the_serving_driver_adds_what_the_configurations_program_gives(
+        config, traffic, program, extras):
+    bag = drive(config, traffic, 2 ** 31 + 31)
+    section = getattr(importlib.import_module(program), "model_section",
+                      lambda c: c["model"])
+    assert bag["model"] == section(data(config))
+    for key in ("kv_bytes_per_token", "state_bytes_per_slot"):
+        assert bag.get(key) == extras.get(key), key
+        assert (key in bag) == (key in extras), key
+    assert bag["failed"] == 0 and harness.decide(bag["checks"][-1:])
 
 
 def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch):

@@ -5,7 +5,8 @@ session runs the mix's ramp and a window, and the line printed says what was
 sent, what failed, the queue (pending less the slots in use) at the window's
 middle and at its end, and the client's numbers. The knee is the highest rate
 at which nothing is rejected and the queue at the end is no longer than at the
-middle; the mix's file then gets 0.75 of it.
+middle; the mix's file then gets 0.75 of it. The engine is built by the
+program module that the cell's configuration names.
 
     python3 -m benchmark.tools.sweep --workload <cell> --per-block 30,40,50 --seconds 30 --seed 1
 """
@@ -15,7 +16,7 @@ import importlib
 import json
 import sys
 
-from benchmark import harness, program, stats
+from benchmark import harness, stats
 from benchmark.drivers import serve
 
 
@@ -33,6 +34,7 @@ def main(argv=None) -> int:
 
     paddle.jit.enable_compilation_cache()
     config, traffic = loaded["config"], dict(loaded["traffic"])
+    program = harness.module_of(config, "program")
     model = program.build_model(config, args.seed)
     engine, fe = program.build_serving(model, config)
     fe.warmup()
