@@ -628,11 +628,12 @@ class LlamaPretrainingCriterion(Layer):
         the blockwise fused linear+CE op — no (B,S,V) logits buffer
         (c_softmax_with_cross_entropy_op.cu's memory story, TPU-blockwise).
         ``transpose_y=True`` for the tied-embedding (V,H) layout, False for
-        the nn.Linear (H,V) layout."""
-        loss = fused_linear_cross_entropy(
+        the nn.Linear (H,V) layout. The mean is the op's own: it forms the
+        gradient in one walk over row chunks, holding a float32 (H,V)
+        accumulator and one chunk's float32 logits (ops/fused_ce.py)."""
+        return fused_linear_cross_entropy(
             hidden[:, :-1, :], lm_weight, labels[:, 1:],
-            transpose_y=transpose_y)
-        return loss.mean()
+            transpose_y=transpose_y, reduction="mean")
 
 
 # ----------------------------------------------------------------- pipeline

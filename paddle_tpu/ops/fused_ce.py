@@ -27,6 +27,24 @@ otherwise cross shard boundaries every block). For *vocab-sharded* (TP)
 logits use `distributed.fleet.ParallelCrossEntropy`, whose local-max /
 local-sumexp / masked-pick composition GSPMD partitions into exactly the
 reference kernel's all-reduce pattern.
+
+The MEAN over rows (``reduction="mean"``, what a training criterion asks
+for) takes a path of its own. Its cotangent is one scalar for every row,
+so the whole gradient can be formed in the forward and scaled in the
+backward: the rows are walked in chunks, each chunk's logits over the
+WHOLE vocabulary are formed once in float32 (its logsumexp exact at once),
+and from those same logits come the chunk's loss, its dx and its addend to
+one float32 dW accumulator. Three vocabulary-sized products where the
+per-token path needs four, no label gather (the one-hot is a select over
+columns the chunk holds) and no block of dW written at a column offset.
+A per-token cotangent cannot be applied to a dW formed in the forward, so
+``reduction="none"`` keeps the vocabulary walk. A chunk takes rows of every
+sequence of the batch, so a batch sharded over devices stays on them and
+each device adds its own rows into its accumulator, which is all-reduced
+once after the walk. The price is memory: the accumulator and one chunk's
+float32 logits, 4 x (H + chunk) bytes a word of the vocabulary (1.41 GiB
+at H 2048, chunk 2048, V 92,544), where the vocabulary walk holds a
+(rows, block) float32 block of logits and the weight's dtype's dW.
 """
 from __future__ import annotations
 
@@ -35,6 +53,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..core import telemetry
 
 __all__ = ["fused_linear_cross_entropy", "c_softmax_with_cross_entropy"]
 
@@ -65,6 +85,11 @@ def c_softmax_with_cross_entropy(logits, label, ignore_index=-100):
     return loss[..., None]
 
 _NEG_INF = float(np.finfo(np.float32).min)
+
+# which walk each traced caller took, bumped at trace time: ``token`` (the
+# mean path's gradient) or ``vocab`` (the per-token path, and the mean's
+# value where no gradient is asked)
+_M_WALK = telemetry.counter("ops.fused_ce_walk_total")
 
 
 def _vocab_dim(weight, transpose_y):
@@ -103,6 +128,7 @@ def _fused_lce(x2d, weight, labels, transpose_y, ignore_index, block):
 
 
 def _fused_lce_fwd(x2d, weight, labels, transpose_y, ignore_index, block):
+    _M_WALK.inc(walk="vocab")
     n = x2d.shape[0]
     v = _vocab_dim(weight, transpose_y)
     nblk = -(-v // block)
@@ -186,6 +212,132 @@ def _fused_lce_bwd(transpose_y, ignore_index, block, res, g):
 _fused_lce.defvjp(_fused_lce_fwd, _fused_lce_bwd)
 
 
+def _chunk_rows(x3, labels, ignore_index, chunk):
+    """(nchunk, B, T_c, H) rows and (nchunk, B, T_c) labels from (B, T, H)
+    rows: chunk c takes rows [c T_c, (c + 1) T_c) of EVERY index of the
+    leading axis, with T padded by rows at ``ignore_index``. A leading axis
+    sharded over devices (a data-parallel batch) is never walked or padded,
+    so each device's chunk holds its own rows; walking the flattened rows
+    would all-gather them and run the whole head on every device."""
+    b, t, h = x3.shape
+    nchunk = -(-b * t // chunk)
+    tc = -(-t // nchunk)
+    if tc > 8:
+        tc = -(-tc // 8) * 8
+    pad = nchunk * tc - t
+    if pad:
+        x3 = jnp.pad(x3, ((0, 0), (0, pad), (0, 0)))
+        labels = jnp.pad(labels, ((0, 0), (0, pad)),
+                         constant_values=ignore_index)
+    return (jnp.moveaxis(x3.reshape(b, nchunk, tc, h), 1, 0),
+            jnp.moveaxis(labels.reshape(b, nchunk, tc), 1, 0))
+
+
+def _chunk_loss(logits, lab, ignore_index):
+    """A chunk's per-row loss from its float32 logits over the whole
+    vocabulary, with the pieces the gradient reuses: the one-hot, the
+    logsumexp and which rows count."""
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    onehot = col == lab[:, None]
+    m = logits.max(axis=-1)
+    lse = m + jnp.log(jnp.exp(logits - m[:, None]).sum(axis=-1))
+    picked = jnp.where(onehot, logits, 0.0).sum(axis=-1)
+    valid = lab != ignore_index
+    return jnp.where(valid, lse - picked, 0.0), onehot, lse, valid
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fused_lce_mean(x3, weight, labels, transpose_y, ignore_index, chunk,
+                    dtypes):
+    # no gradient asked: the per-token walk's losses, averaged
+    block = _pick_block(_vocab_dim(weight, transpose_y))
+    return _fused_lce(x3.reshape(-1, x3.shape[-1]), weight,
+                      labels.reshape(-1), transpose_y, ignore_index,
+                      block).mean()
+
+
+def _fused_lce_mean_fwd(x3, weight, labels, transpose_y, ignore_index,
+                        chunk, dtypes):
+    """The loss and the unscaled gradient in one walk over row chunks:
+    per chunk one product for the logits, then dx = dl @ W^T and
+    dW += x^T @ dl with dl = softmax - onehot (zero on an ignored row)
+    as the products' operand."""
+    _M_WALK.inc(walk="token")
+    b, t, h = x3.shape
+    xc, lc = _chunk_rows(x3, labels, ignore_index, chunk)
+    op_dtype = jnp.promote_types(x3.dtype, weight.dtype)
+
+    def body(carry, c):
+        total, dw = carry
+        x, lab = c[0].reshape(-1, h), c[1].reshape(-1)
+        logits = _block_logits(x, weight, transpose_y)
+        loss, onehot, lse, valid = _chunk_loss(logits, lab, ignore_index)
+        # exp(logits - lse), not exp(logits - max) / sum: the products
+        # form dl inside their operand, once an output tile, and a divide
+        # there costs the vector unit more than the exp
+        p = jnp.exp(logits - lse[:, None])
+        dl = jnp.where(valid[:, None], jnp.where(onehot, p - 1.0, p), 0.0)
+        dl = dl.astype(op_dtype)
+        if transpose_y:  # weight (V, H): dx = dl @ W; dW += dl^T @ x
+            dx = jax.lax.dot_general(dl, weight, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            dw = dw + jax.lax.dot_general(
+                dl, x, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:  # weight (H, V): dx = dl @ W^T; dW += x^T @ dl
+            dx = jax.lax.dot_general(dl, weight, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            dw = dw + jax.lax.dot_general(
+                x, dl, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return (total + loss.sum(), dw), dx.reshape(b, -1, h)
+
+    init = (jnp.zeros((), jnp.float32), jnp.zeros(weight.shape, jnp.float32))
+    (total, dw), dx = jax.lax.scan(body, init, (xc, lc))
+    dx = jnp.moveaxis(dx, 0, 1).reshape(b, -1, h)[:, :t]
+    return total / (b * t), (dx, dw)
+
+
+def _fused_lce_mean_bwd(transpose_y, ignore_index, chunk, dtypes, res, g):
+    dx, dw = res
+    scale = g / (dx.shape[0] * dx.shape[1])
+    x_dtype, w_dtype = dtypes
+    # the barrier keeps the scale-and-cast here: fused into the optimizer's
+    # update instead, the float32 accumulator would stay alive through the
+    # whole backward of the layers
+    dx, dw = jax.lax.optimization_barrier(
+        ((dx * scale).astype(x_dtype), (dw * scale).astype(w_dtype)))
+    dlabels = np.zeros(dx.shape[:2], dtype=jax.dtypes.float0)
+    return dx, dw, dlabels
+
+
+_fused_lce_mean.defvjp(_fused_lce_mean_fwd, _fused_lce_mean_bwd)
+
+
+# the most a chunk's float32 logits (chunk x V) may take
+_CHUNK_LOGITS_BYTES = 768 << 20
+
+
+def _pick_chunk(n, h, v):
+    """Rows a chunk of the mean path, from the shapes alone. A chunk reads
+    and writes the float32 (H, V) accumulator once, 8 bytes an entry,
+    against 2 x chunk FLOPs an entry of its dW product: under ~1,000 rows
+    that product is bound by the accumulator's bytes on a v5e (197 TFLOP/s
+    over 819 GB/s). A chunk wider than H holds float32 logits (chunk x V)
+    larger than the accumulator (H x V) beside it. So H, held to
+    [1024, 2048]; never more than the rows (rounded up to 8); and no more
+    than puts 768 MiB in a chunk's logits, a multiple of 128 and at least
+    128 (768 rows at a 256k vocabulary). The walk then holds 4 (H + chunk)
+    bytes a word of the vocabulary beside the weight: 16 KiB at H 2048,
+    1.41 GiB at the training cell's head (2 x 4,095 rows, H 2048, V
+    92,544: four chunks of 2 x 1,024, which the chip's compiler fits
+    without a rematerialized clone in the whole step, PERF.md §6). Under
+    data parallelism a device's share of a chunk is chunk / devices rows:
+    at four or more its dW product is bound by the accumulator's bytes."""
+    cap = max(128, _CHUNK_LOGITS_BYTES // (4 * v) // 128 * 128)
+    return min(max(1024, min(h, 2048)), cap, -(-n // 8) * 8)
+
+
 def _pick_block(v):
     """Largest lane-aligned block <= 4096 that DIVIDES the 128-rounded
     vocab (32000 -> 3200, 32768 -> 4096) — a divisor means `_pad_vocab` is
@@ -201,7 +353,8 @@ def _pick_block(v):
 
 
 def fused_linear_cross_entropy(x, weight, label, transpose_y=True,
-                               ignore_index=-100, block_size=0):
+                               ignore_index=-100, block_size=0,
+                               reduction="none"):
     """loss = cross_entropy(x @ W(^T), label) without materializing logits.
 
     Args:
@@ -209,9 +362,14 @@ def fused_linear_cross_entropy(x, weight, label, transpose_y=True,
         weight: (V, H) if ``transpose_y`` (tied-embedding layout) else
             (H, V) (``nn.Linear`` layout).
         label: (...,) integer class ids; ``ignore_index`` rows get loss 0.
-        block_size: vocab block width (0 = auto, multiple of 128).
+        block_size: vocab block width of the per-token path (0 = auto,
+            multiple of 128).
+        reduction: ``"none"`` for the per-token loss, ``"mean"`` for its
+            mean over ALL rows, ignored rows included (``loss.mean()``),
+            formed by the row-chunk walk (module docstring).
 
-    Returns per-token loss of shape (...,), float32.
+    Returns per-token loss of shape (...,), float32, or its mean, a float32
+    scalar.
     """
     lead = x.shape[:-1]
     h = x.shape[-1]
@@ -221,8 +379,22 @@ def fused_linear_cross_entropy(x, weight, label, transpose_y=True,
     if tuple(label.shape) != tuple(lead):
         raise ValueError(
             f"label shape {label.shape} must match x leading dims {lead}")
+    lab = label.astype(jnp.int32)
+    if reduction == "mean":
+        # (B, T, H) with B the leading axis, the one a data-parallel batch
+        # is sharded on; B = 1 where the rows are one axis, or where B rows
+        # would not fit in a chunk
+        n = int(np.prod(lead))
+        chunk = _pick_chunk(n, h, v)
+        b = lead[0] if len(lead) > 1 and lead[0] <= chunk else 1
+        return _fused_lce_mean(
+            x.reshape(b, -1, h), weight, lab.reshape(b, -1),
+            bool(transpose_y), int(ignore_index), chunk,
+            (jnp.dtype(x.dtype), jnp.dtype(weight.dtype)))
+    if reduction != "none":
+        raise ValueError(f"reduction must be 'none' or 'mean', not "
+                         f"{reduction!r}")
     block = int(block_size) or _pick_block(v)
-    loss = _fused_lce(x.reshape(-1, h), weight,
-                      label.reshape(-1).astype(jnp.int32),
+    loss = _fused_lce(x.reshape(-1, h), weight, lab.reshape(-1),
                       bool(transpose_y), int(ignore_index), block)
     return loss.reshape(lead)

@@ -292,3 +292,158 @@ def test_llama_labels_path_compiled_trainstep():
     s2 = paddle.jit.TrainStep(m2, lambda logits, lab: crit(logits, lab), o2)
     l2 = np.asarray(s2.run(ids, labels=ids, steps=3)._value)
     np.testing.assert_allclose(l1, l2, rtol=1e-4, atol=1e-5)
+
+
+# (leading shape of the rows, which labels are ignored). The mean path's
+# chunk is H held to [1024, 2048] and never more than the rows rounded up
+# to 8; rows (B, T) walk T in chunks of (B, T_c), rows of one axis or more
+# than a chunk of sequences walk the flattened rows. So 2,500 rows walk
+# three chunks of 840 (the last padded), 2,048 rows two whole ones of
+# 1,024, 37 or 45 rows one chunk of 40 or 48, wider than the rows; 3 x 900
+# rows walk three chunks of 3 x 304 (each sequence padded by 12 rows), and
+# 1,100 x 2 rows, more sequences than a chunk's 1,024 rows, walk three
+# flattened chunks of 736
+_MEAN_CASES = {
+    "ragged_chunks": ((2500,), "some"),
+    "whole_chunks": ((2048,), "every_third"),
+    "chunk_wider_than_rows": ((37,), "some"),
+    "all_ignored": ((45,), "all"),
+    "sequences_ragged": ((3, 900), "every_third"),
+    "more_sequences_than_a_chunk": ((1100, 2), "some"),
+}
+
+
+def _rel_gaps(got, want):
+    """(norm-relative, max-relative) gap of got against want; the absolute
+    gap where want is all zeros."""
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    err = got - want
+    norm, peak = np.linalg.norm(want), np.abs(want).max()
+    return (float(np.linalg.norm(err) / (norm or 1.0)),
+            float(np.abs(err).max() / (peak or 1.0)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_MEAN_CASES))
+@pytest.mark.parametrize("transpose_y", [True, False])
+def test_mean_path_matches_per_token_mean_and_dense(transpose_y, case,
+                                                    dtype):
+    """reduction="mean" (the row-chunk walk that forms the gradient in its
+    forward) gives the loss, dx and dW of the per-token path's .mean() and
+    of a float32 dense reference; the division is by ALL rows, ignored
+    rows included."""
+    lead, ignored = _MEAN_CASES[case]
+    n = int(np.prod(lead))
+    h, v = 16, 300
+    dt = jnp.dtype(dtype)
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((n, h)), dt)
+    w0 = jnp.asarray(rng.standard_normal((v, h)) * 0.3, dt)
+    w = w0 if transpose_y else w0.T
+    lab = rng.integers(0, v, (n,))
+    if ignored == "some":
+        lab[[1, 5, n - 1]] = -100
+    elif ignored == "every_third":
+        lab[::3] = -100
+    else:
+        lab[:] = -100
+    x = x.reshape(lead + (h,))
+    lab = jnp.asarray(lab, jnp.int32).reshape(lead)
+
+    def mean(x, w):
+        return flce(x, w, lab, transpose_y=transpose_y, reduction="mean")
+
+    lm, gm = jax.value_and_grad(mean, argnums=(0, 1))(x, w)
+    lt, gt = jax.value_and_grad(
+        lambda x, w: flce(x, w, lab, transpose_y=transpose_y).mean(),
+        argnums=(0, 1))(x, w)
+    lr, gr = jax.value_and_grad(
+        lambda x, w0: _dense(x, w0, lab).mean(), argnums=(0, 1))(
+            x.astype(jnp.float32), w0.astype(jnp.float32))
+    assert lm.shape == () and lm.dtype == jnp.float32
+    assert gm[0].dtype == gm[1].dtype == dt
+    np.testing.assert_allclose(float(mean(x, w)), float(lm), rtol=1e-6)
+    np.testing.assert_allclose(float(lm), float(lt), rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(float(lm), float(lr), rtol=1e-5, atol=1e-7)
+    # f32: the same sums in another order; bf16: dl rounded to bf16 once as
+    # the products' operand, and dx, dW once on the way out
+    limit = 1e-5 if dt == jnp.float32 else 1e-2
+    dw_m = gm[1] if transpose_y else gm[1].T
+    dw_t = gt[1] if transpose_y else gt[1].T
+    for name, got, want in (("dx/per-token", gm[0], gt[0]),
+                            ("dW/per-token", dw_m, dw_t),
+                            ("dx/dense", gm[0], gr[0]),
+                            ("dW/dense", dw_m, gr[1])):
+        norm, peak = _rel_gaps(got, want)
+        assert norm < limit / 2 and peak < limit, (name, norm, peak)
+
+
+def _walks():
+    from paddle_tpu.core import telemetry
+
+    c = telemetry.counter("ops.fused_ce_walk_total")
+    return c.value(walk="token"), c.value(walk="vocab")
+
+
+@pytest.mark.parametrize("transpose_y", [True, False])
+def test_mean_gradient_program_has_three_vocabulary_products(transpose_y):
+    """The mean path's gradient program holds three products, each with the
+    vocabulary as a dimension (logits, dx, dW), where the per-token path's
+    holds four; no gather, no scatter and no dynamic-update-slice of a
+    vocabulary-wide array (dW is one accumulator, not blocks written at a
+    column offset). Tracing it counts one token walk; the per-token path
+    counts a vocabulary walk."""
+    n, h, v = 2100, 32, 1000
+    x = jnp.zeros((n, h), jnp.bfloat16)
+    w = jnp.zeros((v, h) if transpose_y else (h, v), jnp.bfloat16)
+    lab = jnp.zeros((n,), jnp.int32)
+
+    def program(reduction):
+        def loss(x, w):
+            out = flce(x, w, lab, transpose_y=transpose_y,
+                       reduction=reduction)
+            return out if reduction == "mean" else out.mean()
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w)
+
+    before = _walks()
+    lowered = program("mean")
+    assert _walks() == (before[0] + 1, before[1])
+    dots = re.findall(r"stablehlo\.dot_general .*?: \((.*?)\) ->",
+                      lowered.as_text())
+    assert len(dots) == 3, dots
+    assert all(f"x{v}x" in d or f"<{v}x" in d for d in dots), dots
+    ops = _hlo_ops(lowered.compile().as_text())
+    assert sum(op == "dot" for _, _, op in ops) == 3
+    assert not [op for _, _, op in ops if op in ("gather", "scatter")]
+    assert not [dims for _, dims, op in ops
+                if op == "dynamic-update-slice" and v in dims]
+
+    before = _walks()
+    per_token = program("none")
+    assert _walks() == (before[0], before[1] + 1)
+    assert len(re.findall(r"stablehlo\.dot_general", per_token.as_text())) \
+        == 4
+
+
+def test_mean_chunk_is_held_by_the_logits_budget():
+    """The mean path's chunk: H held to [1024, 2048], never more than the
+    rows rounded up to 8, and no more rows than put 768 MiB in a chunk's
+    float32 logits, a multiple of 128."""
+    from paddle_tpu.ops.fused_ce import _pick_chunk
+
+    assert _pick_chunk(8190, 2048, 92544) == 2048   # the training cell
+    assert _pick_chunk(8190, 4096, 92544) == 2048
+    assert _pick_chunk(8190, 512, 92544) == 1024
+    assert _pick_chunk(37, 2048, 92544) == 40
+    assert _pick_chunk(8190, 2048, 256000) == 768
+    assert _pick_chunk(8190, 2048, 4 << 20) == 128
+    for v in (32000, 92544, 152064, 256000):
+        assert _pick_chunk(1 << 20, 2048, v) * v * 4 <= 768 << 20
+
+
+def test_reduction_must_be_none_or_mean():
+    x = jnp.zeros((4, 8), jnp.float32)
+    with pytest.raises(ValueError, match="reduction"):
+        flce(x, jnp.zeros((16, 8)), jnp.zeros((4,), jnp.int32),
+             reduction="sum")

@@ -224,17 +224,21 @@ def test_recomputed_gradient_program_compiles_at_the_train_cell_widths(topo):
     as the kernel writes it, ``f32[b, h, s, 1]``), and what it compiled runs
     ``flash_fwd`` once a layer and ``fused_rope`` four times (forward and
     backward, q and k; six with q, k and v rebuilt in the recomputation).
-    The compiler's temporaries read 1,049,316,352 B here (0.98 GiB; 1.82
-    until PR 36, when the lm-head's float32 weight-gradient stack, its
-    relayout and its scatter-add went): the bound leaves 3 % over that (31
-    MiB), less than two layers of the next candidates' own bytes (a kept
-    gate 256 MiB, a kept ``h1`` 64), so a later name cannot grow the kept
-    set unseen."""
+    The loss is the per-token fused lm-head + CE's mean over the model's
+    hidden states, so what is measured is the kept set and the layers; the
+    training criterion's mean path has its own budget
+    (``test_fused_lm_head_mean_at_the_train_cell_head``). The compiler's
+    temporaries read 1,049,316,352 B here (0.98 GiB; 1.82 until PR 36, when
+    the lm-head's float32 weight-gradient stack, its relayout and its
+    scatter-add went): the bound leaves 3 % over that (31 MiB), less than
+    two layers of the next candidates' own bytes (a kept gate 256 MiB, a
+    kept ``h1`` 64), so a later name cannot grow the kept set unseen."""
     import re
 
     import paddle_tpu as paddle
     from paddle_tpu.jit import _FunctionalModel
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
 
     layers = 2
     paddle.set_default_dtype("bfloat16")
@@ -247,18 +251,22 @@ def test_recomputed_gradient_program_compiles_at_the_train_cell_widths(topo):
                 rope_theta=1e6, use_recompute=True))
     finally:
         paddle.set_default_dtype("float32")
-    functional = _FunctionalModel(model)
+    functional = _FunctionalModel(model.model)
     one_chip = SingleDeviceSharding(topo.devices[0])
     params = {k: jax.ShapeDtypeStruct(p._lazy_init[1], BF16,
                                       sharding=one_chip)
-              for k, p in model.named_parameters()}
-    buffers = {k: b._value for k, b in model.named_buffers()}
+              for k, p in model.model.named_parameters()}
+    head = jax.ShapeDtypeStruct(model.lm_head.weight._lazy_init[1], BF16,
+                                sharding=one_chip)
+    buffers = {k: b._value for k, b in model.model.named_buffers()}
 
-    def loss(params, ids, key):
-        return functional(params, buffers, (ids,), {"labels": ids}, key)[0]
+    def loss(params, head, ids, key):
+        hidden = functional(params, buffers, (ids,), {}, key)[0]
+        return fused_linear_cross_entropy(
+            hidden[:, :-1, :], head, ids[:, 1:], transpose_y=False).mean()
 
-    compiled = jax.jit(jax.grad(loss)).lower(
-        params,
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, head,
         jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
     ).compile()
@@ -303,6 +311,89 @@ def test_fused_lm_head_gradient_at_the_train_cell_head(topo, transpose_y):
     big = [(dt, dims, op) for dt, dims, op, _ in ops if dt == "f32"
            and int(np.prod([int(d) for d in dims.split(",") if d])) >= v * h]
     assert not big, big
+
+
+def _hlo_ops(text):
+    """(dtype, dims, opcode) of every instruction of an HLO module text."""
+    import re
+
+    return [(dt, tuple(int(d) for d in dims.split(",") if d), op)
+            for dt, dims, op in re.findall(
+                r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", text)]
+
+
+@pytest.mark.parametrize("transpose_y", [False, True], ids=["HV", "VH"])
+def test_fused_lm_head_mean_at_the_train_cell_head(topo, transpose_y):
+    """The mean path (``reduction="mean"``, what the training criterion
+    asks for) at the training cell's head: 2 x 4,095 rows of hidden 2048,
+    walked in four chunks of 2 x 1,024 (each sequence padded by one row),
+    vocabulary 92,544, bf16. The chip's compiler keeps three products
+    (logits, dx, dW) where the per-token path has four, no gather or
+    scatter (the label is a select over the chunk's columns) and no array
+    of rows x vocabulary: the float32 arrays of the vocabulary's width are
+    the one dW accumulator and one chunk's logits. The temporaries read
+    2,308,231,680 B (2.15 GiB) in both layouts: the accumulator and a
+    chunk's logits (0.71 GiB each), the bf16 dW (0.35) and the rows' dx;
+    the bound leaves 4.7 % over that."""
+    from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
+
+    b, t, h, v = 2, 4095, 2048, 92544
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(x, w, lab):
+        return fused_linear_cross_entropy(x, w, lab, transpose_y=transpose_y,
+                                          reduction="mean")
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((b, t, h), BF16), ((v, h) if transpose_y else (h, v), BF16),
+            ((b, t), jnp.int32))]).compile()
+    ops = _hlo_ops(compiled.as_text())
+    products = [dims for _, dims, op in ops if op == "convolution"]
+    assert sorted(products) == sorted(
+        [(2048, v), (v, h) if transpose_y else (h, v), (2048, h)]), products
+    assert not [op for _, _, op in ops if op in ("gather", "scatter")]
+    assert not [dims for _, dims, op in ops
+                if op == "dynamic-update-slice" and v in dims]
+    wide = {dims for dt, dims, _ in ops if dt == "f32" and v in dims}
+    assert wide <= {(2048, v), (v, h), (h, v)}, wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.25 * 2 ** 30
+
+
+def test_fused_lm_head_mean_keeps_a_data_parallel_batch_on_its_devices(topo):
+    """The mean path under data parallelism: the training cell's head with
+    a batch of 8 x 4,095 rows sharded over the four chips of a v5e:2x2 and
+    the (H, V) weight replicated. Each chunk takes its rows from every
+    sequence, so no chip gathers another's rows: every product is one
+    chip's quarter of a chunk (512 of 2,048 rows), and the one
+    collective of the weight's size is the all-reduce of the float32 dW
+    accumulator, once, after the walk (its loop body holds none)."""
+    import re
+
+    from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
+
+    b, t, h, v = 8, 4095, 2048, 92544
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+
+    def loss(x, w, lab):
+        return fused_linear_cross_entropy(x, w, lab, transpose_y=False,
+                                          reduction="mean")
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
+          for s, d, spec in (((b, t, h), BF16, P("dp")), ((h, v), BF16, P()),
+                             ((b, t), jnp.int32, P("dp")))]).compile()
+    text = compiled.as_text()
+    ops = _hlo_ops(text)
+    assert not [op for _, _, op in ops if op.startswith("all-gather")]
+    assert sorted(dims for _, dims, op in ops if op == "convolution") == \
+        sorted([(512, v), (h, v), (512, h)])
+    reduced = [dims for _, dims, op in ops if op.startswith("all-reduce")
+               and v in dims]
+    assert reduced == [(h, v)], reduced
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert re.search(rf"f32\[{h},{v}\]\S* all-reduce(-start)?\(", entry)
 
 
 @pytest.mark.parametrize("b", [1, 32], ids=["width1", "width32"])

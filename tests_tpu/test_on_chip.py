@@ -502,6 +502,56 @@ def test_fused_linear_cross_entropy_on_chip():
         assert gap["norm"] < 1e-2 and gap["max"] < 1e-2, (name, gap)
 
 
+def test_fused_linear_cross_entropy_mean_path_on_chip():
+    """The mean path (``reduction="mean"``, the training criterion's): the
+    whole gradient formed in one walk over row chunks. InternLM2's untied
+    (H, V) head at the training cell's widths on 2 x 2,050 tokens (three
+    chunks of 2 x 688, each sequence padded by 14 rows), a few rows at
+    ignore_index: loss, bf16 dx and dW against the float32 dense gradient
+    of the same bf16 values and against the per-token path's .mean()
+    (PERF.md §6)."""
+    from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy as flce
+
+    B, T, H, V = 2, 2050, 2048, 92544
+    x = jnp.asarray(rng.standard_normal((B, T, H)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((H, V)) * 0.02, jnp.bfloat16)
+    lab_np = rng.randint(0, V, (B * T,))
+    lab_np[[3, 2050, B * T - 1]] = -100
+    lab = jnp.asarray(lab_np.reshape(B, T), jnp.int32)
+
+    def dense32(x, w):
+        logits = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+        logp = jax.nn.log_softmax(logits, -1)
+        safe = jnp.where(lab == -100, 0, lab)
+        loss = -jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
+        return jnp.where(lab == -100, 0.0, loss).mean()
+
+    lm, gm = jax.jit(jax.value_and_grad(
+        lambda x, w: flce(x, w, lab, transpose_y=False, reduction="mean"),
+        argnums=(0, 1)))(x, w)
+    lt, gt = jax.jit(jax.value_and_grad(
+        lambda x, w: flce(x, w, lab, transpose_y=False).mean(),
+        argnums=(0, 1)))(x, w)
+    lr, gr = jax.jit(jax.value_and_grad(dense32, argnums=(0, 1)))(
+        x.astype(jnp.float32), w.astype(jnp.float32))
+    assert gm[0].dtype == gm[1].dtype == jnp.bfloat16
+    gaps = {"loss": float(abs(lm - lr) / abs(lr)),
+            "loss_vs_per_token": float(abs(lm - lt) / abs(lt))}
+    for name, got, want in (("dx", gm[0], gr[0]), ("dW", gm[1], gr[1]),
+                            ("dx_vs_per_token", gm[0], gt[0]),
+                            ("dW_vs_per_token", gm[1], gt[1])):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        gaps[name] = {
+            "norm": float(np.linalg.norm(got - want) / np.linalg.norm(want)),
+            "max": float(np.abs(got - want).max() / np.abs(want).max())}
+    print("FUSED_CE_MEAN_GAPS", gaps)
+    assert gaps["loss"] < 1e-4 and gaps["loss_vs_per_token"] < 1e-4, gaps
+    for name in ("dx", "dW", "dx_vs_per_token", "dW_vs_per_token"):
+        assert gaps[name]["norm"] < 1e-2 and gaps[name]["max"] < 1e-2, (
+            name, gaps[name])
+
+
 def test_continuous_batching_on_chip():
     """Per-slot-depth decode segments (continuous batching) must emit the
     same greedy tokens as per-request generate() with the REAL paged
